@@ -162,7 +162,8 @@ test_core:
 	  tests/test_comm_hook.py tests/test_powersgd.py \
 	  tests/test_config_knobs.py \
 	  tests/test_tracking.py tests/test_telemetry.py tests/test_device_time.py \
-	  tests/test_utils_misc.py \
+	  tests/test_utils_misc.py tests/test_compile_cache_placement.py \
+	  tests/test_no_fallback.py \
 	  tests/test_deepspeed_compat.py tests/test_param_offload.py -q
 
 test_models:
@@ -178,11 +179,12 @@ test_parallel:
 	  tests/test_zero1.py tests/test_compression.py \
 	  tests/test_pipeline.py tests/test_1f1b.py tests/test_parallel_plan.py \
 	  tests/test_stagewise.py tests/test_ring_attention.py \
-	  tests/test_flash_attention.py tests/test_sliding_window.py -q
+	  tests/test_flash_attention.py tests/test_sliding_window.py \
+	  tests/test_tpu_compile.py -q
 
 test_cli:
 	python -m pytest tests/test_cli.py tests/test_menu.py tests/test_launcher.py \
-	  tests/test_config_templates.py -q
+	  tests/test_config_templates.py tests/test_chip_smoke.py -q
 
 test_big_modeling:
 	python -m pytest tests/test_big_modeling.py tests/test_hooks.py \

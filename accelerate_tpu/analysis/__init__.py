@@ -4,7 +4,7 @@ The paper's promise is that one unmodified loop body runs from 1-process CPU
 to a multi-chip TPU mesh.  The failure modes that break that promise — host
 syncs baked into a ``jax.jit`` trace, per-step recompiles, collectives over
 axis names the mesh does not carry — surface only at runtime, often only on
-hardware (see TPU_OUTAGE_r0*.log).  This subsystem catches them from the AST,
+hardware.  This subsystem catches them from the AST,
 in CI, on the virtual 8-device CPU mesh.
 
 Layout:

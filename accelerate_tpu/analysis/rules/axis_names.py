@@ -2,7 +2,7 @@
 
 ``lax.psum(x, "batch")`` over a mesh whose axes are ``("dp", "fsdp", "tp",
 "sp", "ep", "pp")`` is a NameError *at trace time on hardware* — i.e. in the
-one environment we can't always reach (TPU_OUTAGE logs).  The declared axis
+one environment tests do not run in.  The declared axis
 universe is harvested in the engine's first pass from ``MESH_AXIS_*`` /
 ``ALL_MESH_AXES`` constants, ``Mesh(..., axis_names=...)`` literals and
 ``make_mesh({...})`` keys, so the rule checks every literal collective axis,

@@ -227,6 +227,19 @@ class CapturedStep:
         # recomputing repr+sha1 every replay would tax the hot path
         self._key_ids: dict = {}
 
+    def compiled_hlo(self) -> list[str]:
+        """Post-optimisation HLO text of every variant this step holds as an
+        explicitly compiled executable — the program the device runs, where
+        a Pallas kernel shows as ``tpu_custom_call`` and GSPMD's collectives
+        by name.  Builds go through ``lower().compile()`` only with
+        telemetry or the AOT cache on; plain-jit variants have no
+        inspectable executable and are left out."""
+        return [
+            entry[0].as_text()
+            for entry in self._cache.values()
+            if hasattr(entry[0], "as_text")
+        ]
+
     # -- state threading -----------------------------------------------------
     def _collect_state(self) -> dict:
         acc = self.accelerator

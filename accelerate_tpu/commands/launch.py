@@ -283,6 +283,16 @@ def multihost_launcher(args) -> None:
     num_processes = args.num_processes
     port = args.main_process_port or 29500
     coordinator = f"127.0.0.1:{port}"
+    platforms = os.environ.get("JAX_PLATFORMS", "cpu")
+    if platforms != "cpu" and not args.cpu:
+        raise ValueError(
+            f"--num_processes {num_processes} on one machine is the CPU "
+            f"simulation, but JAX_PLATFORMS={platforms!r} sends the workers "
+            "to an accelerator.  A chip belongs to one process at a time: on "
+            "a TPU host ONE process drives all local chips (launch without "
+            "--num_processes; shard with --dp_size/--fsdp_size/...).  For "
+            "the simulation pass --cpu or set JAX_PLATFORMS=cpu."
+        )
 
     cmd = []
     if args.module:
@@ -306,7 +316,7 @@ def multihost_launcher(args) -> None:
         processes = []
         for rank in range(num_processes):
             env = prepare_multihost_worker_env(args, rank, num_processes, coordinator)
-            env.setdefault("JAX_PLATFORMS", "cpu")
+            env["JAX_PLATFORMS"] = "cpu"
             processes.append(subprocess.Popen(cmd, env=env))
         try:
             while processes:

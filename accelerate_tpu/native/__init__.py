@@ -9,8 +9,9 @@ chunked parallel checkpoint IO.
 
 Binding strategy (no pybind11 in the image): a plain ``extern "C"`` ABI
 loaded with ctypes.  The .so is built on demand with g++ the first time it
-is needed, cached next to the source, and keyed by source mtime + ABI probe
-so edits rebuild automatically.  Everything here degrades gracefully:
+is needed, cached next to the source, and keyed by a hash of the source
+(recorded beside it) + ABI probe, so what loads is always built from the
+``fastloader.cc`` that is there.  Everything here degrades gracefully:
 
 * ``ACCELERATE_TPU_NO_NATIVE=1`` disables the library entirely;
 * missing g++ / failed compile / load error → ``available()`` is False and
@@ -29,6 +30,7 @@ import numpy as np
 
 _SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "src", "fastloader.cc")
 _SO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "src", "_fastloader.so")
+_SO_HASH = _SO + ".sha256"  # hash of the source the library was built from
 _ABI_VERSION = 1
 
 _lock = threading.Lock()
@@ -53,10 +55,30 @@ def _cap_threads(threads: int | None, total_bytes: int) -> int:
     return max(1, min(t, total_bytes // _MIN_BYTES_PER_THREAD or 1))
 
 
+def _source_hash() -> str:
+    import hashlib
+
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
 def _build() -> str | None:
-    """Compile the .so if missing/stale; returns an error string on failure."""
+    """Compile the .so unless the one on disk was built from exactly this
+    source; returns an error string on failure.
+
+    Staleness is keyed on a hash of ``fastloader.cc`` recorded beside the
+    library, not on mtimes: the .so is git-ignored and travels with copies
+    of the tree (a chip machine, a CI checkout), where copied mtimes say
+    nothing about which source it was built from.
+    """
     try:
-        if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
+        want = _source_hash()
+        try:
+            with open(_SO_HASH, encoding="utf-8") as f:
+                have = f.read().strip()
+        except OSError:
+            have = None
+        if have == want and os.path.exists(_SO):
             return None
         # per-process tmp name: concurrent first-use builds (pytest-xdist,
         # data workers) must not interleave linker output on a shared path
@@ -69,6 +91,12 @@ def _build() -> str | None:
         if proc.returncode != 0:
             return f"g++ failed: {proc.stderr[-500:]}"
         os.replace(tmp, _SO)
+        # the hash lands after the library: a crash between the two leaves
+        # a stale hash, which only costs a rebuild
+        tmp_hash = f"{_SO_HASH}.{os.getpid()}.tmp"
+        with open(tmp_hash, "w", encoding="utf-8") as f:
+            f.write(want + "\n")
+        os.replace(tmp_hash, _SO_HASH)
         return None
     except (OSError, subprocess.SubprocessError) as e:  # g++ missing, RO fs, ...
         return f"build error: {e}"

@@ -198,6 +198,25 @@ def fingerprint_mismatch(stored: Optional[dict], live: dict) -> str:
     return "fingerprint mismatch: " + "; ".join(moved)
 
 
+def _deserialize(entry: dict, devices):
+    """A stored executable, loaded onto ``devices`` — the live mesh's, in
+    mesh order.  Left to itself jax loads onto every device of the backend,
+    which gives a program compiled for a sub-mesh (a shrunk fleet's dp=4 of
+    8 devices) the wrong shard count: the dispatch then dies with "Expected
+    args to execute_sharded_on_local_devices to have 8 shards, got 4".
+    ``None`` (no mesh pinned) keeps jax's default.
+
+    ``entry`` must come from a fingerprint-matched lookup (``lookup`` /
+    ``warm``): loading skips trace AND compile, so nothing below the caller
+    re-validates the program against this process's topology."""
+    from jax.experimental import serialize_executable
+
+    return serialize_executable.deserialize_and_load(
+        entry["payload"], entry["in_tree"], entry["out_tree"],
+        execution_devices=devices,
+    )
+
+
 def _atomic_write_bytes(path: str, data: bytes) -> None:
     """Write-then-rename so concurrent multi-host writers never tear an
     entry; the temp file lives in the same dir (rename must not cross
@@ -244,6 +263,7 @@ class AOTCompilationCache:
         self._prefetched: dict[str, bytes] = {}
         self._telemetry = None
         self._fingerprint: Optional[dict] = None
+        self._execution_devices = None  # the pinned mesh's devices (set_context)
         if not self.enabled:
             return
         try:
@@ -268,15 +288,13 @@ class AOTCompilationCache:
                 )
             else:
                 # second layer (SNIPPETS.md [2]): jax's own persistent XLA
-                # compilation cache catches programs outside the capture path
-                try:
-                    import jax
+                # compilation cache catches programs outside the capture
+                # path.  Placed by the one rule (docs/aot_cache.md §compile
+                # cache placement): $JAX_COMPILATION_CACHE_DIR wins over
+                # this knob, so a cache placed from outside stays put
+                from ..utils.environment import enable_compilation_cache
 
-                    jax.config.update(
-                        "jax_compilation_cache_dir", handler.jax_cache_dir
-                    )
-                except Exception as exc:
-                    logger.warning("jax compilation cache dir not set: %s", exc)
+                enable_compilation_cache(handler.jax_cache_dir)
 
     # -- telemetry -----------------------------------------------------------
     def attach_telemetry(self, hub) -> None:
@@ -388,6 +406,9 @@ class AOTCompilationCache:
         if self.enabled:
             self._fingerprint = topology_fingerprint(
                 mesh=mesh, compression=compression, kernels=kernels, plan=plan
+            )
+            self._execution_devices = (
+                list(mesh.devices.flat) if mesh is not None else None
             )
 
     def fingerprint(self) -> dict:
@@ -546,10 +567,7 @@ class AOTCompilationCache:
             # a serialized program that cannot deserialize here would only
             # ever produce downstream loud misses, so refuse it now and
             # keep the run on its in-memory compiled object
-            probe = pickle.loads(blob)
-            serialize_executable.deserialize_and_load(
-                probe["payload"], probe["in_tree"], probe["out_tree"]
-            )
+            _deserialize(pickle.loads(blob), self._execution_devices)
             _atomic_write_bytes(pkl_path, blob)
             _atomic_write_json(
                 meta_path,
@@ -734,11 +752,7 @@ class AOTCompilationCache:
                 )
                 return None, None
         try:
-            from jax.experimental import serialize_executable
-
-            compiled = serialize_executable.deserialize_and_load(
-                entry["payload"], entry["in_tree"], entry["out_tree"]
-            )
+            compiled = _deserialize(entry, self._execution_devices)
         except Exception as exc:
             self.record_miss(
                 "train", key_id(key),
@@ -866,8 +880,6 @@ class AOTServingPrograms:
             return 0
         live = self.cache.fingerprint()
         fp_digest = _digest(live)
-        from jax.experimental import serialize_executable
-
         for meta_path in glob.glob(
             os.path.join(self.cache.cache_dir, f"*-{fp_digest}.json")
         ):
@@ -886,9 +898,7 @@ class AOTServingPrograms:
             try:
                 with open(pkl_path, "rb") as f:
                     entry = pickle.loads(f.read())
-                compiled = serialize_executable.deserialize_and_load(
-                    entry["payload"], entry["in_tree"], entry["out_tree"]
-                )
+                compiled = _deserialize(entry, self.cache._execution_devices)
             except Exception as exc:
                 self.cache.record_miss(
                     "serving", str(meta.get("sig")),

@@ -39,6 +39,7 @@ from typing import Optional
 __all__ = [
     "KernelPolicy",
     "KERNEL_NAMES",
+    "TPU_REFUSED",
     "resolve_kernel_policy",
     "current_kernel_policy",
     "_set_active_kernels",
@@ -47,6 +48,25 @@ __all__ = [
 
 # the three hot-path fusions, in the order ROADMAP names them
 KERNEL_NAMES = ("collective_matmul", "quantized_rs", "paged_attention")
+
+# Kernels the TPU compiler refuses today, with its own words (asked of it
+# for a described v5e:2x2 — tests/test_tpu_compile.py keeps each message
+# true; docs/kernels.md has the shapes; ROADMAP D1 the follow-up).  Arming
+# one on a TPU backend raises this instead of ever interpreting it there.
+# ``collective_matmul`` is not here: the policy routes only the ring
+# all-gather (shard_map + ppermute, no Pallas body), which compiles.
+TPU_REFUSED = {
+    "quantized_rs": (
+        "inside the captured step the kernel is called on a dp-sharded "
+        "array, and the lowering refuses: 'Mosaic kernels cannot be "
+        "automatically partitioned. Please wrap the call in a shard_map.'"
+    ),
+    "paged_attention": (
+        "the attend math (models.generation.cached_attention, grouped "
+        "einsums) does not lower: \"'tpu.matmul' op Not implemented: Up to "
+        "1 batch dim supported\""
+    ),
+}
 
 
 class KernelPolicy:
@@ -78,13 +98,31 @@ class KernelPolicy:
 
     @property
     def interpret(self) -> bool:
-        if self._interpret is None:
-            try:
-                import jax
+        """The lowering mode — and the gate every armed kernel passes, since
+        each call site asks it: on a TPU backend a kernel compiles as Mosaic
+        or arming raises; it never interprets there."""
+        import jax
 
-                self._interpret = jax.default_backend() != "tpu"
-            except Exception:
-                self._interpret = True
+        # a backend that cannot be asked raises here: falling to the
+        # interpreter would run a TPU's kernels interpreted, unnoticed
+        on_tpu = jax.default_backend() == "tpu"
+        if on_tpu:
+            if self._interpret:
+                raise ValueError(
+                    "KernelKwargs(interpret=True) on a TPU backend: the "
+                    "Pallas interpreter is for CPU verification only"
+                )
+            refused = [n for n in self.armed() if n in TPU_REFUSED]
+            if refused:
+                raise NotImplementedError(
+                    "; ".join(
+                        f"kernel {n!r} does not compile for TPU: {TPU_REFUSED[n]}"
+                        for n in refused
+                    )
+                    + " (docs/kernels.md; ROADMAP D1)"
+                )
+        if self._interpret is None:
+            self._interpret = not on_tpu
         return self._interpret
 
     def armed(self) -> tuple:

@@ -37,7 +37,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental import shard_map
+
+from ...parallel.mesh import shard_map_compat
 
 __all__ = [
     "collective_matmul",
@@ -96,9 +97,7 @@ def ring_all_gather(arr, sharding, axis: int, *, axis_name: str = "dp"):
     body = functools.partial(
         _ring_gather_local, n=n, axis=axis, axis_name=axis_name
     )
-    return shard_map.shard_map(
-        body, mesh=mesh, in_specs=in_spec, out_specs=out_spec, check_rep=False
-    )(arr)
+    return shard_map_compat(body, mesh, in_spec, out_spec)(arr)
 
 
 def _padded_spec(spec, ndim: int) -> jax.sharding.PartitionSpec:
@@ -118,7 +117,7 @@ def zero1_gather_eligible(sharding, axis, *, axis_name: str = "dp") -> bool:
     return sharding.mesh.shape.get(axis_name, 1) > 1
 
 
-def zero1_all_gather(arr, sharding, axis: int, *, interpret: bool = True):
+def zero1_all_gather(arr, sharding, axis: int, *, interpret: bool):
     """The ZeRO-1 writeback wire: ``Optimizer.step`` hands the updated
     param (already cast to the param dtype, still on the dp-sharded state
     layout) to this instead of the GSPMD layout constraint when the kernel
@@ -189,6 +188,16 @@ def _cm_rdma_kernel(x_ref, w_ref, o_ref, comm_buf, partials, send_sem,
 
     my_id = jax.lax.axis_index(axis_name)
     right = (my_id + 1) % n_devices
+    left = (my_id + n_devices - 1) % n_devices
+    # both neighbours must be inside the kernel before a chunk lands in
+    # their comm buffer (what collective_id in the compiler params is for)
+    barrier = pltpu.get_barrier_semaphore()
+    for neighbour in (left, right):
+        pltpu.semaphore_signal(
+            barrier, inc=1, device_id={axis_name: neighbour},
+            device_id_type=pltpu.DeviceIdType.MESH,
+        )
+    pltpu.semaphore_wait(barrier, 2)
     comm_buf[0] = w_ref[:]
     for hop in range(n_devices):
         slot = hop % 2
@@ -198,8 +207,12 @@ def _cm_rdma_kernel(x_ref, w_ref, o_ref, comm_buf, partials, send_sem,
                 dst_ref=comm_buf.at[(hop + 1) % 2],
                 send_sem=send_sem.at[slot],
                 recv_sem=recv_sem.at[(hop + 1) % 2],
-                device_id=(right,),
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
+                # the neighbour along the ring's mesh axis, every other
+                # mesh coordinate kept — a LOGICAL id would be the global
+                # device number, which an axis index is not on a mesh of
+                # more than one axis
+                device_id={axis_name: right},
+                device_id_type=pltpu.DeviceIdType.MESH,
             )
             rdma.start()
         src = (my_id - hop) % n_devices
@@ -217,10 +230,8 @@ def _cm_rdma_kernel(x_ref, w_ref, o_ref, comm_buf, partials, send_sem,
 def _cm_tpu_body(x_full, w_shard, *, n: int, axis_name: str):
     from jax.experimental.pallas import tpu as pltpu
 
-    # jax 0.4.x spells it TPUCompilerParams; newer releases CompilerParams.
     # collective_id sequences the RDMA ring; no has_side_effects needed —
     # the kernel has a real output, so it cannot be DCE'd.
-    params_cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
     kc, nc = w_shard.shape
     return pl.pallas_call(
         functools.partial(
@@ -233,13 +244,14 @@ def _cm_tpu_body(x_full, w_shard, *, n: int, axis_name: str):
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
-        compiler_params=params_cls(collective_id=0),
+        compiler_params=pltpu.CompilerParams(collective_id=0),
         interpret=False,
+        name="collective_matmul_rdma",
     )(x_full, w_shard)
 
 
 def collective_matmul(x, w, *, mesh, axis_name: str = "dp",
-                      interpret: bool = True):
+                      interpret: bool):
     """``x @ w`` where ``w`` arrives sharded along its contraction (first)
     axis over ``axis_name`` and ``x`` is replicated — WITHOUT ever
     materializing the gathered ``w``.
@@ -263,13 +275,7 @@ def collective_matmul(x, w, *, mesh, axis_name: str = "dp",
         axis_name=axis_name,
         **({"interpret": True} if interpret else {}),
     )
-    return shard_map.shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(P(), P(axis_name, None)),
-        out_specs=P(),
-        check_rep=False,
-    )(x, w)
+    return shard_map_compat(body, mesh, (P(), P(axis_name, None)), P())(x, w)
 
 
 def reference_collective_matmul(x, w):

@@ -36,6 +36,8 @@ import re
 import jax
 import jax.numpy as jnp
 
+from ...parallel.mesh import make_mesh
+
 __all__ = [
     "stablehlo_text",
     "jaxpr_text",
@@ -80,7 +82,7 @@ def check_collective_matmul(mesh=None, *, m: int = 8, k_chunk: int = 8,
     from .collective_matmul import collective_matmul, reference_collective_matmul
 
     if mesh is None:
-        mesh = jax.make_mesh((len(jax.devices()),), ("dp",))
+        mesh = make_mesh({"dp": len(jax.devices())}, axis_order=("dp",))
     n = mesh.shape["dp"]
     P = jax.sharding.PartitionSpec
     x = jnp.ones((m, k_chunk * n), jnp.float32)
@@ -219,7 +221,10 @@ def check_pipeline_layout(mesh=None, *, num_stages: int = 2, virtual: int = 3,
     from ...parallel.plan import _layer_orders
 
     if mesh is None:
-        mesh = jax.make_mesh((num_stages,), ("pp",))
+        mesh = make_mesh(
+            {"pp": num_stages}, devices=jax.devices()[:num_stages],
+            axis_order=("pp",),
+        )
     S, V, L = num_stages, virtual, num_layers
     ks = jax.random.split(jax.random.key(0), L)
     plain = {

@@ -36,14 +36,17 @@ def _paged_attn_kernel(table_ref, pos_ref, q_ref, kp_ref, vp_ref, o_ref,
                        interpret: bool):
     from ...models.generation import cached_attention
 
-    row = table_ref[0]  # (blocks_per_slot,) — this slot's block-table row
-    p_s = pos_ref[0]
+    # the block table and the positions are scalar-prefetched (SMEM): the
+    # walk reads this slot's row entry by entry, as addresses, not as a
+    # vector block
+    slot = pl.program_id(0)
+    p_s = pos_ref[slot]
     if interpret:
         # interpreter lowering: dynamic-index loads walk the table; each
         # page lands in scratch one block at a time — no batched gather
         for j in range(bps):
-            k_scratch[j] = kp_ref[row[j]]
-            v_scratch[j] = vp_ref[row[j]]
+            k_scratch[j] = kp_ref[table_ref[slot, j]]
+            v_scratch[j] = vp_ref[table_ref[slot, j]]
     else:
         from jax.experimental.pallas import tpu as pltpu
 
@@ -54,10 +57,10 @@ def _paged_attn_kernel(table_ref, pos_ref, q_ref, kp_ref, vp_ref, o_ref,
             # reference's gathered padding)
             for j in range(bps):
                 kd = pltpu.make_async_copy(
-                    kp_ref.at[row[j]], k_scratch.at[j], sems.at[0]
+                    kp_ref.at[table_ref[slot, j]], k_scratch.at[j], sems.at[0]
                 )
                 vd = pltpu.make_async_copy(
-                    vp_ref.at[row[j]], v_scratch.at[j], sems.at[1]
+                    vp_ref.at[table_ref[slot, j]], v_scratch.at[j], sems.at[1]
                 )
                 kd.start()
                 vd.start()
@@ -78,7 +81,7 @@ def _paged_attn_kernel(table_ref, pos_ref, q_ref, kp_ref, vp_ref, o_ref,
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, positions, *, cfg,
-                    interpret: bool = True):
+                    interpret: bool):
     """Attend the whole slot batch one token against the paged KV pool.
 
     ``q: (slots, H, 1, d)``; ``k_pool/v_pool: (num_blocks, n_kv, bs, d)``
@@ -86,44 +89,36 @@ def paged_attention(q, k_pool, v_pool, block_tables, positions, *, cfg,
     ``block_tables: (slots, blocks_per_slot)``; ``positions: (slots,)``.
     Returns ``(slots, H, 1, d)`` in the pool dtype, bitwise-equal to the
     reference gather-then-attend."""
+    from jax.experimental.pallas import tpu as pltpu
+
     slots, n_heads, _, d = q.shape
     bps = block_tables.shape[1]
     kernel = functools.partial(
         _paged_attn_kernel, bps=bps, cfg=cfg, interpret=interpret
     )
-    pool_spec_space = {}
-    scratch_dtype = k_pool.dtype
-    if not interpret:
-        from jax.experimental.pallas import tpu as pltpu
-
+    if interpret:
+        pool_spec = pl.BlockSpec(k_pool.shape, lambda i, t, p: (0, 0, 0, 0))
+    else:
         # TPU: pools are far too big for VMEM — leave them where they live
         # and DMA pages on demand (the whole point of the walk)
-        pool_spec_space = {"memory_space": pltpu.ANY}
+        pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    q_spec = pl.BlockSpec((1, n_heads, 1, d), lambda i, t, p: (i, 0, 0, 0))
     return pl.pallas_call(
         kernel,
-        grid=(slots,),
-        in_specs=[
-            pl.BlockSpec((1, bps), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1, n_heads, 1, d), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec(k_pool.shape, lambda i: (0, 0, 0, 0), **pool_spec_space),
-            pl.BlockSpec(v_pool.shape, lambda i: (0, 0, 0, 0), **pool_spec_space),
-        ],
-        out_specs=pl.BlockSpec((1, n_heads, 1, d), lambda i: (i, 0, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,  # block_tables, positions
+            grid=(slots,),
+            in_specs=[q_spec, pool_spec, pool_spec],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((bps,) + k_pool.shape[1:], k_pool.dtype),
+                pltpu.VMEM((bps,) + v_pool.shape[1:], v_pool.dtype),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((slots, n_heads, 1, d), v_pool.dtype),
-        scratch_shapes=[
-            _scratch((bps,) + k_pool.shape[1:], scratch_dtype, interpret),
-            _scratch((bps,) + v_pool.shape[1:], scratch_dtype, interpret),
-        ],
         interpret=interpret,
+        name="paged_attention",
     )(block_tables, positions, q, k_pool, v_pool)
-
-
-def _scratch(shape, dtype, interpret: bool):
-    from jax.experimental.pallas import tpu as pltpu
-
-    del interpret  # VMEM scratch lowers on both paths (interpreter emulates)
-    return pltpu.VMEM(shape, dtype)
 
 
 def reference_paged_attention(q, k_pool, v_pool, block_tables, positions, *,

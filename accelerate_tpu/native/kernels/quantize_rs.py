@@ -30,6 +30,7 @@ cross the dp boundary narrow during accumulation without systematic drift
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +46,54 @@ __all__ = [
 ]
 
 
+# One block's fp32 bytes.  The input and output windows are double-buffered
+# and the body keeps a few block-sized fp32 temporaries live, about seven
+# copies in all, against a 16 MiB scoped-VMEM limit: a grid-less call over a
+# 768×3072 leaf asked for 27 MB there, over the 50304×768 embedding for 154.
+_BLOCK_BYTES = 1 << 20
+
+
+def _block_len(shape: tuple, axis: int):
+    """Block length along ``axis`` for a leaf too big for one VMEM window,
+    else ``None`` (whole array, no grid).  Aligned to the TPU tile: 128 on
+    the last dimension, 8 on the one before it."""
+    total = 4 * math.prod(shape)
+    if total <= _BLOCK_BYTES or len(shape) < 2:
+        return None
+    align = {len(shape) - 1: 128, len(shape) - 2: 8}.get(axis, 1)
+    step = _BLOCK_BYTES // (total // shape[axis]) // align * align
+    step = max(step, align)
+    return step if step < shape[axis] else None
+
+
+def _call_over_axis_blocks(kernel, *operands, axis: int, interpret: bool, name: str):
+    """``pallas_call`` of a kernel whose scales are per index of ``axis``:
+    slices along ``axis`` never see each other, so a grid over blocks of
+    that axis computes exactly what the whole-array call does (a ragged
+    last block reads padding that only ever scales itself, and its writes
+    past the edge are dropped)."""
+    x = operands[0]
+    out_shape = jax.ShapeDtypeStruct(x.shape, jnp.float32)
+    step = _block_len(x.shape, axis)
+    if step is None:
+        return pl.pallas_call(
+            kernel, out_shape=out_shape, interpret=interpret, name=name
+        )(*operands)
+    block = tuple(step if i == axis else d for i, d in enumerate(x.shape))
+    spec = pl.BlockSpec(
+        block, lambda b: tuple(b if i == axis else 0 for i in range(x.ndim))
+    )
+    return pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(x.shape[axis], step),),
+        in_specs=[spec] * len(operands),
+        out_specs=spec,
+        out_shape=out_shape,
+        interpret=interpret,
+        name=name,
+    )(*operands)
+
+
 def _qdq_kernel(x_ref, o_ref, *, axis: int, wire_dtype):
     """One region: per-block amax → scale → round/clip → narrow → widen —
     by calling the reference's own ``compress.quantize``/``dequantize`` on
@@ -56,19 +105,17 @@ def _qdq_kernel(x_ref, o_ref, *, axis: int, wire_dtype):
     o_ref[:] = dequantize(payload, scales)
 
 
-def fused_quantize_dequantize(x, axis: int, wire_dtype, *, interpret: bool = True):
+def fused_quantize_dequantize(x, axis: int, wire_dtype, *, interpret: bool):
     """``x`` (fp32) → the wire value (fp32, same shape): what the far side
     of the quantized reduce-scatter reconstructs, computed in one kernel."""
     kernel = functools.partial(_qdq_kernel, axis=axis, wire_dtype=wire_dtype)
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
-        interpret=interpret,
-    )(x)
+    return _call_over_axis_blocks(
+        kernel, x, axis=axis, interpret=interpret, name="quantize_dequantize"
+    )
 
 
 def fused_reduce_scatter(x32, sharding, axis: int, err, policy, *,
-                         interpret: bool = True):
+                         interpret: bool):
     """Drop-in for :meth:`CompressionPolicy.reduce_scatter` with the wire
     computed by the fused kernel.  Returns ``(g_used, err_new)`` with the
     identical contract — and identical bits: the residual update
@@ -102,21 +149,20 @@ def _sr_kernel(x_ref, u_ref, o_ref, *, axis: int, qmax: float):
     o_ref[:] = payload.astype(jnp.float32) * scales
 
 
-def stochastic_quantize_dequantize(x, axis: int, key, *, interpret: bool = True):
+def stochastic_quantize_dequantize(x, axis: int, key, *, interpret: bool):
     """Stochastically-rounded int8 wire value of ``x``: deterministic for a
     fixed ``key`` (replay-stable under capture — the key threads through
     the captured RNG state), unbiased across keys."""
     u = jax.random.uniform(key, x.shape, jnp.float32)
     kernel = functools.partial(_sr_kernel, axis=axis, qmax=_qmax(jnp.int8))
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
-        interpret=interpret,
-    )(x, u)
+    return _call_over_axis_blocks(
+        kernel, x, u, axis=axis, interpret=interpret,
+        name="stochastic_quantize_dequantize",
+    )
 
 
 def zero2_stochastic_wire(grad, sharding, axis: int, key, *,
-                          interpret: bool = True):
+                          interpret: bool):
     """The ZeRO-2 mid-accumulation scatter, narrow: stochastic int8 wire +
     the same layout constraint ``compress.shard_accumulation`` applies.
 

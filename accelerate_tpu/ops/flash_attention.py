@@ -36,13 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pallas TPU backend is absent on CPU builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from .attention import sdpa_reference
 
@@ -57,8 +51,11 @@ DEFAULT_BWD_BLOCK_K = int(os.environ.get("ACCELERATE_TPU_FLASH_BWD_BLOCK_K", 102
 _LANES = 128  # TPU lane count: last-dim tile width for every dtype
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
-# interpret-mode escape hatch so the kernels are testable on CPU CI
-_INTERPRET = False
+def _interpret() -> bool:
+    """Mosaic on a TPU backend, the Pallas interpreter anywhere else — the
+    only way these kernels can run off-TPU (the CPU tests, and
+    ``ACCELERATE_TPU_FLASH=1`` there).  Never the interpreter on a TPU."""
+    return jax.default_backend() != "tpu"
 
 
 def _compiler_params(semantics=("parallel", "parallel", "arbitrary")):
@@ -70,7 +67,7 @@ def _compiler_params(semantics=("parallel", "parallel", "arbitrary")):
     on v5e.  Dimensions that carry accumulator state across iterations
     (scratch or revisited output blocks) MUST be "arbitrary".
     """
-    if not _HAS_PLTPU or _INTERPRET:
+    if _interpret():
         return None
     return pltpu.CompilerParams(
         dimension_semantics=semantics,
@@ -336,7 +333,8 @@ def _flash_forward(
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=_INTERPRET,
+        interpret=_interpret(),
+        name="flash_fwd",
         compiler_params=_compiler_params(),
     )(_offsets_arr(q_offset, k_offset), q3, k3, v3)
     if return_lse:
@@ -621,7 +619,8 @@ def _flash_backward_window(q3, k3, v3, do3, lse3, delta3, scale, block,
             pltpu.VMEM((block, d), jnp.float32),
             pltpu.VMEM((block, d), jnp.float32),
         ],
-        interpret=_INTERPRET,
+        interpret=_interpret(),
+        name="flash_bwd_dkv_window",
         # ki carries no loop state here (scratch re-zeroed at qr==0, one
         # output write per ki) — parallel is safe and pipelines
         compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
@@ -644,7 +643,8 @@ def _flash_backward_window(q3, k3, v3, do3, lse3, delta3, scale, block,
         out_specs=q_fixed,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), dtype_q),
         scratch_shapes=[pltpu.VMEM((block, d), jnp.float32)],
-        interpret=_INTERPRET,
+        interpret=_interpret(),
+        name="flash_bwd_dq_window",
         compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
     )(offs, q3, k3, v3, do3, lse3, delta3)
     return dq3, dk3, dv3
@@ -757,7 +757,8 @@ def _flash_backward(
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        interpret=_INTERPRET,
+        interpret=_interpret(),
+        name="flash_bwd",
         # ki carries the dq scratch, qi carries the dk/dv scratch: both are
         # loop-carried, only bh is safe to parallelize
         compiler_params=_compiler_params(("parallel", "arbitrary", "arbitrary")),

@@ -68,7 +68,7 @@ def _use_flash_hops(chunk: int, d: int) -> bool:
 
     if _FORCE_FLASH_HOPS:
         return True
-    return _on_tpu(None) and chunk % 128 == 0 and d in _MXU_HEAD_DIMS
+    return _on_tpu() and chunk % 128 == 0 and d in _MXU_HEAD_DIMS
 
 
 def _ring_hops(k, v, carry0, do_step, *, axis_name: str, is_causal: bool,

@@ -21,27 +21,12 @@ from jax.sharding import Mesh
 from ..utils.constants import ALL_MESH_AXES
 
 
-# API detection ONCE at import (not per-call exception probing, which would
-# mask genuine caller errors by silently retrying on the legacy path)
-_HAS_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-
-
 def shard_map_compat(f, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across the 0.8 API move.
-
-    jax>=0.8 exposes keyword-only ``jax.shard_map`` with ``check_vma``;
-    the old ``jax.experimental.shard_map`` used ``check_rep``.  One shim so
-    every caller (pipeline schedules, ring attention, tests) follows the
-    same path and the deprecation never prints.
-    """
-    if _HAS_NEW_SHARD_MAP:
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
-        )
-    from jax.experimental.shard_map import shard_map as _legacy
-
-    return _legacy(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
+    """The package's one ``jax.shard_map`` call (pipeline schedules, ring
+    attention, the flash kernel on a mesh, the collective-matmul ring,
+    tests): keyword-only API, ``check_vma`` off unless asked."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
     )
 
 
@@ -68,15 +53,17 @@ def make_mesh(
             f"mesh axis sizes {dict(zip(axis_order, sizes))} require {total} "
             f"devices, have {len(devices)}"
         )
-    try:
+    if devices[0].platform == "cpu":
+        # virtual host devices have no topology to map onto
+        device_array = np.asarray(list(devices)).reshape(tuple(sizes))
+    else:
+        # a real topology this cannot lay out is an error to see, not a
+        # reshape that quietly puts tp/sp neighbours on distant chips
         from jax.experimental import mesh_utils
 
         device_array = mesh_utils.create_device_mesh(
             tuple(sizes), devices=list(devices)
         )
-    except Exception:
-        # CPU simulation or exotic topologies: plain reshape is fine.
-        device_array = np.asarray(list(devices)).reshape(tuple(sizes))
     return Mesh(device_array, axis_names=tuple(axis_order))
 
 

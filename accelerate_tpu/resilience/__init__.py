@@ -4,8 +4,9 @@ Four pillars, all default-OFF (off = byte-identical capture hot path, one
 ``None``-check, matching the telemetry precedent):
 
 1. **Hardened backend init** (`backend.py`) — subprocess-isolated PJRT probe
-   with retry/backoff/jitter and an ordered platform fallback chain, emitting
-   a structured :class:`~.backend.InitReport`.
+   with retry/backoff/jitter and an opt-in platform fallback chain (none by
+   default: the requested platform comes up or the init fails), emitting a
+   structured :class:`~.backend.InitReport`.
 2. **Preemption-safe checkpointing** (`preemption.py`) — SIGTERM/SIGINT set a
    sticky flag read via ``resilience.should_save`` / ``should_exit``
    (``check_trigger()``-style, collective on multi-process);

@@ -4,7 +4,7 @@ A captured-step dispatch can fail for two very different reasons and they
 must not be handled alike:
 
 * **transient runtime faults** — the PJRT/XLA runtime path to the device
-  hiccuped (UNAVAILABLE, DEADLINE_EXCEEDED, a dropped tunnel connection).
+  hiccuped (UNAVAILABLE, DEADLINE_EXCEEDED, a dropped connection).
   The program and its inputs are fine; trying again is both safe and the
   right move.  Safe because of the donation guarantee the capture layer
   already relies on (capture.py ``_dispatch_aot``): argument validation —

@@ -146,7 +146,7 @@ def _decode_body(
     qbits: int,
     temperature: float,
     paged: bool = False,  # paged-attention kernel (docs/kernels.md)
-    kernel_interpret: bool = True,
+    kernel_interpret: Optional[bool] = None,
 ):
     """ONE token for the whole slot batch — the micro-step body shared by
     every ``decode_steps`` variant, so an n-token block is bitwise the same
@@ -180,7 +180,15 @@ def _decode_body(
         if paged:
             # paged-attention kernel (docs/kernels.md): walk the block table
             # in VMEM instead of materializing each slot's full page span —
-            # per-slot logits bitwise-identical to the gather path below
+            # per-slot logits bitwise-identical to the gather path below.
+            # The lowering mode has no default here: it comes from the
+            # KernelPolicy (run_decode*), so no caller can forget it and
+            # interpret a TPU's kernel
+            if kernel_interpret is None:
+                raise ValueError(
+                    "paged=True needs kernel_interpret from the KernelPolicy "
+                    "(KernelPolicy.interpret); it has no default"
+                )
             from ..native.kernels.paged_attention import paged_attention
 
             att = paged_attention(
@@ -241,7 +249,7 @@ def _decode_jit(
     qbits: int,
     temperature: float,
     paged: bool = False,
-    kernel_interpret: bool = True,
+    kernel_interpret: Optional[bool] = None,
 ):
     """The classic single-token program — ``_decode_body`` jitted with the
     SAME signature, donation split and outputs the service has always
@@ -284,7 +292,7 @@ def _decode_n_jit(
     temperature: float,
     decode_steps: int = 1,
     paged: bool = False,
-    kernel_interpret: bool = True,
+    kernel_interpret: Optional[bool] = None,
 ):
     """``decode_steps`` micro-steps of ``_decode_body`` in one captured
     program: the sampled token feeds the next embed and positions advance

@@ -55,7 +55,9 @@ from .fsdp_utils import (
 from .environment import (
     are_libraries_initialized,
     clear_environment,
+    compilation_cache_dir,
     convert_dict_to_env_variables,
+    enable_compilation_cache,
     get_int_from_env,
     parse_choice_from_env,
     parse_flag_from_env,

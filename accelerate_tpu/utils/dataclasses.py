@@ -453,7 +453,9 @@ class CompilationCacheKwargs(KwargsHandler):
     path) so restore-after-fault replays the serialized executable without a
     step-path disk read.  ``jax_cache_dir`` additionally arms jax's own
     persistent XLA compilation cache (``$ACCELERATE_AOT_CACHE_JAX_DIR``) as
-    a second layer for programs outside the capture path.
+    a second layer for programs outside the capture path — at that
+    directory unless ``$JAX_COMPILATION_CACHE_DIR`` is set, which wins
+    (``utils.environment.compilation_cache_dir``, the one placement rule).
     """
 
     cache_dir: Optional[str] = None  # None → $ACCELERATE_AOT_CACHE, unset = off

@@ -75,6 +75,44 @@ def patch_environment(**kwargs: Any):
                 os.environ.pop(key, None)
 
 
+# the source checkout (or install prefix) this package lives in
+_CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+
+
+def compilation_cache_dir(preferred: str | None = None) -> str:
+    """Where JAX's persistent compilation cache lives — the ONE placement
+    rule (docs/aot_cache.md §compile cache placement).
+
+    ``$JAX_COMPILATION_CACHE_DIR`` wins whenever it is set, so whoever runs
+    the program (a chip tool, a CI driver, a fleet launcher) places the
+    cache from outside and nothing in code moves it.  Unset, a caller's
+    ``preferred`` directory (``CompilationCacheKwargs.jax_cache_dir``) is
+    used, else the fixed ``<checkout>/.jax_cache`` — never a temp, pid or
+    timestamp path: the directory is part of what a later process must find
+    again, so a directory that moves never hits.
+    """
+    return (
+        os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        or preferred
+        or os.path.join(_CHECKOUT, ".jax_cache")
+    )
+
+
+def enable_compilation_cache(preferred: str | None = None) -> str:
+    """Arm JAX's persistent compilation cache at :func:`compilation_cache_dir`
+    and return the directory.  The choice is exported to the environment so
+    child processes (launch workers, smoke subprocesses) share the cache."""
+    import jax
+
+    path = compilation_cache_dir(preferred)
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = path
+    if jax.config.jax_compilation_cache_dir != path:
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def get_tpu_worker_id() -> int:
     """Host/worker index within a TPU pod slice (0 on single host)."""
     return get_int_from_env(

@@ -567,7 +567,7 @@ def test_scope_map_persists_across_processes(tmp_path):
     ), f"warm samples lost the per-phase split: {warm['phases_per_sample']}"
 
 
-def test_jax_cache_layer_disarmed_for_scope_dependent_runs(tmp_path):
+def test_jax_cache_layer_disarmed_for_scope_dependent_runs(tmp_path, monkeypatch):
     """ROADMAP carried item, second layer: executables served by jax's OWN
     XLA compilation cache (``jax_cache_dir``) carry no HLO metadata and no
     side payload to persist a scope map in — a device-time-sampling run
@@ -584,6 +584,10 @@ def test_jax_cache_layer_disarmed_for_scope_dependent_runs(tmp_path):
 
     saved = jax.config.jax_compilation_cache_dir
     jax_dir = str(tmp_path / "jaxcache")
+    # the suite's own placement (conftest) would win over jax_cache_dir —
+    # the one rule, tested in test_compile_cache_placement.py; this test is
+    # about arming and disarming, so it runs with the environment unset
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     try:
         # a hub WITHOUT device-time sampling: the layer stays armed
         cache = AOTCompilationCache(CompilationCacheKwargs(

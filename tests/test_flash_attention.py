@@ -15,11 +15,6 @@ import accelerate_tpu.ops.flash_attention as fa
 from accelerate_tpu.ops.attention import sdpa_reference
 
 
-@pytest.fixture(autouse=True)
-def _interpret(monkeypatch):
-    monkeypatch.setattr(fa, "_INTERPRET", True)
-
-
 def _rand_qkv(b=1, h=2, s=256, d=64, dtype=jnp.float32, seed=0):
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(kq, (b, h, s, d), dtype)

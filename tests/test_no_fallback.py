@@ -1,0 +1,232 @@
+"""A missing chip is an error, not a quieter path: the places that used to
+hide the device (bench.py's CPU row under a per-chip name, the O(S²)
+attention behind a failed import, interpreted kernels behind a swallowed
+exception, a reshaped mesh behind a failed topology mapping, skip decisions
+that opened a backend at import) fail where they used to fall back."""
+
+import importlib.util
+import json
+import os
+import subprocess
+import sys
+import unittest
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def bench():
+    spec = importlib.util.spec_from_file_location("bench", os.path.join(REPO, "bench.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)  # defines only; main() is under __main__
+    return module
+
+
+# ---------------------------------------------------------------------------
+# bench.py: what it prints is true of the device it names
+# ---------------------------------------------------------------------------
+def test_unknown_device_kind_is_an_error_not_a_default_peak(bench):
+    assert bench.peak_flops("TPU v5 lite") == 197e12
+    assert "TPU v5e" in bench.PEAK_BF16_FLOPS["TPU v5 lite"][1]  # its source
+    for kind in ("cpu", "TPU v9", ""):
+        with pytest.raises(SystemExit, match="no published peak"):
+            bench.peak_flops(kind)
+    assert "BENCH_TPU_PEAK_FLOPS" not in open(os.path.join(REPO, "bench.py")).read()
+
+
+def test_bench_without_a_tpu_exits_nonzero_and_prints_no_row():
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench.py")], cwd=REPO,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode != 0
+    assert "per_chip" not in proc.stdout and "{" not in proc.stdout
+    assert "not on a TPU" in proc.stderr
+
+
+def test_a_cpu_row_is_never_printed_under_a_per_chip_name(bench, capsys):
+    row = {
+        "metric": "gpt2_small_train_tokens_per_sec_per_chip", "value": 7000.0,
+        "vs_baseline": 0.05, "mfu_pct": None,
+        "bert_mrpc_samples_per_sec_per_chip": 3.0,
+    }
+    bench._EMITTED = False
+    assert bench._emit_result(dict(row), on_accel=False) == 0
+    printed = json.loads(capsys.readouterr().out)
+    assert not any("per_chip" in str(k) or "per_chip" in str(v) for k, v in printed.items())
+    assert printed["metric"] == "gpt2_tiny_train_tokens_per_sec_cpu_rehearsal"
+    assert "vs_baseline" not in printed and printed["mfu_pct"] is None
+    # on the chip the names stand
+    bench._EMITTED = False
+    assert bench._emit_result(dict(row), on_accel=True) == 0
+    assert json.loads(capsys.readouterr().out)["metric"].endswith("_per_chip")
+
+
+def test_a_failed_phase_makes_the_exit_code_nonzero(bench, capsys):
+    result = {"metric": "gpt2_small_train_tokens_per_sec_per_chip", "value": 1.0}
+    try:
+        raise RuntimeError("serving block broke")
+    except RuntimeError as exc:
+        bench._record_failure(result, "serving", exc)
+    bench._EMITTED = False
+    assert bench._emit_result(result, on_accel=True) == 1
+    out, err = capsys.readouterr()
+    assert json.loads(out)["failed_phases"] == ["serving_error"]
+    assert "RuntimeError: serving block broke" in err  # the traceback, not just a field
+
+
+# ---------------------------------------------------------------------------
+# attention / kernels / mesh
+# ---------------------------------------------------------------------------
+def test_flash_import_failure_on_a_tpu_backend_raises(monkeypatch):
+    """On ``tpu`` a kernel that fails to import is an error; it does not
+    warn once and run the O(S²) reference."""
+    from accelerate_tpu.ops import attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setitem(sys.modules, "accelerate_tpu.ops.flash_attention", None)
+    q = jnp.zeros((1, 2, 128, 64), jnp.bfloat16)
+    with pytest.raises(ImportError):
+        attention.sdpa_tpu(q, q, q, is_causal=True)
+    # a shape the kernel cannot tile is the caller's to see, and runs the reference
+    short = jnp.zeros((1, 2, 96, 64), jnp.bfloat16)
+    assert attention.sdpa_tpu(short, short, short, is_causal=True).shape == short.shape
+
+
+def test_kernel_policy_interpret_does_not_swallow(monkeypatch):
+    """A backend that cannot be asked raises; it does not become "interpret"."""
+    from accelerate_tpu.native.kernels import KernelPolicy
+
+    def unreachable():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "default_backend", unreachable)
+    with pytest.raises(RuntimeError, match="Unable to initialize backend"):
+        KernelPolicy(collective_matmul=True).interpret
+    # and the interpreter is never forced onto a TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="CPU verification only"):
+        KernelPolicy(collective_matmul=True, interpret=True).interpret
+
+
+def test_serving_engine_has_no_interpret_default():
+    """``paged=True`` without the policy's lowering mode is an error: there
+    is no ``kernel_interpret=True`` a caller can forget to override."""
+    import inspect
+
+    from accelerate_tpu.serving import engine
+
+    for fn in (engine._decode_body, engine._decode_jit, engine._decode_n_jit):
+        target = inspect.unwrap(fn)
+        default = inspect.signature(target).parameters["kernel_interpret"].default
+        assert default is None, fn
+    source = inspect.getsource(engine._decode_body)
+    assert "kernel_interpret is None" in source and "raise ValueError" in source
+
+
+def test_mesh_keeps_the_plain_reshape_for_the_cpu_only(monkeypatch):
+    from jax.experimental import mesh_utils
+
+    from accelerate_tpu.parallel.mesh import make_mesh
+
+    def refuse(*args, **kwargs):
+        raise NotImplementedError("cannot map this topology")
+
+    monkeypatch.setattr(mesh_utils, "create_device_mesh", refuse)
+    n = len(jax.devices())
+    assert make_mesh({"dp": n}).shape["dp"] == n  # CPU: reshape, no topology asked
+
+    class FakeTpu:
+        platform = "tpu"
+
+    with pytest.raises(NotImplementedError, match="cannot map this topology"):
+        make_mesh({"dp": 4}, devices=[FakeTpu() for _ in range(4)])
+
+
+def test_local_multiprocess_launch_on_an_accelerator_host_says_why(monkeypatch):
+    from accelerate_tpu.commands.launch import launch_command_parser, multihost_launcher
+
+    args = launch_command_parser().parse_args(["--num_processes", "2", "train.py"])
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with pytest.raises(ValueError, match="ONE process drives all local chips"):
+        multihost_launcher(args)
+
+
+# ---------------------------------------------------------------------------
+# importing a test file never initialises a backend
+# ---------------------------------------------------------------------------
+def test_require_decorators_decide_when_the_test_runs(monkeypatch):
+    from accelerate_tpu.test_utils import testing
+
+    asked = []
+
+    def backend():
+        asked.append(1)
+        return "cpu"
+
+    monkeypatch.setattr(testing, "_backend", backend)
+
+    @testing.require_tpu
+    def needs_tpu():
+        return "ran"
+
+    @testing.require_cpu
+    def needs_cpu():
+        return "ran"
+
+    class Case(unittest.TestCase):
+        def test_it(self):
+            pass
+
+    testing.require_non_cpu(Case)
+    assert asked == []  # decorating asked nothing
+    with pytest.raises(unittest.SkipTest, match="requires TPU"):
+        needs_tpu()
+    assert needs_cpu() == "ran"
+    with pytest.raises(unittest.SkipTest, match="requires an accelerator"):
+        Case("test_it").setUp()
+    assert len(asked) == 3
+
+
+def test_importing_the_test_harness_opens_no_backend():
+    code = (
+        "import accelerate_tpu.test_utils as t\n"
+        "@t.require_tpu\n@t.require_multi_device\n@t.require_non_cpu\n"
+        "def test_x(): pass\n"
+        "from jax._src import xla_bridge\n"
+        "print('INITIALIZED', xla_bridge.backends_are_initialized())\n"
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", code], cwd=REPO,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "INITIALIZED False" in proc.stdout
+
+
+def test_aot_store_loads_onto_the_meshs_devices_not_the_backends():
+    """The jax 0.9 failure behind resize-prewarm: ``deserialize_and_load``
+    defaults to every device of the backend, so a program compiled for a
+    4-device sub-mesh came back expecting 8 shards."""
+    from jax.experimental import serialize_executable
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from accelerate_tpu.native.aot_cache import _deserialize
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs >= 4 devices")
+    half = jax.devices()[: len(jax.devices()) // 2]
+    sharding = NamedSharding(Mesh(np.array(half), ("dp",)), P("dp"))
+    x = jax.device_put(jnp.arange(4.0 * len(half)), sharding)
+    compiled = jax.jit(lambda a: a * 2).lower(x).compile()
+    payload, in_tree, out_tree = serialize_executable.serialize(compiled)
+    entry = {"payload": payload, "in_tree": in_tree, "out_tree": out_tree}
+    loaded = _deserialize(entry, half)
+    np.testing.assert_array_equal(np.asarray(loaded(x)), np.asarray(x) * 2)

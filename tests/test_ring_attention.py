@@ -67,10 +67,8 @@ def test_ring_attention_sp1_fallback():
 def test_ring_flash_hop_path_matches_reference(is_causal, monkeypatch):
     """The TPU hop-kernel ring path (forced on CPU via interpret mode):
     parity with monolithic attention, forward and backward."""
-    import accelerate_tpu.ops.flash_attention as fa
     import accelerate_tpu.ops.ring_attention as ra
 
-    monkeypatch.setattr(fa, "_INTERPRET", True)
     monkeypatch.setattr(ra, "_FORCE_FLASH_HOPS", True)
 
     mesh = _setup(sp=2, dp_extra=4)

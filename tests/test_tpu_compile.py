@@ -1,0 +1,268 @@
+"""Ask the chip's compiler, without a chip (on-chip-measurement guide §2).
+
+Every Pallas kernel reachable on a TPU is compiled here in Mosaic mode for a
+described ``v5e:2x2`` device at GPT-2-small widths — or, where the compiler
+refuses it, the refusal is pinned, with the words the arming error carries
+(``native/kernels/TPU_REFUSED``; docs/kernels.md has the table).  A compile
+that passes is not a chip run; these guard every later PR at no chip time.
+
+The topology is described inside a module-scoped fixture and nowhere else:
+only one process may load libtpu, and every xdist worker imports this file.
+Keep all such tests in THIS file.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+import accelerate_tpu.ops.flash_attention as flash
+from accelerate_tpu.native.kernels import TPU_REFUSED, KernelPolicy
+from accelerate_tpu.state import AcceleratorState
+
+BF16 = jnp.bfloat16
+GPT2_SMALL = (12, 12, 1024, 64)  # batch, heads, seq, head_dim of the 12×1024 step
+LONG_WIDE = (1, 32, 4096, 128)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        described = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip (the next one warns and
+    # compiles again): keep the cache out of these
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield described
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def dp_mesh(topo):
+    return Mesh(np.array(topo.devices).reshape(4), ("dp",))
+
+
+@pytest.fixture(autouse=True)
+def mosaic(monkeypatch):
+    """These compiles target a TPU while jax's default backend is the CPU:
+    steer the flash kernels' backend question to the chip's answer."""
+    monkeypatch.setattr(flash, "_interpret", lambda: False)
+
+
+def sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def pallas_calls(compiled) -> int:
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+def flash_loss(q, k, v):
+    return flash.flash_attention(q, k, v, is_causal=True).astype(jnp.float32).sum()
+
+
+# ---------------------------------------------------------------------------
+# the main path's kernels
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("shape", [GPT2_SMALL, LONG_WIDE], ids=["gpt2-small", "4096x128"])
+def test_flash_forward_compiles_for_v5e(one_chip, shape):
+    x = sds(shape, BF16, one_chip)
+    compiled = (
+        jax.jit(lambda q, k, v: flash.flash_attention(q, k, v, is_causal=True))
+        .lower(x, x, x)
+        .compile()
+    )
+    assert pallas_calls(compiled) == 1
+    assert "flash_fwd" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape", [GPT2_SMALL, LONG_WIDE], ids=["gpt2-small", "4096x128"])
+def test_flash_fused_backward_compiles_for_v5e(one_chip, shape):
+    x = sds(shape, BF16, one_chip)
+    compiled = jax.jit(jax.grad(flash_loss, argnums=(0, 1, 2))).lower(x, x, x).compile()
+    assert pallas_calls(compiled) == 2  # forward (for residuals) + fused backward
+    assert "flash_bwd" in compiled.as_text()
+
+
+def test_ring_attention_hop_kernel_compiles_for_v5e(one_chip):
+    """One hop of ring attention at a real chunk: 4096 tokens over sp=4,
+    GPT-2-small heads — forward and backward through the offset-aware
+    kernels."""
+    chunk = sds((3, 12, 1024, 64), BF16, one_chip)
+    offset = sds((), jnp.int32, one_chip)
+
+    def hop_loss(q, k, v, q_off, k_off):
+        out, lse = flash.flash_attention_hop(q, k, v, q_off, k_off, True, None, 0)
+        return out.astype(jnp.float32).sum() + lse.sum()
+
+    compiled = (
+        jax.jit(jax.grad(hop_loss, argnums=(0, 1, 2)))
+        .lower(chunk, chunk, chunk, offset, offset)
+        .compile()
+    )
+    assert pallas_calls(compiled) == 2
+
+
+def test_flash_on_a_mesh_needs_shard_map_and_has_it(dp_mesh, monkeypatch):
+    """GSPMD cannot partition a Mosaic kernel — only the TPU lowering says
+    so, which is why the multi-chip train step had never lowered on a TPU.
+    Bare, the kernel is refused; through the package's dispatch
+    (``_flash_on_mesh``: shard_map over the state's mesh) it compiles, one
+    kernel per device on a quarter of the batch."""
+    from accelerate_tpu.ops import attention
+
+    x = sds(GPT2_SMALL, BF16, NamedSharding(dp_mesh, P("dp")))
+    with pytest.raises(NotImplementedError, match="cannot be automatically partitioned"):
+        jax.jit(jax.grad(flash_loss, argnums=(0, 1, 2))).lower(x, x, x).compile()
+
+    monkeypatch.setitem(AcceleratorState._shared_state, "mesh", dp_mesh)
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+
+    def loss(q, k, v):
+        return attention.sdpa_tpu(q, k, v, is_causal=True).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile()
+    text = compiled.as_text()
+    assert pallas_calls(compiled) == 2
+    assert "bf16[36,1024,64]" in text  # 3 of 12 batch rows × 12 heads per device
+
+
+# ---------------------------------------------------------------------------
+# the three KernelPolicy kernels, Mosaic mode
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize(
+    "shape, axis",
+    [((768, 3072), 1), ((50304, 768), 0), ((3072,), 0)],
+    ids=["mlp-weight", "embedding", "bias"],
+)
+def test_quantize_rs_kernel_compiles_for_v5e(one_chip, shape, axis):
+    """GPT-2-small's leaves: gridded over blocks of the scaled axis, the
+    kernel stays under the scoped-VMEM limit (grid-less, the 768×3072 leaf
+    asked for 27 MB against 16)."""
+    from accelerate_tpu.native.kernels.quantize_rs import (
+        fused_quantize_dequantize,
+        stochastic_quantize_dequantize,
+    )
+
+    x = sds(shape, jnp.float32, one_chip)
+    compiled = (
+        jax.jit(lambda x: fused_quantize_dequantize(x, axis, jnp.int8, interpret=False))
+        .lower(x)
+        .compile()
+    )
+    assert pallas_calls(compiled) == 1
+    key = sds((2,), jnp.uint32, one_chip)
+    compiled = (
+        jax.jit(lambda x, k: stochastic_quantize_dequantize(x, axis, k, interpret=False))
+        .lower(x, key)
+        .compile()
+    )
+    assert pallas_calls(compiled) == 1
+
+
+def refusal_words(name: str) -> str:
+    """The compiler's own words quoted in ``TPU_REFUSED[name]``."""
+    quoted = re.findall(r"""['"]([^'"]{20,})['"]""", TPU_REFUSED[name])
+    assert quoted, TPU_REFUSED[name]
+    return quoted[-1].rstrip(".")
+
+
+def test_quantize_rs_on_a_sharded_array_is_refused(dp_mesh):
+    """Why arming ``quantized_rs`` on a TPU raises: inside the captured step
+    the kernel sees a dp-sharded gradient."""
+    from accelerate_tpu.native.kernels.quantize_rs import fused_quantize_dequantize
+
+    x = sds((768, 3072), jnp.float32, NamedSharding(dp_mesh, P(None, "dp")))
+    with pytest.raises(NotImplementedError) as refused:
+        jax.jit(
+            lambda x: fused_quantize_dequantize(x, 1, jnp.int8, interpret=False)
+        ).lower(x).compile()
+    assert "cannot be automatically partitioned" in str(refused.value)
+    assert "cannot be automatically partitioned" in TPU_REFUSED["quantized_rs"]
+
+
+def test_paged_attention_is_refused_by_the_tpu_compiler(one_chip):
+    """Past the block-table BlockSpec (now scalar-prefetched), the attend
+    math does not lower; the arming error quotes this refusal."""
+    from accelerate_tpu.models.gpt import _GPTDecodeCfg
+    from accelerate_tpu.native.kernels.paged_attention import paged_attention
+
+    cfg = _GPTDecodeCfg(n_head=12, n_kv_head=12, head_dim=64, eps=1e-5)
+    slots, bps, block = 8, 64, 16
+    pool = sds((slots * bps + 1, 12, block, 64), BF16, one_chip)
+    with pytest.raises(Exception) as refused:
+        jax.jit(
+            lambda q, kp, vp, t, p: paged_attention(
+                q, kp, vp, t, p, cfg=cfg, interpret=False
+            )
+        ).lower(
+            sds((slots, 12, 1, 64), BF16, one_chip), pool, pool,
+            sds((slots, bps), jnp.int32, one_chip), sds((slots,), jnp.int32, one_chip),
+        ).compile()
+    assert "Up to 1 batch dim supported" in str(refused.value)
+    assert "Up to 1 batch dim supported" in refusal_words("paged_attention")
+
+
+def test_collective_matmul_ring_compiles_for_a_v5e_mesh(dp_mesh):
+    """What the policy arms — the ZeRO-1 writeback ring (shard_map +
+    ppermute) — and the fused RDMA primitive at an activation tile that
+    fits; at the full 1024 rows the primitive is refused for VMEM."""
+    from accelerate_tpu.native.kernels.collective_matmul import (
+        collective_matmul,
+        ring_all_gather,
+    )
+
+    sharded = NamedSharding(dp_mesh, P("dp"))
+    compiled = (
+        jax.jit(lambda a: ring_all_gather(a, sharded, 0))
+        .lower(sds((768, 3072), BF16, sharded))
+        .compile()
+    )
+    assert "collective-permute" in compiled.as_text()
+    assert pallas_calls(compiled) == 0
+
+    w = sds((3072, 768), BF16, sharded)
+
+    def fused(rows):
+        x = sds((rows, 3072), BF16, NamedSharding(dp_mesh, P()))
+        return (
+            jax.jit(lambda x, w: collective_matmul(x, w, mesh=dp_mesh, interpret=False))
+            .lower(x, w)
+            .compile()
+        )
+
+    assert pallas_calls(fused(256)) == 1
+    with pytest.raises(Exception, match="vmem"):
+        fused(1024)
+
+
+def test_collective_matmul_policy_arms_on_tpu_refused_kernels_do_not(monkeypatch):
+    """Arming follows the compiler: the ring is allowed on a TPU backend,
+    the two refused kernels raise with its words — nothing interprets."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert KernelPolicy(collective_matmul=True).interpret is False
+    for name in ("quantized_rs", "paged_attention"):
+        with pytest.raises(NotImplementedError) as refused:
+            KernelPolicy(**{name: True}).interpret
+        assert refusal_words(name) in str(refused.value)

@@ -1,11 +1,11 @@
 #!/usr/bin/env python
-"""outage_summary — aggregate tools/tpu_when_up.sh probe logs.
+"""outage_summary — aggregate backend-availability probe logs.
 
-    python tools/outage_summary.py TPU_OUTAGE_r*.log
-    python tools/outage_summary.py --json TPU_OUTAGE_r05.log
-    python tools/outage_summary.py TPU_OUTAGE_r05.log --bench-json BENCH_r05.json
+    python tools/outage_summary.py probe.log [more.log ...]
+    python tools/outage_summary.py --json probe.log
+    python tools/outage_summary.py probe.log --bench-json bench_row.json
 
-The watcher writes one line per probe: ``<epoch-seconds> <STATE> <detail>``
+A watcher writes one line per probe: ``<epoch-seconds> <STATE> <detail>``
 where STATE is ``TPU_UP`` (probe saw a healthy accelerator) or ``DOWN``
 (probe failed; detail is the last stderr line).  The raw logs were
 write-only; this renders what the round verdicts actually need: total
@@ -376,7 +376,7 @@ def render(path: str, s: dict) -> str:
 
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(prog="outage_summary", description=__doc__)
-    parser.add_argument("logs", nargs="+", help="TPU_OUTAGE_r*.log files")
+    parser.add_argument("logs", nargs="+", help="probe log files")
     parser.add_argument("--json", action="store_true", help="machine output")
     parser.add_argument(
         "--bench-json",
