@@ -1634,6 +1634,11 @@ class Accelerator:
 
     def free_memory(self, *objects):
         """Release references + device buffers (reference accelerator.py:3412)."""
+        # the captured steps' compiled handles are about to go: let the
+        # scope registry read their HLO text first (telemetry/profiler.py)
+        from .telemetry.profiler import settle_programs
+
+        settle_programs()
         self._models.clear()
         self._optimizers.clear()
         self._schedulers.clear()

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from .nn import random as nn_random
 from .nn.tape import Tensor
 from .telemetry import flightrec as _flightrec
+from .telemetry import profiler as _profiler
 from .telemetry import watchdog as _watchdog
 from .telemetry.recompile import RecompileEvent, diff_keys, key_id
 from .telemetry.timeline import StepRecord
@@ -165,6 +166,10 @@ def _grad_placeholder(p):
     return _zeros_like_on_device(p.data)
 
 
+# span stamps without a ring: what a step pinned to a killed recorder uses
+_STAMPS_ONLY = _flightrec.FlightRecorder(capacity=16, enabled=False)
+
+
 class CapturedStep:
     """Callable produced by ``accelerator.compile_step``."""
 
@@ -193,6 +198,10 @@ class CapturedStep:
         # ($ACCELERATE_FLIGHTREC=0) costs the hot path a single None-check
         rec = _flightrec.recorder()
         self._flightrec = rec if rec.enabled else None
+        # who stamps this step's spans: the recorder, or (killed) a disabled
+        # stand-in whose spans only read the clock — StepRecord's phases are
+        # computed from the spans' stamps either way
+        self._spans = rec if rec.enabled else _STAMPS_ONLY
         self._flight_steps = 0  # step-index fallback when telemetry is OFF
         # resilience (docs/resilience.md): same pinning discipline — when
         # OFF the dispatch below is byte-identical to the pre-resilience
@@ -298,7 +307,11 @@ class CapturedStep:
 
     # -- call ----------------------------------------------------------------
     def __call__(self, *args):
-        t_call = _time.perf_counter()
+        # host phases (docs/telemetry.md §spans and scopes): assemble →
+        # atpu/dispatch → writeback, three spans on the flight recorder's
+        # ring, from whose stamps StepRecord's phases are computed too
+        spans = self._spans
+        assemble = spans.span("atpu/step/assemble").__enter__()
         tel = self._telemetry
         # flight event: dispatch begin, stamped with the step index this call
         # will carry (telemetry's global counter when ON, a local one when
@@ -395,11 +408,10 @@ class CapturedStep:
         dev_leaves = tuple(x for x, h in zip(flat_state, host_mask) if not h)
         host_leaves = tuple(x for x, h in zip(flat_state, host_mask) if h)
         if not built:
-            self.host_assembly_ms_total += (_time.perf_counter() - t_call) * 1e3
+            self.host_assembly_ms_total += (spans.now_ns() - assemble.start_ns) / 1e6
             self.host_assembly_calls += 1
         self._last_key = key
         retry_rebuild = False
-        t_dispatch = 0.0
         res = self._resilience
         retrier = res.retrier if res is not None else None
         if res is not None:
@@ -412,38 +424,44 @@ class CapturedStep:
             # never orphan a session.  The measured window is backdated to
             # call entry — device idle while the host assembled/built is
             # real idle, and busy+idle must account for the step wall clock
-            if prof.start(tel.steps_total, t0=t_call):
+            entered_s = _time.perf_counter() - (spans.now_ns() - assemble.start_ns) / 1e9
+            if prof.start(tel.steps_total, t0=entered_s):
                 prof_step = tel.steps_total
+        assemble.fields.update(step=flight_step, built=built)
+        assemble.__exit__(None, None, None)
         try:
-            if tel is not None or self._aot_cache is not None:
-                # AOT-compiled entries (telemetry's split builds AND cache-
-                # armed builds) reject drifted input layouts instead of
-                # silently re-tracing — route through the drift-tolerant
-                # dispatch either way; _dispatch_aot is telemetry-optional
-                t_dispatch = _time.perf_counter()
-                if retrier is None:
-                    new_state, out, entry, retry_rebuild = self._dispatch_aot(
-                        tel, key, entry, state, args, dev_leaves, host_leaves, flat_args
-                    )
-                else:
-                    new_state, out, entry, retry_rebuild = retrier.run_dispatch(
+            # the executable call alone (a drift rebuild or a resilience
+            # retry, where one happens, is inside it and is split out of
+            # StepRecord.dispatch_ms below)
+            with spans.span("atpu/dispatch") as launch:
+                if tel is not None or self._aot_cache is not None:
+                    # AOT-compiled entries (telemetry's split builds AND cache-
+                    # armed builds) reject drifted input layouts instead of
+                    # silently re-tracing — route through the drift-tolerant
+                    # dispatch either way; _dispatch_aot is telemetry-optional
+                    if retrier is None:
+                        new_state, out, entry, retry_rebuild = self._dispatch_aot(
+                            tel, key, entry, state, args, dev_leaves, host_leaves, flat_args
+                        )
+                    else:
+                        new_state, out, entry, retry_rebuild = retrier.run_dispatch(
+                            self,
+                            lambda dev, host, e: self._dispatch_aot(
+                                tel, key, e, state, args, dev, host, flat_args
+                            ),
+                            entry, dev_leaves, host_leaves, host_mask,
+                        )
+                    if retry_rebuild:
+                        built = True
+                        jitted, ctx, _, host_mask = entry
+                elif retrier is not None:
+                    new_state, out, _, _ = retrier.run_dispatch(
                         self,
-                        lambda dev, host, e: self._dispatch_aot(
-                            tel, key, e, state, args, dev, host, flat_args
-                        ),
+                        lambda dev, host, e: (*e[0](dev, host, *flat_args), e, False),
                         entry, dev_leaves, host_leaves, host_mask,
                     )
-                if retry_rebuild:
-                    built = True
-                    jitted, ctx, _, host_mask = entry
-            elif retrier is not None:
-                new_state, out, _, _ = retrier.run_dispatch(
-                    self,
-                    lambda dev, host, e: (*e[0](dev, host, *flat_args), e, False),
-                    entry, dev_leaves, host_leaves, host_mask,
-                )
-            else:
-                new_state, out = jitted(dev_leaves, host_leaves, *flat_args)
+                else:
+                    new_state, out = jitted(dev_leaves, host_leaves, *flat_args)
             if prof_step >= 0:
                 # close the sampled window before writeback: blocks on this
                 # call's outputs (the documented sampling overhead), parses
@@ -472,76 +490,83 @@ class CapturedStep:
                 # it would silently trace every step until the next sample
                 prof.abort()
             raise
-        self._writeback(new_state)
-        if self._uses_accumulate is None:
-            # first ever call: the trace just revealed whether the body
-            # accumulates.  If it advanced the schedule mid-trace, the key
-            # computed above used the stale flag — re-file the entry under
-            # the flag the program was actually traced with.
-            self._uses_accumulate = ctx.used_accumulate
-            if ctx.used_accumulate:
-                ctx.owner_advances_accumulate = True
-                new_key = (key[0], key[1], acc.gradient_state.sync_gradients, key[3])
-                if new_key != key:
-                    self._cache[new_key] = entry
-                    self._cache.pop(key, None)
-                    # forensics/timeline must follow the re-file: diffing the
-                    # next miss against the popped key would blame the wrong
-                    # baseline, and the build record's key id would never
-                    # match its replays'
-                    key = self._last_key = new_key
-                    if tel is not None:
-                        # the ProgramRecord written in _build carries the
-                        # pre-refile key — which the SECOND variant will
-                        # reuse (the sync flag flips back), cross-wiring the
-                        # per-program HBM/FLOP stats
-                        tel.rekey_last_program(key_id(new_key))
-                        if prof_step >= 0 and device_record is not None:
-                            # a sampled first call recorded its device
-                            # record under the same pre-refile key — follow
-                            # the re-file or the device_step↔program join
-                            # dangles for that sample.  Only when the sample
-                            # actually produced a record: an empty-trace
-                            # sample must not re-key an UNRELATED earlier
-                            # record at device_records[-1]
-                            tel.rekey_last_device_step(key_id(new_key))
-        elif ctx.used_accumulate != self._uses_accumulate:
-            # a later variant disagrees with the first trace (e.g. the body
-            # enters `accumulate()` only when model.training) — the schedule
-            # advance would silently skip or double-count; fail loudly
-            raise RuntimeError(
-                "compile_step body uses accelerator.accumulate() in some "
-                "trace variants but not others (e.g. behind a training-mode "
-                "or warmup branch); the accumulation schedule cannot track "
-                "such a step. Call accumulate() unconditionally inside the "
-                "body, or move it outside the captured call."
-            )
-        if (
-            built
-            and self._aot_cache is not None
-            and not ctx.aot_loaded
-            and not hasattr(entry[0], "lower")
-        ):
-            # persist the freshly compiled executable under the FINAL key
-            # (the accumulate re-file above already settled it) so the next
-            # process starts zero-cold.  Plain-jit fallback entries (.lower
-            # present: repeated layout drift) hold no serializable
-            # executable; cache-loaded entries must not round-trip.
-            # Fail-soft by construction — store_captured records its own
-            # store_failed cause and never raises into the step.
-            build_trace_ms, build_compile_ms = self._last_build_ms
-            self._aot_cache.store_captured(
-                self, key, entry[0], ctx, state, entry[3],
-                build_trace_ms, build_compile_ms,
-            )
-        # deferred scheduler steps run for real, python-side, every replay
-        for scheduler, s_args, s_kwargs in ctx.deferred_scheduler_steps:
-            scheduler.step(*s_args, _from_capture_replay=True, **s_kwargs)
+        with spans.span("atpu/step/writeback") as writeback:
+            self._writeback(new_state)
+            if self._uses_accumulate is None:
+                # first ever call: the trace just revealed whether the body
+                # accumulates.  If it advanced the schedule mid-trace, the key
+                # computed above used the stale flag — re-file the entry under
+                # the flag the program was actually traced with.
+                self._uses_accumulate = ctx.used_accumulate
+                if ctx.used_accumulate:
+                    ctx.owner_advances_accumulate = True
+                    new_key = (key[0], key[1], acc.gradient_state.sync_gradients, key[3])
+                    if new_key != key:
+                        self._cache[new_key] = entry
+                        self._cache.pop(key, None)
+                        # forensics/timeline must follow the re-file: diffing the
+                        # next miss against the popped key would blame the wrong
+                        # baseline, and the build record's key id would never
+                        # match its replays'
+                        key = self._last_key = new_key
+                        if tel is not None:
+                            # the ProgramRecord written in _build carries the
+                            # pre-refile key — which the SECOND variant will
+                            # reuse (the sync flag flips back), cross-wiring the
+                            # per-program HBM/FLOP stats
+                            tel.rekey_last_program(key_id(new_key))
+                            if prof_step >= 0 and device_record is not None:
+                                # a sampled first call recorded its device
+                                # record under the same pre-refile key — follow
+                                # the re-file or the device_step↔program join
+                                # dangles for that sample.  Only when the sample
+                                # actually produced a record: an empty-trace
+                                # sample must not re-key an UNRELATED earlier
+                                # record at device_records[-1]
+                                tel.rekey_last_device_step(key_id(new_key))
+            elif ctx.used_accumulate != self._uses_accumulate:
+                # a later variant disagrees with the first trace (e.g. the body
+                # enters `accumulate()` only when model.training) — the schedule
+                # advance would silently skip or double-count; fail loudly
+                raise RuntimeError(
+                    "compile_step body uses accelerator.accumulate() in some "
+                    "trace variants but not others (e.g. behind a training-mode "
+                    "or warmup branch); the accumulation schedule cannot track "
+                    "such a step. Call accumulate() unconditionally inside the "
+                    "body, or move it outside the captured call."
+                )
+            if (
+                built
+                and self._aot_cache is not None
+                and not ctx.aot_loaded
+                and not hasattr(entry[0], "lower")
+            ):
+                # persist the freshly compiled executable under the FINAL key
+                # (the accumulate re-file above already settled it) so the next
+                # process starts zero-cold.  Plain-jit fallback entries (.lower
+                # present: repeated layout drift) hold no serializable
+                # executable; cache-loaded entries must not round-trip.
+                # Fail-soft by construction — store_captured records its own
+                # store_failed cause and never raises into the step.
+                build_trace_ms, build_compile_ms = self._last_build_ms
+                self._aot_cache.store_captured(
+                    self, key, entry[0], ctx, state, entry[3],
+                    build_trace_ms, build_compile_ms,
+                )
+            # deferred scheduler steps run for real, python-side, every replay
+            for scheduler, s_args, s_kwargs in ctx.deferred_scheduler_steps:
+                scheduler.step(*s_args, _from_capture_replay=True, **s_kwargs)
+            # the handles of the state that went in (donated) are dropped
+            # here, inside the span, not at the frame's end: letting go of
+            # several hundred arrays is host time too (2.5 ms a call at
+            # GPT-2-medium, PERF.md PR 27)
+            del state, flat_state, dev_leaves, host_leaves, new_state
         if tel is not None:
-            t_end = _time.perf_counter()
+            # the spans' own stamps: no second pair of clock reads.
+            # dispatch_ms keeps its meaning: launch through writeback
             trace_ms, compile_ms = self._last_build_ms if built else (0.0, 0.0)
-            assembly_ms = (t_dispatch - t_call) * 1e3
-            dispatch_ms = (t_end - t_dispatch) * 1e3
+            assembly_ms = (launch.start_ns - assemble.start_ns) / 1e6
+            dispatch_ms = (writeback.end_ns - launch.start_ns) / 1e6
             if built and not retry_rebuild:
                 assembly_ms -= trace_ms + compile_ms  # build ran pre-dispatch
             elif retry_rebuild:
@@ -559,7 +584,7 @@ class CapturedStep:
                     step=tel.next_step_index(),
                     key=kid,
                     built=built,
-                    total_ms=(t_end - t_call) * 1e3,
+                    total_ms=(writeback.end_ns - assemble.start_ns) / 1e6,
                     assembly_ms=max(0.0, assembly_ms),
                     trace_ms=trace_ms,
                     compile_ms=compile_ms,
@@ -592,16 +617,12 @@ class CapturedStep:
         rebuild is exactly the hidden multi-minute recompile the forensics
         pillar exists to expose.  Returns (new_state, out, entry,
         retry_rebuild).  ``tel`` may be None (cache-armed, telemetry-off
-        runs ride this path too): spans and events are then skipped, the
-        drift handling is identical."""
+        runs ride this path too): events are then skipped, the drift
+        handling is identical.  The caller's ``atpu/dispatch`` span covers
+        all of it."""
         executable = entry[0]
-
-        def span(name):
-            return tel.span(name) if tel is not None else contextlib.nullcontext()
-
         try:
-            with span("atpu/dispatch"):
-                return (*executable(dev_leaves, host_leaves, *flat_args), entry, False)
+            return (*executable(dev_leaves, host_leaves, *flat_args), entry, False)
         except (TypeError, ValueError) as exc:
             # TypeError/ValueError is how the executable's *argument
             # validation* rejects drifted avals/shardings (jaxlib maps
@@ -661,8 +682,7 @@ class CapturedStep:
             # argument validation fails BEFORE any buffer is donated, so the
             # leaves the failed call touched are intact for the retry; an
             # error from the rebuilt program is real and propagates
-            with span("atpu/dispatch"):
-                new_state, out = entry[0](dev_leaves, host_leaves, *flat_args)  # graftlint: disable=donation-reuse
+            new_state, out = entry[0](dev_leaves, host_leaves, *flat_args)  # graftlint: disable=donation-reuse
             return new_state, out, entry, True
 
     def _note_recompile(self, tel, key, state_cause: Optional[str]) -> None:
@@ -825,27 +845,28 @@ class CapturedStep:
                 host_leaves = tuple(x for x, h in zip(flat_state, host_mask) if h)
                 flat_args, _ = jax.tree_util.tree_flatten(args_template)
 
-                def span(name):
-                    return (
-                        tel.span(name) if tel is not None else contextlib.nullcontext()
-                    )
-
-                t0 = _time.perf_counter()
-                with span("atpu/trace"):
-                    lowered = jitted.lower(dev_leaves, host_leaves, *flat_args)
-                t1 = _time.perf_counter()
-                with span("atpu/compile"):
-                    compiled = lowered.compile()
-                t2 = _time.perf_counter()
-                self._last_build_ms = ((t1 - t0) * 1e3, (t2 - t1) * 1e3)
+                # the scopes are read back from this executable's text: its
+                # cache key has to see them (profiler.scopes_in_cache_key)
+                with _profiler.scopes_in_cache_key():
+                    with self._spans.span("atpu/trace") as tracing:
+                        lowered = jitted.lower(dev_leaves, host_leaves, *flat_args)
+                    with self._spans.span("atpu/compile") as compiling:
+                        compiled = lowered.compile()
+                self._last_build_ms = (tracing.ms, compiling.ms)
                 label = f"capture:{self._builds_total}"
             self._builds_total += 1
+            # scopes of this program for whoever reads a device trace later
+            # (docs/telemetry.md §spans and scopes): only HOW to get the HLO
+            # text is kept, the handle weakly; nothing is fetched or parsed
+            # here.  A deserialized AOT executable has no metadata: it
+            # brings the map its storing process parsed
+            scopes = _profiler.register_program(
+                "jit_traced", _profiler.compiled_text_fn(compiled),
+                key=("captured", id(self), key_id(key)), scope_map=aot_scope_map,
+                perishable=True,
+            )
             if tel is not None:
-                tel.record_program(key, label, compiled)
-                if aot_scope_map:
-                    # after record_program: its live parse of the metadata-
-                    # less deserialized executable filed an empty map
-                    tel.restore_scope_map(key_id(key), aot_scope_map)
+                tel.record_program(key, label, compiled, scopes=scopes)
                 if tel.resource_sampling:
                     tel.sample_resources(label)
             entry = (compiled, captured_ctx, state_treedef, host_mask)

@@ -41,14 +41,18 @@ def lm_head_loss(x, head, labels, vocab_size: int):
     is the documented contract for label-bearing calls with the knob on.
     """
     chunk = F.ce_chunk_size()
-    if chunk > 0:
-        loss = F.chunked_lm_head_ce(
-            x, head.weight, shift_labels_for_lm(labels), vocab_size, chunk,
-            bias=getattr(head, "bias", None),
-        )
-        return loss, None
-    logits = head(x)
-    return lm_shift_loss(logits, labels, vocab_size), logits
+    # HLO metadata only: a device trace reads the head + cross-entropy's
+    # forward under this scope; its backward falls under atpu_backward
+    # (docs/telemetry.md §spans and scopes)
+    with jax.named_scope("atpu_head_loss"):
+        if chunk > 0:
+            loss = F.chunked_lm_head_ce(
+                x, head.weight, shift_labels_for_lm(labels), vocab_size, chunk,
+                bias=getattr(head, "bias", None),
+            )
+            return loss, None
+        logits = head(x)
+        return lm_shift_loss(logits, labels, vocab_size), logits
 
 
 def lm_shift_loss(logits, labels, vocab_size: int):

@@ -60,6 +60,7 @@ regression guard the bench/smoke assertions read.
 
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 from typing import Optional
 
@@ -99,35 +100,43 @@ def _prefill_jit(
     positions = jnp.arange(bucket_len)
     plain_layers, q_layers, s_layers = layers
 
+    # the atpu_serve_* scopes are HLO metadata only (numerics untouched): a
+    # device trace is split by them (docs/telemetry.md §spans and scopes)
     def prefill_layer(x, layer_in):
         l_parts, kp_l, vp_l = layer_in
-        l = _dequant_layer(*l_parts, qbits, x.dtype)
-        q, k, v = family.attn_in(l, x, positions, cfg)
-        att = cached_attention(q, k, v, positions, cfg)
-        # the bucket covers whole blocks: write them with one scatter each.
-        # Positions >= prompt_len hold pad-token k/v — invisible behind the
-        # causal mask until the decode loop overwrites them with real tokens
-        kb = k[0].transpose(1, 0, 2).reshape(n_blocks, block_size, k.shape[1], k.shape[3])
-        vb = v[0].transpose(1, 0, 2).reshape(n_blocks, block_size, v.shape[1], v.shape[3])
-        kp_l = kp_l.at[block_row[:n_blocks]].set(kb.transpose(0, 2, 1, 3).astype(kp_l.dtype))
-        vp_l = vp_l.at[block_row[:n_blocks]].set(vb.transpose(0, 2, 1, 3).astype(vp_l.dtype))
-        return family.attn_out(l, x, att, cfg), (kp_l, vp_l)
+        with jax.named_scope("atpu_serve_qkv"):
+            l = _dequant_layer(*l_parts, qbits, x.dtype)
+            q, k, v = family.attn_in(l, x, positions, cfg)
+        with jax.named_scope("atpu_serve_attend"):
+            att = cached_attention(q, k, v, positions, cfg)
+        with jax.named_scope("atpu_serve_kv_write"):
+            # the bucket covers whole blocks: write them with one scatter each.
+            # Positions >= prompt_len hold pad-token k/v — invisible behind the
+            # causal mask until the decode loop overwrites them with real tokens
+            kb = k[0].transpose(1, 0, 2).reshape(n_blocks, block_size, k.shape[1], k.shape[3])
+            vb = v[0].transpose(1, 0, 2).reshape(n_blocks, block_size, v.shape[1], v.shape[3])
+            kp_l = kp_l.at[block_row[:n_blocks]].set(kb.transpose(0, 2, 1, 3).astype(kp_l.dtype))
+            vp_l = vp_l.at[block_row[:n_blocks]].set(vb.transpose(0, 2, 1, 3).astype(vp_l.dtype))
+        with jax.named_scope("atpu_serve_out_mlp"):
+            return family.attn_out(l, x, att, cfg), (kp_l, vp_l)
 
-    x = family.embed(g, padded_ids, positions, cfg)
+    with jax.named_scope("atpu_serve_embed"):
+        x = family.embed(g, padded_ids, positions, cfg)
     x, (k_pool, v_pool) = jax.lax.scan(
         prefill_layer, x, ((plain_layers, q_layers, s_layers), k_pool, v_pool)
     )
-    # logits at the TRUE last prompt position (finalize reads x[:, -1], so
-    # hand it the one gathered position) — identical math to an unpadded
-    # prefill's last position
-    x_last = jax.lax.dynamic_slice_in_dim(x, prompt_len - 1, 1, axis=1)
-    logits = family.finalize(g, x_last, cfg)  # (1, V)
-    if temperature == 0.0:
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        rng_out = rng
-    else:
-        rng_out, key = jax.random.split(rng)
-        tok = jax.random.categorical(key, logits / temperature, axis=-1).astype(jnp.int32)
+    with jax.named_scope("atpu_serve_head"):
+        # logits at the TRUE last prompt position (finalize reads x[:, -1], so
+        # hand it the one gathered position) — identical math to an unpadded
+        # prefill's last position
+        x_last = jax.lax.dynamic_slice_in_dim(x, prompt_len - 1, 1, axis=1)
+        logits = family.finalize(g, x_last, cfg)  # (1, V)
+        if temperature == 0.0:
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            rng_out = rng
+        else:
+            rng_out, key = jax.random.split(rng)
+            tok = jax.random.categorical(key, logits / temperature, axis=-1).astype(jnp.int32)
     return k_pool, v_pool, tok[0], rng_out
 
 
@@ -154,28 +163,33 @@ def _decode_body(
     block_size = k_pool.shape[3]
     plain_layers, q_layers, s_layers = layers
 
-    # per-slot embed at the slot's OWN position (family.embed broadcasts one
-    # position vector over the batch, which is exactly wrong here)
-    x = jax.vmap(lambda t, p: family.embed(g, t[None, None], p[None], cfg)[0])(
-        tokens, positions
-    )  # (slots, 1, c)
+    # the atpu_serve_* scopes are HLO metadata only (numerics untouched): a
+    # device trace is split by them (docs/telemetry.md §spans and scopes)
+    with jax.named_scope("atpu_serve_embed"):
+        # per-slot embed at the slot's OWN position (family.embed broadcasts
+        # one position vector over the batch, which is exactly wrong here)
+        x = jax.vmap(lambda t, p: family.embed(g, t[None, None], p[None], cfg)[0])(
+            tokens, positions
+        )  # (slots, 1, c)
 
     def decode_layer(x, layer_in):
         l_parts, kp_l, vp_l = layer_in
-        l = _dequant_layer(*l_parts, qbits, x.dtype)
-        q, k, v = jax.vmap(
-            lambda x_s, p_s: family.attn_in(l, x_s[None], p_s[None], cfg)
-        )(x, positions)
-        q, k, v = q[:, 0], k[:, 0], v[:, 0]  # (slots, H|Hkv, 1, d)
-        # scatter each slot's new k/v into its current block.  Inactive
-        # slots' tables point at trash block 0, so the unconditional write
-        # (and any duplicate trash indices) never touches live cache
-        blk = jnp.take_along_axis(
-            block_tables, (positions // block_size)[:, None], axis=1
-        )[:, 0]
-        off = positions % block_size
-        kp_l = kp_l.at[blk, :, off].set(k[:, :, 0, :].astype(kp_l.dtype))
-        vp_l = vp_l.at[blk, :, off].set(v[:, :, 0, :].astype(vp_l.dtype))
+        with jax.named_scope("atpu_serve_qkv"):
+            l = _dequant_layer(*l_parts, qbits, x.dtype)
+            q, k, v = jax.vmap(
+                lambda x_s, p_s: family.attn_in(l, x_s[None], p_s[None], cfg)
+            )(x, positions)
+            q, k, v = q[:, 0], k[:, 0], v[:, 0]  # (slots, H|Hkv, 1, d)
+        with jax.named_scope("atpu_serve_kv_write"):
+            # scatter each slot's new k/v into its current block.  Inactive
+            # slots' tables point at trash block 0, so the unconditional write
+            # (and any duplicate trash indices) never touches live cache
+            blk = jnp.take_along_axis(
+                block_tables, (positions // block_size)[:, None], axis=1
+            )[:, 0]
+            off = positions % block_size
+            kp_l = kp_l.at[blk, :, off].set(k[:, :, 0, :].astype(kp_l.dtype))
+            vp_l = vp_l.at[blk, :, off].set(v[:, :, 0, :].astype(vp_l.dtype))
 
         if paged:
             # paged-attention kernel (docs/kernels.md): walk the block table
@@ -191,40 +205,53 @@ def _decode_body(
                 )
             from ..native.kernels.paged_attention import paged_attention
 
-            att = paged_attention(
-                q, kp_l, vp_l, block_tables, positions, cfg=cfg,
-                interpret=kernel_interpret,
-            )
+            with jax.named_scope("atpu_serve_attend"):
+                att = paged_attention(
+                    q, kp_l, vp_l, block_tables, positions, cfg=cfg,
+                    interpret=kernel_interpret,
+                )
         else:
-            def attend_one(q_s, row, p_s):
+            # two vmaps where one would do, so that each phase's scope sits
+            # OUTSIDE its vmap: a scope entered inside reads ``vmap(<scope>)``
+            # in the op's path and is lost to the map.  Same batched
+            # primitives in the same order either way
+            def gather_one(row):
                 # gather this slot's pages: table order IS logical order, so
                 # the flattened view is a virtually contiguous cache and the
                 # plain causal mask applies unchanged
                 kc = kp_l[row].transpose(1, 0, 2, 3).reshape(kp_l.shape[1], -1, kp_l.shape[3])
                 vc = vp_l[row].transpose(1, 0, 2, 3).reshape(vp_l.shape[1], -1, vp_l.shape[3])
+                return kc, vc
+
+            def attend_one(q_s, kc, vc, p_s):
                 return cached_attention(q_s[None], kc[None], vc[None], p_s[None], cfg)[0]
 
-            att = jax.vmap(attend_one)(q, block_tables, positions)  # (slots, H, 1, d)
-        x = jax.vmap(lambda x_s, a_s: family.attn_out(l, x_s[None], a_s[None], cfg)[0])(
-            x, att
-        )
+            with jax.named_scope("atpu_serve_kv_gather"):
+                kc, vc = jax.vmap(gather_one)(block_tables)
+            with jax.named_scope("atpu_serve_attend"):
+                att = jax.vmap(attend_one)(q, kc, vc, positions)  # (slots, H, 1, d)
+        with jax.named_scope("atpu_serve_out_mlp"):
+            x = jax.vmap(lambda x_s, a_s: family.attn_out(l, x_s[None], a_s[None], cfg)[0])(
+                x, att
+            )
         return x, (kp_l, vp_l)
 
     x, (k_pool, v_pool) = jax.lax.scan(
         decode_layer, x, ((plain_layers, q_layers, s_layers), k_pool, v_pool)
     )
-    logits = family.finalize(g, x, cfg)  # (slots, V)
-    if temperature == 0.0:
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        rngs_out = rngs
-    else:
-        # per-slot streams: a request's sampled tokens depend only on its
-        # own key, never on which neighbours share the batch or finish
-        def sample_one(key_data, lg):
-            nk, sk = jax.random.split(key_data)
-            return nk, jax.random.categorical(sk, lg / temperature).astype(jnp.int32)
+    with jax.named_scope("atpu_serve_head"):
+        logits = family.finalize(g, x, cfg)  # (slots, V)
+        if temperature == 0.0:
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            rngs_out = rngs
+        else:
+            # per-slot streams: a request's sampled tokens depend only on its
+            # own key, never on which neighbours share the batch or finish
+            def sample_one(key_data, lg):
+                nk, sk = jax.random.split(key_data)
+                return nk, jax.random.categorical(sk, lg / temperature).astype(jnp.int32)
 
-        rngs_out, nxt = jax.vmap(sample_one)(rngs, logits)
+            rngs_out, nxt = jax.vmap(sample_one)(rngs, logits)
     return k_pool, v_pool, nxt, rngs_out
 
 
@@ -396,6 +423,30 @@ class CompileWatcher:
         return out
 
 
+def _dispatch(label: str, sig, jit_fn, args, statics, watcher, aot):
+    """The one way a serving program is called.  On a signature's first call
+    the program is also entered in the scope registry (telemetry/profiler.py:
+    the jit function, its statics and the arguments' shapes, dtypes and
+    shardings — no buffer — from which its HLO text can be had again later),
+    and that call, the one that compiles, runs with the scopes in the
+    persistent-cache key."""
+    from ..telemetry import profiler
+
+    key = (sig, *statics.values())  # sig leaves the model out; cfg is a static
+    first = not profiler.program_registered(key)
+    if first:
+        profiler.register_program(
+            f"jit_{jit_fn.__name__}", profiler.relowered_text_fn(jit_fn, args, statics),
+            key=key,
+        )
+    with profiler.scopes_in_cache_key() if first else contextlib.nullcontext():
+        if aot is not None:
+            return aot.call(label, sig, jit_fn, args, statics, watcher=watcher)
+        if watcher is None:
+            return jit_fn(*args, **statics)
+        return watcher.call(label, sig, jit_fn, *args, **statics)
+
+
 def run_prefill(k_pool, v_pool, g, layers, padded_ids, block_row, prompt_len,
                 rng, *, family, cfg, qbits, temperature,
                 watcher: Optional[CompileWatcher] = None, aot=None):
@@ -409,11 +460,7 @@ def run_prefill(k_pool, v_pool, g, layers, padded_ids, block_row, prompt_len,
     args = (k_pool, v_pool, g, layers, padded_ids, block_row, prompt_len, rng)
     statics = dict(family=family, cfg=cfg, qbits=qbits, temperature=temperature)
     sig = ("prefill", padded_ids.shape[1], qbits, float(temperature))
-    if aot is not None:
-        return aot.call("prefill", sig, _prefill_jit, args, statics, watcher=watcher)
-    if watcher is None:
-        return _prefill_jit(*args, **statics)
-    return watcher.call("prefill", sig, _prefill_jit, *args, **statics)
+    return _dispatch("prefill", sig, _prefill_jit, args, statics, watcher, aot)
 
 
 def run_decode(k_pool, v_pool, g, layers, block_tables, positions, tokens,
@@ -438,11 +485,7 @@ def run_decode(k_pool, v_pool, g, layers, block_tables, positions, tokens,
     # two services with opposite modes must not share one program
     sig = ("decode", block_tables.shape, qbits, float(temperature),
            paged and ("interpret" if kernels.interpret else "mosaic"))
-    if aot is not None:
-        return aot.call("decode", sig, _decode_jit, args, statics, watcher=watcher)
-    if watcher is None:
-        return _decode_jit(*args, **statics)
-    return watcher.call("decode", sig, _decode_jit, *args, **statics)
+    return _dispatch("decode", sig, _decode_jit, args, statics, watcher, aot)
 
 
 def run_decode_n(k_pool, v_pool, g, layers, block_tables, positions, tokens,
@@ -482,8 +525,4 @@ def run_decode_n(k_pool, v_pool, g, layers, block_tables, positions, tokens,
     sig = ("decode", block_tables.shape, qbits, float(temperature),
            paged and ("interpret" if kernels.interpret else "mosaic"),
            decode_steps)
-    if aot is not None:
-        return aot.call("decode", sig, _decode_n_jit, args, statics, watcher=watcher)
-    if watcher is None:
-        return _decode_n_jit(*args, **statics)
-    return watcher.call("decode", sig, _decode_n_jit, *args, **statics)
+    return _dispatch("decode", sig, _decode_n_jit, args, statics, watcher, aot)

@@ -50,6 +50,7 @@ from typing import Optional
 import numpy as np
 
 from ..logging import get_logger
+from ..telemetry import flightrec
 from .kv_blocks import BlockPool, blocks_for_request, bucket_length, make_pools
 
 logger = get_logger(__name__)
@@ -143,6 +144,10 @@ class Request:
     state: str = "queued"  # queued -> running -> done (or -> shed)
     tokens: list = dataclasses.field(default_factory=list)
     submitted_t: float = 0.0
+    # when the request left the queue with a slot and its blocks, before its
+    # prefill (perf_counter, like its neighbours); a requeued request keeps
+    # its first admission
+    admitted_t: Optional[float] = None
     first_token_t: Optional[float] = None
     done_t: Optional[float] = None
     # per-request latency budget: a queued request whose age exceeds this
@@ -553,8 +558,6 @@ class DecodeService:
             reason = "draining" if self._draining else "queue_full"
             retry_after = self._retry_after_ms()
             self.stats["shed"] += 1
-            from ..telemetry import flightrec
-
             flightrec.record(
                 "serving_shed", reason=reason, queue_depth=len(self._queue),
             )
@@ -588,6 +591,11 @@ class DecodeService:
             )
         self._queue.append(req)
         self.stats["queue_peak"] = max(self.stats["queue_peak"], len(self._queue))
+        rec = flightrec.recorder()
+        rec.record(
+            "serve/submit", rid=rid, prompt_len=p_len,
+            submitted=rec.from_perf_counter(req.submitted_t),
+        )
         return rid
 
     # -- scheduling ----------------------------------------------------------
@@ -637,6 +645,8 @@ class DecodeService:
             if slot is None or not self.pool.can_alloc(req.blocks_needed):
                 break
             self._queue.popleft()
+            if req.admitted_t is None:
+                req.admitted_t = time.perf_counter()
             if req.tokens:
                 # journal-recovered (or retry-requeued) request: rebuild
                 # its KV by teacher-forced re-prefill over the emitted
@@ -647,21 +657,24 @@ class DecodeService:
             row = self.pool.alloc(slot, req.blocks_needed)
             table_row = np.zeros(self.pool.blocks_per_slot, np.int32)
             table_row[: len(row)] = row
-            padded_ids = np.full((1, req.bucket_len), self.config.pad_token_id, np.int32)
-            padded_ids[0, : req.prompt_len] = req.prompt
-            self._k_pool, self._v_pool, tok, rng_out = run_prefill(
-                self._k_pool, self._v_pool, self._g, self._layers,
-                jnp.asarray(padded_ids), jnp.asarray(table_row),
-                jnp.asarray(req.prompt_len, jnp.int32),
-                jax.random.fold_in(self._base_rng, 2 * req.rid + 1),
-                family=self.spec.family, cfg=self.spec.cfg,
-                qbits=self._qbits,
-                temperature=float(self.config.temperature),
-                watcher=self.watcher, aot=self._aot,
-            )
+            about = dict(rid=req.rid, bucket_len=req.bucket_len, prompt_len=req.prompt_len)
+            with flightrec.span("atpu/serve/prefill_launch", **about):
+                padded_ids = np.full((1, req.bucket_len), self.config.pad_token_id, np.int32)
+                padded_ids[0, : req.prompt_len] = req.prompt
+                self._k_pool, self._v_pool, tok, rng_out = run_prefill(
+                    self._k_pool, self._v_pool, self._g, self._layers,
+                    jnp.asarray(padded_ids), jnp.asarray(table_row),
+                    jnp.asarray(req.prompt_len, jnp.int32),
+                    jax.random.fold_in(self._base_rng, 2 * req.rid + 1),
+                    family=self.spec.family, cfg=self.spec.cfg,
+                    qbits=self._qbits,
+                    temperature=float(self.config.temperature),
+                    watcher=self.watcher, aot=self._aot,
+                )
             self.stats["host_syncs"] += 1
             self._programs_warmed = True
-            first = int(tok)
+            with flightrec.span("atpu/serve/prefill_sync", **about):
+                first = int(tok)
             req.first_token_t = time.perf_counter()
             req.tokens.append(first)
             req.state = "running"
@@ -707,6 +720,14 @@ class DecodeService:
         req.done_t = time.perf_counter()
         req.state = "done"
         self.results[req.rid] = req
+        rec = flightrec.recorder()
+        rec.record(
+            "serve/request", rid=req.rid, prompt_len=req.prompt_len, tokens=len(req.tokens),
+            **{name: rec.from_perf_counter(t) for name, t in (
+                ("submitted", req.submitted_t), ("admitted", req.admitted_t),
+                ("first_token", req.first_token_t), ("done", req.done_t),
+            )},
+        )
         while len(self.results) > self.config.max_retained_results:
             self.results.pop(next(iter(self.results)))
         if self._journal is not None:
@@ -740,8 +761,6 @@ class DecodeService:
         self.stats["shed"] += 1
         if self._journal is not None:
             self._journal.log_shed(req.rid, reason)
-        from ..telemetry import flightrec
-
         flightrec.record("serving_shed", rid=req.rid, reason=reason)
         if self._hub is not None:
             self._hub.record_serving({
@@ -812,22 +831,25 @@ class DecodeService:
         row = self.pool.alloc(slot, req.blocks_needed)
         table_row = np.zeros(self.pool.blocks_per_slot, np.int32)
         table_row[: len(row)] = row
-        padded_ids = np.full((1, req.bucket_len), self.config.pad_token_id, np.int32)
-        padded_ids[0, :seq_len] = seq
-        rng = jax.random.fold_in(self._base_rng, 2 * req.rid + 1)
-        if float(self.config.temperature) > 0.0:
-            rng = advance_rng(rng, k - 1)
-        self._k_pool, self._v_pool, tok, rng_out = run_prefill(
-            self._k_pool, self._v_pool, self._g, self._layers,
-            jnp.asarray(padded_ids), jnp.asarray(table_row),
-            jnp.asarray(seq_len, jnp.int32), rng,
-            family=self.spec.family, cfg=self.spec.cfg,
-            qbits=self._qbits,
-            temperature=float(self.config.temperature),
-            watcher=self.watcher, aot=self._aot,
-        )
+        about = dict(rid=req.rid, bucket_len=req.bucket_len, prompt_len=seq_len)
+        with flightrec.span("atpu/serve/prefill_launch", **about):
+            padded_ids = np.full((1, req.bucket_len), self.config.pad_token_id, np.int32)
+            padded_ids[0, :seq_len] = seq
+            rng = jax.random.fold_in(self._base_rng, 2 * req.rid + 1)
+            if float(self.config.temperature) > 0.0:
+                rng = advance_rng(rng, k - 1)
+            self._k_pool, self._v_pool, tok, rng_out = run_prefill(
+                self._k_pool, self._v_pool, self._g, self._layers,
+                jnp.asarray(padded_ids), jnp.asarray(table_row),
+                jnp.asarray(seq_len, jnp.int32), rng,
+                family=self.spec.family, cfg=self.spec.cfg,
+                qbits=self._qbits,
+                temperature=float(self.config.temperature),
+                watcher=self.watcher, aot=self._aot,
+            )
         self.stats["host_syncs"] += 1
-        int(tok)  # block for the prefill; the sample itself is teacher-forced away
+        with flightrec.span("atpu/serve/prefill_sync", **about):
+            int(tok)  # block for the prefill; the sample itself is teacher-forced away
         self._programs_warmed = True
         req.state = "running"
         if req.first_token_t is None:
@@ -837,8 +859,6 @@ class DecodeService:
             req.first_token_t = time.perf_counter()
         self.stats["admitted"] += 1
         self.stats["recovered"] += 1
-        from ..telemetry import flightrec
-
         flightrec.record(
             "serving_recovered", rid=req.rid, prefix_tokens=k,
         )
@@ -878,8 +898,6 @@ class DecodeService:
             self.stats["pool_rebuilds"] += 1
         self._queue_recovery(reqs, front=True)
         self.stats["requeued"] += len(reqs)
-        from ..telemetry import flightrec
-
         flightrec.record(
             "serving_requeue", count=len(reqs), reason=reason,
         )
@@ -908,8 +926,6 @@ class DecodeService:
             reason = (
                 self._guard.signal_name if self._guard is not None else None
             ) or "drain"
-        from ..telemetry import flightrec
-
         flightrec.record(
             "serving_drain", reason=reason, open=len(open_rids),
         )
@@ -981,8 +997,6 @@ class DecodeService:
                         req.eos_token_id, tokens=req.tokens,
                     )
         self._queue_recovery(reqs, front=False)
-        from ..telemetry import flightrec
-
         flightrec.record("serving_resume", count=len(rids))
         if self._hub is not None and rids:
             self._hub.record_serving_recovery({
@@ -1037,10 +1051,19 @@ class DecodeService:
 
     def step(self) -> list[Request]:
         """One engine iteration (admit → decode a ``decode_steps`` token
-        block → evict); returns the requests that completed during it."""
-        from .engine import run_decode, run_decode_n
+        block → evict); returns the requests that completed during it.
 
-        from ..telemetry import flightrec
+        Its host phases are spans on the flight recorder's ring
+        (docs/telemetry.md §spans and scopes): ``atpu/serve/step`` around
+        all of it, inside it ``admit`` (with a ``prefill_launch`` and a
+        ``prefill_sync`` per admitted request), ``decode_launch``,
+        ``decode_sync`` and ``emit`` — none inside a per-token or per-slot
+        loop."""
+        with flightrec.span("atpu/serve/step", step=self.stats["steps"]) as whole:
+            return self._step(whole.fields)
+
+    def _step(self, about: dict) -> list[Request]:
+        from .engine import run_decode, run_decode_n
 
         n = self.config.decode_steps
         if self._injector is not None:
@@ -1056,7 +1079,11 @@ class DecodeService:
             # admission stopped; in-flight requests stay open in the
             # journal for the successor replica
             return []
-        admitted = self._admit()
+        admitted = []
+        if self._queue:
+            with flightrec.span("atpu/serve/admit") as admitting:
+                admitted = self._admit()
+                admitting.fields["admitted"] = len(admitted)
         if admitted:
             # flight event: admissions (docs/telemetry.md §flight recorder)
             # — in a hang postmortem the last admit/decode_window pair shows
@@ -1069,105 +1096,112 @@ class DecodeService:
         slot_evictions = 0
         emitted = 0
         active = [i for i, r in enumerate(self._slot_req) if r is not None]
+        about.update(active=len(active), queue_depth=len(self._queue))
+        emitting = None
         uploads_before = self.stats["h2d_uploads"]
         if active:
             flightrec.record(
                 "decode_window",
                 step=self.stats["steps"], active=len(active), decode_steps=n,
             )
-            if n > 1:
-                self._flush_device_state()
-            common = dict(
-                family=self.spec.family, cfg=self.spec.cfg,
-                qbits=self._qbits,
-                temperature=float(self.config.temperature),
-                watcher=self.watcher, aot=self._aot,
-                kernels=self._kernels,
-            )
-            # transient-fault retry (docs/serving.md §fault tolerance):
-            # the injected/classified-transient fault fires BEFORE the
-            # dispatch consumes the donated pools, so a retry re-dispatches
-            # the SAME compiled program (zero extra compiles).  A real
-            # mid-execution fault that consumed the pools skips straight
-            # to eviction-and-requeue, whose re-prefills rebuild all KV.
-            dispatched = False
-            attempt = 0
-            while True:
-                try:
-                    if self._injector is not None:
-                        self._injector.maybe_decode_fault(self.stats["steps"])
-                    if n == 1:
-                        # legacy single-token dispatch, byte-identical to the
-                        # pre-multi-token service INCLUDING the per-step mirror
-                        # uploads: the program must see the exact avals it always
-                        # has (fresh uncommitted int arrays), because inputs
-                        # committed with a NamedSharding lower to a DIFFERENT HLO
-                        # module — an independently compiled binary whose near-tie
-                        # argmaxes can drift 1 ulp from generate()'s programs and
-                        # break the bitwise parity contract (caught live on a
-                        # prepared single-device run; see engine._decode_jit for
-                        # the same argument against a length-1 loop variant).  The
-                        # uploads are three tiny int arrays; the per-token cost
-                        # that matters — the blocking host sync — is unchanged
-                        # here and amortized n-fold on the n>1 path below.
-                        import jax.numpy as jnp
+            with flightrec.span("atpu/serve/decode_launch", active=len(active)):
+                if n > 1:
+                    self._flush_device_state()
+                common = dict(
+                    family=self.spec.family, cfg=self.spec.cfg,
+                    qbits=self._qbits,
+                    temperature=float(self.config.temperature),
+                    watcher=self.watcher, aot=self._aot,
+                    kernels=self._kernels,
+                )
+                # transient-fault retry (docs/serving.md §fault tolerance):
+                # the injected/classified-transient fault fires BEFORE the
+                # dispatch consumes the donated pools, so a retry re-dispatches
+                # the SAME compiled program (zero extra compiles).  A real
+                # mid-execution fault that consumed the pools skips straight
+                # to eviction-and-requeue, whose re-prefills rebuild all KV.
+                dispatched = False
+                attempt = 0
+                while True:
+                    try:
+                        if self._injector is not None:
+                            self._injector.maybe_decode_fault(self.stats["steps"])
+                        if n == 1:
+                            # legacy single-token dispatch, byte-identical to the
+                            # pre-multi-token service INCLUDING the per-step mirror
+                            # uploads: the program must see the exact avals it always
+                            # has (fresh uncommitted int arrays), because inputs
+                            # committed with a NamedSharding lower to a DIFFERENT HLO
+                            # module — an independently compiled binary whose near-tie
+                            # argmaxes can drift 1 ulp from generate()'s programs and
+                            # break the bitwise parity contract (caught live on a
+                            # prepared single-device run; see engine._decode_jit for
+                            # the same argument against a length-1 loop variant).  The
+                            # uploads are three tiny int arrays; the per-token cost
+                            # that matters — the blocking host sync — is unchanged
+                            # here and amortized n-fold on the n>1 path below.
+                            import jax.numpy as jnp
 
-                        (self._k_pool, self._v_pool, nxt, self._rngs) = run_decode(
-                            self._k_pool, self._v_pool, self._g, self._layers,
-                            jnp.asarray(self._tables), jnp.asarray(self._positions),
-                            jnp.asarray(self._tokens), self._rngs, **common,
-                        )
-                        self.stats["h2d_uploads"] += 1
-                        self._state_dirty = True  # mirrors stay the source of truth
-                        tok_block = nxt  # reshaped host-side below
-                    else:
-                        (self._k_pool, self._v_pool, tok_block, self._dev_positions,
-                         self._dev_tokens, self._rngs) = run_decode_n(
-                            self._k_pool, self._v_pool, self._g, self._layers,
-                            self._dev_tables, self._dev_positions, self._dev_tokens,
-                            self._rngs, decode_steps=n, **common,
-                        )
-                    dispatched = True
-                    break
-                except Exception as exc:
-                    from ..resilience.backend import backoff_delay
-                    from ..resilience.retry import classify_failure
+                            (self._k_pool, self._v_pool, nxt, self._rngs) = run_decode(
+                                self._k_pool, self._v_pool, self._g, self._layers,
+                                jnp.asarray(self._tables), jnp.asarray(self._positions),
+                                jnp.asarray(self._tokens), self._rngs, **common,
+                            )
+                            self.stats["h2d_uploads"] += 1
+                            self._state_dirty = True  # mirrors stay the source of truth
+                            tok_block = nxt  # reshaped host-side below
+                        else:
+                            (self._k_pool, self._v_pool, tok_block, self._dev_positions,
+                             self._dev_tokens, self._rngs) = run_decode_n(
+                                self._k_pool, self._v_pool, self._g, self._layers,
+                                self._dev_tables, self._dev_positions, self._dev_tokens,
+                                self._rngs, decode_steps=n, **common,
+                            )
+                        dispatched = True
+                        break
+                    except Exception as exc:
+                        from ..resilience.backend import backoff_delay
+                        from ..resilience.retry import classify_failure
 
-                    if classify_failure(exc) != "transient":
-                        raise  # user/program errors propagate unchanged
-                    pools_ok = not self._k_pool.is_deleted()
-                    if attempt < self.config.max_decode_retries and pools_ok:
-                        attempt += 1
-                        self.stats["decode_retries"] += 1
-                        delay = backoff_delay(
-                            attempt, self.config.retry_backoff_s, cap_s=5.0
+                        if classify_failure(exc) != "transient":
+                            raise  # user/program errors propagate unchanged
+                        pools_ok = not self._k_pool.is_deleted()
+                        if attempt < self.config.max_decode_retries and pools_ok:
+                            attempt += 1
+                            self.stats["decode_retries"] += 1
+                            delay = backoff_delay(
+                                attempt, self.config.retry_backoff_s, cap_s=5.0
+                            )
+                            flightrec.record(
+                                "serving_retry", step=self.stats["steps"],
+                                attempt=attempt,
+                            )
+                            if self._hub is not None:
+                                self._hub.record_serving_recovery({
+                                    "event": "retry", "step": self.stats["steps"],
+                                    "attempt": attempt, "wait_ms": delay * 1e3,
+                                    "error": f"{type(exc).__name__}: {exc}"[:300],
+                                })
+                            time.sleep(delay)
+                            continue
+                        self._requeue_active(
+                            "retry_exhausted" if pools_ok else "pools_consumed",
+                            error=exc,
                         )
-                        flightrec.record(
-                            "serving_retry", step=self.stats["steps"],
-                            attempt=attempt,
-                        )
-                        if self._hub is not None:
-                            self._hub.record_serving_recovery({
-                                "event": "retry", "step": self.stats["steps"],
-                                "attempt": attempt, "wait_ms": delay * 1e3,
-                                "error": f"{type(exc).__name__}: {exc}"[:300],
-                            })
-                        time.sleep(delay)
-                        continue
-                    self._requeue_active(
-                        "retry_exhausted" if pools_ok else "pools_consumed",
-                        error=exc,
-                    )
-                    break
+                        break
             if dispatched:
                 # THE host sync of the hot loop: one blocking read per
                 # n-token block, weighted per active slot for the
                 # per-token ratio
                 self.stats["host_syncs"] += 1
                 self.stats["decode_syncs"] += len(active)
-                block_host = np.asarray(tok_block).reshape(
-                    self.config.max_slots, n
-                )
+                with flightrec.span("atpu/serve/decode_sync"):
+                    block_host = np.asarray(tok_block).reshape(
+                        self.config.max_slots, n
+                    )
+                # closed at the end of the step: the slot loop and the
+                # step's bookkeeping after it
+                emitting = flightrec.span("atpu/serve/emit").__enter__()
                 for slot in active:
                     req = self._slot_req[slot]
                     emitted_before = len(req.tokens)
@@ -1229,6 +1263,9 @@ class DecodeService:
                 "emitted": emitted,
                 "h2d_upload": self.stats["h2d_uploads"] > uploads_before,
             })
+        if emitting is not None:
+            emitting.fields.update(emitted=emitted, completed=len(completed))
+            emitting.__exit__(None, None, None)
         return completed
 
     def run(self, max_steps: Optional[int] = None) -> dict[int, Request]:
@@ -1275,8 +1312,6 @@ class DecodeService:
                 continue
         else:
             self.stats["metrics_snapshot_retry_exhausted"] += 1
-            from ..telemetry import flightrec
-
             flightrec.record(
                 "metrics_snapshot_retry_exhausted",
                 retries=_METRICS_SNAPSHOT_RETRIES,

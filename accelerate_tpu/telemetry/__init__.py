@@ -4,9 +4,11 @@ Four pillars, all default-OFF and zero-overhead when off:
 
 1. **Step-phase timing** (`timeline.py`) — every ``CapturedStep.__call__``
    records dataloader-wait / assembly / trace / compile / dispatch ms into a
-   ring-buffered :class:`~.timeline.StepTimeline`, with
-   ``jax.profiler.TraceAnnotation`` spans around each phase so xprof traces
-   collected through ``accelerator.profile()`` show named capture phases.
+   ring-buffered :class:`~.timeline.StepTimeline`.  The phases' spans
+   (``atpu/step/assemble``, ``atpu/dispatch``, ``atpu/step/writeback``, and
+   ``atpu/trace`` / ``atpu/compile`` on a build) are the flight recorder's
+   (pillar 8): always on, and visible in an xprof trace collected through
+   ``accelerator.profile()``.
 2. **Recompile forensics** (`recompile.py`) — every new compiled variant is
    diffed against the previous cache key and emits a
    :class:`~.recompile.RecompileEvent` naming exactly what moved (arg
@@ -36,9 +38,11 @@ Four pillars, all default-OFF and zero-overhead when off:
    provider (the decode service self-registers its ``metrics()`` snapshot).
 8. **Black-box forensics** (`flightrec.py` / `watchdog.py` /
    `trace_export.py`) — the flight recorder is the ONE exception to the
-   default-off convention: an always-on, bounded, per-process event ring
-   (step dispatches, collective-sequence ticks, fleet/serving/checkpoint
-   phases) that the default-off hang watchdog dumps — with faulthandler
+   default-off convention: an always-on, bounded, per-process ring of
+   instants (step dispatches, collective-sequence ticks, fleet/serving/
+   checkpoint phases) and spans (the capture call's and the engine step's
+   host phases, on the profiler's clock) that the default-off hang
+   watchdog dumps — with faulthandler
    stacks — to a per-rank JSON on stall/signal/exit, and
    ``tools/blackbox_report.py`` merges across ranks by collective sequence
    number.  ``trace_export.py`` joins the ring with the host/device step
@@ -55,7 +59,6 @@ from __future__ import annotations
 
 import time
 from collections import deque
-from contextlib import contextmanager
 from typing import Optional
 
 from .profiler import DeviceStepRecord
@@ -98,7 +101,6 @@ class Telemetry:
 
             handler = TelemetryKwargs()
         self.enabled = bool(handler.enabled)
-        self.annotate_spans = bool(handler.annotate_spans)
         self.resource_sampling = bool(handler.sample_resources)
         self.jsonl_path = handler.jsonl_path
         self.timeline = StepTimeline(capacity=handler.timeline_size)
@@ -225,19 +227,6 @@ class Telemetry:
                 displaced.close_metrics()
             self.serve_metrics(port=metrics_port)
 
-    # -- spans ---------------------------------------------------------------
-    @contextmanager
-    def span(self, name: str):
-        """xprof-visible phase span (``jax.profiler.TraceAnnotation``); a
-        no-op region when span annotation is off."""
-        if not self.annotate_spans:
-            yield
-            return
-        import jax
-
-        with jax.profiler.TraceAnnotation(name):
-            yield
-
     # -- producers -----------------------------------------------------------
     def record_dataloader_wait(self, ms: float, owner=None) -> None:
         """Host time a loader spent producing one batch.  ``owner`` (the
@@ -288,17 +277,15 @@ class Telemetry:
         if self._export_sink:
             self._export_queue.append(event.to_dict())
 
-    def record_program(self, key, label: str, compiled) -> ProgramRecord:
+    def record_program(self, key, label: str, compiled, scopes=None) -> ProgramRecord:
+        """``scopes`` is the program's entry in the process-wide registry
+        (``profiler.register_program``); the per-phase join of a sampled
+        step asks it for the op→scope map, which is the first time the HLO
+        text is fetched and parsed."""
         record = ProgramRecord(key=key_id(key), label=label, stats=program_stats(compiled))
         self.program_records.append(record)
-        if self.profiler is not None:
-            # per-phase device attribution (docs/telemetry.md): the HLO
-            # text is the only place the atpu named scopes survive to —
-            # CPU/TPU trace events carry bare op names — so snapshot the
-            # op->scope map per variant while the compiled handle is here
-            from .profiler import scope_map_from_compiled
-
-            self._scope_maps[record.key] = scope_map_from_compiled(compiled)
+        if scopes is not None:
+            self._scope_maps[record.key] = scopes
             if len(self._scope_maps) > len(self.program_records) + 8:
                 # the deque rolls old program records off at max_events;
                 # maps for rolled-off variants must roll too (each holds
@@ -422,8 +409,9 @@ class Telemetry:
             # compute/collective split reads per atpu phase, not one
             # whole-step window.  Fail-soft: no map (pre-build sample,
             # metadata-less backend) leaves phases empty.
-            scope_map = self._scope_maps.get(record.key)
-            if scope_map and record.op_detail:
+            scopes = self._scope_maps.get(record.key)
+            scope_map = scopes.scope_map() if scopes is not None and record.op_detail else None
+            if scope_map:
                 from .profiler import split_phases
 
                 record.phases = split_phases(record.op_detail, scope_map)
@@ -431,19 +419,6 @@ class Telemetry:
         if self._export_sink:
             self._export_queue.append(record.to_dict())
         return record
-
-    def restore_scope_map(self, key: str, scope_map: dict) -> None:
-        """Adopt a PERSISTED HLO op→scope map for a compiled variant
-        (docs/aot_cache.md): executables deserialized from the AOT store
-        carry no HLO metadata, so ``record_program``'s live parse yields an
-        empty map and every sample of that variant would read empty
-        ``phases`` — the store's side payload carries the map the compiling
-        process parsed, and the capture path restores it here on a warm
-        load.  No-op unless the sampler is armed (the maps only feed the
-        per-phase device split) or the map is empty."""
-        if self.profiler is None or not scope_map:
-            return
-        self._scope_maps[key] = dict(scope_map)
 
     def rekey_last_device_step(self, new_key: str) -> None:
         """Re-key the most recent device-step record (and its pending export

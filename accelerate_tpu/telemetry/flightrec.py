@@ -19,12 +19,32 @@ doing when it stopped*:
 * serving admissions and decode windows (``serving/``);
 * checkpoint and AOT-store I/O (``checkpointing.py``, ``native/aot_cache.py``).
 
-Each event is stamped with ``time.monotonic()`` and a per-process sequence
-number; the rank is resolved lazily at dump time (recording must work
-before — and during — distributed init).  The ring is a preallocated slot
-list guarded by one tiny critical section per append (~100 ns uncontended,
-far under the ≤1 % of ``step_ms`` budget the bench A/B row asserts); when
-it wraps, the oldest events are overwritten and ``dropped`` counts them.
+Each event is stamped with ``time.monotonic_ns()`` and a per-process
+sequence number; the rank is resolved lazily at dump time (recording must
+work before — and during — distributed init).  The ring is a preallocated
+slot list guarded by one tiny critical section per append (~100 ns
+uncontended, far under the ≤1 % of ``step_ms`` budget the bench A/B row
+asserts); when it wraps, the oldest events are overwritten and ``dropped``
+counts them.
+
+**Spans** (docs/telemetry.md §spans and scopes) share the ring with the
+instants: :meth:`FlightRecorder.span` is a context manager that stamps its
+entry and exit and appends ONE slot ``(seq, start, name, fields, dur)`` when
+it closes; it also enters a ``jax.profiler.TraceAnnotation`` of the same
+name, so an xprof trace shows what the ring's readers see.  It is the
+package's one span mechanism.  Because a span is written when it closes,
+the instants that mark work *about to start* (``step_begin``,
+``decode_window``) stay instants: in a hang they are the proof of where the
+process stopped.
+
+**The ring clock** is the clock the profiler stamps its host events with:
+Unix-epoch nanoseconds (``CLOCK_REALTIME``; a profiler session then rebases
+every plane to its own start, a constant per session).  Stamps are taken
+from ``time.monotonic_ns()`` and moved by a one-time anchor, so durations
+never see a wall-clock step.  :meth:`FlightRecorder.spans` hands a consumer
+the retained spans and instants of an interval in that clock, and
+:meth:`FlightRecorder.from_perf_counter` moves a ``time.perf_counter()``
+stamp (``Request.submitted_t`` and friends) onto it.
 
 The recorder never issues a collective, never raises into the hot path, and
 its dump (:meth:`FlightRecorder.dump`) writes a *per-rank* JSON file — this
@@ -34,7 +54,8 @@ collective sink.
 
 Kill switch: ``ACCELERATE_FLIGHTREC=0`` turns recording into a no-op (the
 bench A/B's "off" arm); ``ACCELERATE_FLIGHTREC_CAPACITY`` resizes the ring
-(default 2048 events).
+(default 65,536 events: an engine step writes about seven, so a minute of
+serving at 15 ms a step still fits).
 """
 
 from __future__ import annotations
@@ -46,7 +67,7 @@ import threading
 import time
 from typing import Optional
 
-_DEFAULT_CAPACITY = 2048
+_DEFAULT_CAPACITY = 65536
 
 
 def _env_capacity() -> int:
@@ -76,6 +97,76 @@ def resolve_rank() -> int:
         return int(os.environ.get("ACCELERATE_FLIGHTREC_RANK", "0") or 0)
 
 
+def _clock_anchor() -> tuple:
+    """``(wall_ns, monotonic_ns, perf_counter_ns)`` read at one moment: the
+    tightest of a few sandwiched reads, so the anchor is good to well under
+    a microsecond."""
+    best = None
+    for _ in range(5):
+        m0 = time.monotonic_ns()
+        p = time.perf_counter_ns()
+        w = time.time_ns()
+        m1 = time.monotonic_ns()
+        if best is None or m1 - m0 < best[0]:
+            best = (m1 - m0, w, (m0 + m1) // 2, p)
+    return best[1:]
+
+
+_trace_annotation = None  # jax.profiler.TraceAnnotation, or False without jax
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` for ``name`` (a no-op outside a
+    profiler session, ~100 ns), or ``None`` where jax cannot be imported."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+
+            _trace_annotation = TraceAnnotation
+        except Exception:
+            _trace_annotation = False
+    return _trace_annotation(name) if _trace_annotation else None
+
+
+class Span:
+    """One open span (:meth:`FlightRecorder.span`).  ``start_ns``/``end_ns``
+    are ring-clock stamps, readable after the block so a caller that also
+    wants the duration (``StepRecord``) reads no second pair of clocks;
+    ``fields`` may be filled in while the span is open.  On a disabled
+    recorder the stamps are still taken and nothing else happens."""
+
+    __slots__ = ("name", "fields", "start_ns", "end_ns", "_rec", "_ann")
+
+    def __init__(self, rec: "FlightRecorder", name: str, fields: dict):
+        self._rec = rec
+        self.name = name
+        self.fields = fields
+        self.start_ns = self.end_ns = 0
+        self._ann = None
+
+    @property
+    def ms(self) -> float:
+        return (self.end_ns - self.start_ns) / 1e6
+
+    def __enter__(self) -> "Span":
+        rec = self._rec
+        if rec.enabled:
+            self._ann = _annotation(self.name)
+            if self._ann is not None:
+                self._ann.__enter__()
+        self.start_ns = time.monotonic_ns() + rec._wall_offset_ns
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        rec = self._rec
+        self.end_ns = time.monotonic_ns() + rec._wall_offset_ns
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        rec.record_span(self.name, self.start_ns, self.end_ns, **self.fields)
+        return False
+
+
 class FlightRecorder:
     """The per-process event ring.  One module-level instance
     (:func:`recorder`) serves the whole process; constructing private
@@ -84,16 +175,32 @@ class FlightRecorder:
     def __init__(self, capacity: int = _DEFAULT_CAPACITY, enabled: bool = True):
         self.capacity = max(16, int(capacity))
         self.enabled = bool(enabled)
+        # slot: (seq, monotonic ns, kind or span name, fields, dur ns or None)
         self._slots: list = [None] * self.capacity
         self._n = 0  # events ever appended (monotone; ring head = n % cap)
         self._collective_seq = 0
-        self._last_monotonic: Optional[float] = None
+        self._last_ns: Optional[int] = None
+        # monotonic ns up to which overwritten events reached (spans())
+        self._overwritten_until_ns: Optional[int] = None
         self._lock = threading.Lock()
         # monotonic↔wall anchor: collective seqs align ranks *ordinally*;
         # the wall anchor lets tools place per-rank monotonic stamps on one
-        # absolute timeline (outage_summary --blackbox join)
-        self._anchor_wall = time.time()
-        self._anchor_monotonic = time.monotonic()
+        # absolute timeline (outage_summary --blackbox join), and is what
+        # turns a monotonic stamp into the ring clock
+        wall_ns, mono_ns, perf_ns = _clock_anchor()
+        self._anchor_wall = wall_ns / 1e9
+        self._anchor_monotonic = mono_ns / 1e9
+        self._wall_offset_ns = wall_ns - mono_ns
+        self._perf_offset_ns = wall_ns - perf_ns
+
+    # -- the ring clock ------------------------------------------------------
+    def now_ns(self) -> int:
+        """The ring clock now: Unix-epoch ns, ticking with ``monotonic_ns``."""
+        return time.monotonic_ns() + self._wall_offset_ns
+
+    def from_perf_counter(self, t: Optional[float]) -> Optional[int]:
+        """A ``time.perf_counter()`` stamp (seconds) on the ring clock."""
+        return None if t is None else int(t * 1e9) + self._perf_offset_ns
 
     # -- producers (hot path) ------------------------------------------------
     @staticmethod
@@ -106,16 +213,41 @@ class FlightRecorder:
                 fields[f"field_{reserved}"] = fields.pop(reserved)
         return fields
 
+    def _append(self, t_ns: int, kind: str, fields: dict, dur_ns: Optional[int]) -> None:
+        # caller holds the lock
+        i = self._n % self.capacity
+        old = self._slots[i]
+        if old is not None:
+            self._overwritten_until_ns = old[1] + (old[4] or 0)
+        self._slots[i] = (self._n, t_ns, kind, fields, dur_ns)
+        self._n += 1
+        self._last_ns = t_ns + (dur_ns or 0)
+
     def record(self, kind: str, /, **fields) -> None:
         """Append one event.  Never raises; no-op when disabled."""
         if not self.enabled:
             return
         fields = self._shield_reserved(fields, ("kind", "seq", "t"))
-        now = time.monotonic()
+        now = time.monotonic_ns()
         with self._lock:
-            self._slots[self._n % self.capacity] = (self._n, now, kind, fields)
-            self._n += 1
-            self._last_monotonic = now
+            self._append(now, kind, fields, None)
+
+    def span(self, name: str, /, **fields) -> Span:
+        """``with rec.span("atpu/serve/step", step=3) as sp:`` — one slot when
+        the block closes, and a profiler annotation of the same name around
+        it.  Keep spans out of per-token and per-slot loops: at most a
+        handful per engine step or captured call."""
+        return Span(self, name, fields)
+
+    def record_span(self, name: str, start_ns: int, end_ns: int, /, **fields) -> None:
+        """Append a span from ring-clock stamps the caller already holds."""
+        if not self.enabled:
+            return
+        fields = self._shield_reserved(fields, ("kind", "seq", "t", "dur_ms"))
+        with self._lock:
+            self._append(
+                start_ns - self._wall_offset_ns, name, fields, max(0, end_ns - start_ns)
+            )
 
     def note_collective(self, op: str, /, **fields) -> int:
         """Tick the collective-sequence counter and record the event.
@@ -125,15 +257,13 @@ class FlightRecorder:
         if not self.enabled:
             return self._collective_seq
         fields = self._shield_reserved(fields, ("kind", "seq", "t", "cseq", "op"))
-        now = time.monotonic()
+        now = time.monotonic_ns()
         with self._lock:
             self._collective_seq += 1
             seq = self._collective_seq
             fields["cseq"] = seq
             fields["op"] = op
-            self._slots[self._n % self.capacity] = (self._n, now, "collective", fields)
-            self._n += 1
-            self._last_monotonic = now
+            self._append(now, "collective", fields, None)
         return seq
 
     # -- consumers -----------------------------------------------------------
@@ -154,10 +284,10 @@ class FlightRecorder:
         return max(0, self._n - self.capacity)
 
     def seconds_since_last_event(self) -> Optional[float]:
-        last = self._last_monotonic
+        last = self._last_ns
         if last is None:
             return None
-        return max(0.0, time.monotonic() - last)
+        return max(0.0, (time.monotonic_ns() - last) / 1e9)
 
     def health(self) -> dict:
         """Recorder self-diagnostics for the Prometheus endpoint
@@ -172,24 +302,45 @@ class FlightRecorder:
             "last_event_age_seconds": round(age, 3) if age is not None else None,
         }
 
-    def snapshot(self) -> list[dict]:
-        """Retained events, oldest first, as dicts — safe to call from the
-        watchdog thread while producers keep appending."""
+    def _retained(self) -> list:
+        """Retained slots, oldest first — safe to call from the watchdog
+        thread while producers keep appending."""
         with self._lock:
             n, cap = self._n, self.capacity
             slots = list(self._slots)
-        start = max(0, n - cap)
+        return [s for s in (slots[i % cap] for i in range(max(0, n - cap), n)) if s is not None]
+
+    def snapshot(self) -> list[dict]:
+        """Retained events, oldest first, as dicts stamped in monotonic
+        seconds; a span carries its start as ``t`` and a ``dur_ms``."""
         out = []
-        for i in range(start, n):
-            slot = slots[i % cap]
-            if slot is None:
-                continue
-            seq, t, kind, fields = slot
-            event = {"seq": seq, "t": round(t, 6), "kind": kind}
+        for seq, t_ns, kind, fields, dur_ns in self._retained():
+            event = {"seq": seq, "t": round(t_ns / 1e9, 6), "kind": kind}
+            if dur_ns is not None:
+                event["dur_ms"] = round(dur_ns / 1e6, 6)
             if fields:
                 event.update(fields)
             out.append(event)
         return out
+
+    def spans(self, start_ns: int, end_ns: int) -> tuple:
+        """``(events, dropped)`` for a ring-clock interval: the retained
+        spans and instants that touch it, oldest first, each ``{"name",
+        "start_ns", "end_ns", **fields}`` (an instant has ``end_ns ==
+        start_ns``), and how many events the ring has overwritten that may
+        have lain in it — all it ever dropped if the interval begins before
+        the newest overwritten one ended, else 0.  A reader that sees drops
+        has an incomplete interval and should say so, not guess."""
+        off = self._wall_offset_ns
+        events = []
+        for _, t_ns, kind, fields, dur_ns in self._retained():
+            s = t_ns + off
+            e = s + (dur_ns or 0)
+            if e >= start_ns and s <= end_ns:
+                events.append({**fields, "name": kind, "start_ns": s, "end_ns": e})
+        until = self._overwritten_until_ns
+        lost = self.dropped if until is not None and start_ns <= until + off else 0
+        return events, lost
 
     def to_dict(self, reason: str = "manual") -> dict:
         """The full per-rank dump payload (watchdog stall, fatal signal,
@@ -212,7 +363,6 @@ class FlightRecorder:
             "dropped": self.dropped,
             "events": self.snapshot(),
         }
-
     def dump(self, dir_or_path: str, reason: str = "manual",
              extra: Optional[dict] = None) -> Optional[str]:
         """Write the per-rank JSON dump.  ``dir_or_path`` naming a directory
@@ -257,3 +407,8 @@ def record(kind: str, /, **fields) -> None:
 
 def note_collective(op: str, /, **fields) -> int:
     return _RECORDER.note_collective(op, **fields)
+
+
+def span(name: str, /, **fields) -> Span:
+    """Module-level shortcut: ``with flightrec.span("atpu/serve/step"):``."""
+    return _RECORDER.span(name, **fields)

@@ -34,6 +34,7 @@ capture path.
 
 from __future__ import annotations
 
+import contextlib
 import glob
 import gzip
 import json
@@ -41,7 +42,9 @@ import os
 import re
 import shutil
 import tempfile
+import threading
 import time
+import weakref
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -190,19 +193,33 @@ def classify_op(name: str) -> str:
     return "compute"
 
 
+_OP_LINE = "XLA Ops"  # the one device line whose events are executed ops
+
+
 def parse_trace_events(events: list, top_k: int = 10) -> dict:
     """Trace-event JSON (chrome format, µs timestamps) → per-device busy +
     compute/collective/transfer split + top-k ops by device time.
 
     A *device op* is a complete (``ph == "X"``) event carrying an
-    ``args.hlo_op`` tag, or any complete event under a process whose
-    metadata name starts with ``/device:`` (the TPU layout).  Everything
-    else — python frames, runtime bookkeeping, thread markers — is host
-    noise and ignored."""
+    ``args.hlo_op`` tag, or a complete event under a process whose metadata
+    name starts with ``/device:`` (the TPU layout) — there on the ``XLA
+    Ops`` thread alone where the trace names its threads: a real v5e trace
+    also has ``XLA Modules`` and ``Steps`` lines, whose events span the
+    gaps between ops, and ``Async XLA Ops`` beside them, and counting
+    those made an idle chip read busy (PERF.md, PR 26).  Everything else —
+    python frames, runtime bookkeeping, thread markers — is host noise and
+    ignored."""
     process_names: dict = {}
+    thread_names: dict = {}
     for ev in events:
-        if ev.get("ph") == "M" and ev.get("name") == "process_name":
+        if ev.get("ph") != "M":
+            continue
+        if ev.get("name") == "process_name":
             process_names[ev.get("pid")] = ev.get("args", {}).get("name", "")
+        elif ev.get("name") == "thread_name":
+            thread_names[(ev.get("pid"), ev.get("tid"))] = ev.get("args", {}).get("name", "")
+    # device processes whose op line is named: only that line is device work
+    op_line_pids = {pid for (pid, _), name in thread_names.items() if name == _OP_LINE}
     per_device: dict[str, dict] = {}
     intervals: dict[str, list] = {}
     op_ms: dict[str, float] = {}
@@ -213,9 +230,14 @@ def parse_trace_events(events: list, top_k: int = 10) -> dict:
             continue
         args = ev.get("args")
         pname = process_names.get(ev.get("pid"), "")
-        is_op = (isinstance(args, dict) and "hlo_op" in args) or pname.startswith(
-            "/device:"
-        )
+        pid = ev.get("pid")
+        if pname.startswith("/device:"):
+            is_op = (
+                pid not in op_line_pids
+                or thread_names.get((pid, ev.get("tid"))) == _OP_LINE
+            )
+        else:
+            is_op = isinstance(args, dict) and "hlo_op" in args
         if not is_op:
             continue
         try:
@@ -254,17 +276,19 @@ def parse_trace_events(events: list, top_k: int = 10) -> dict:
 _HLO_OP_NAME_RE = re.compile(r"%?([\w.\-]+) = [^\n]*op_name=\"([^\"]+)\"")
 
 
-def scope_map_from_compiled(compiled) -> dict:
-    """``{hlo instruction name: atpu phase}`` from a compiled program's HLO
-    text.  The phase is the DEEPEST ``atpu``-prefixed segment of the op's
-    scope path (``jit(f)/atpu_captured_body/atpu_update/add`` →
-    ``atpu_update``); unscoped instructions are omitted.  Fail-soft: any
-    error returns an empty map and the sample simply carries no phase
-    split."""
-    try:
-        text = compiled.as_text()
-    except Exception:
-        return {}
+# every instruction of a module's text, scoped or not (coverage checks)
+_HLO_INSTRUCTION_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ", re.MULTILINE)
+
+
+def scope_map_from_text(text: str) -> dict:
+    """``{hlo instruction name: atpu phase}`` from a program's HLO text.
+    The phase is the DEEPEST ``atpu``-prefixed segment of the op's scope
+    path (``jit(f)/atpu_captured_body/atpu_update/add`` → ``atpu_update``);
+    unscoped instructions are omitted.  Two consequences worth knowing: a
+    fused instruction carries ONE scope, its root's; and the backward of a
+    scoped forward region runs under ``transpose(jvp(<scope>))`` segments,
+    which do not start with ``atpu``, so it falls to the scope that
+    encloses the backward pass (``atpu_backward``)."""
     scope_map: dict = {}
     for match in _HLO_OP_NAME_RE.finditer(text):
         name, path = match.group(1), match.group(2)
@@ -275,6 +299,192 @@ def scope_map_from_compiled(compiled) -> dict:
         if phase is not None:
             scope_map[name] = phase
     return scope_map
+
+
+def scope_map_from_compiled(compiled) -> dict:
+    """:func:`scope_map_from_text` of a compiled program.  Fail-soft: any
+    error returns an empty map and the sample simply carries no phase
+    split."""
+    try:
+        return scope_map_from_text(compiled.as_text())
+    except Exception:
+        return {}
+
+
+# -- process-wide program registry (docs/telemetry.md §spans and scopes) ----
+#
+# A reader of a device trace sees bare instruction names; the scopes live in
+# the program's HLO text.  Producers register HOW to get that text — nothing
+# is lowered, compiled, fetched or parsed until a reader asks, after the
+# timed window — and the registry outlives the Accelerator and the service.
+# It holds no device buffer: the captured step's compiled handle is held
+# weakly (``settle_programs`` reads its text while the owner still lives),
+# the serving programs are re-lowered from shapes alone.
+_PROGRAMS_MAX = 8
+_programs: dict = {}  # key -> Program, oldest first
+_programs_lock = threading.Lock()
+
+
+class Program:
+    """One registered program: its module name and, unevaluated until a
+    reader asks, the way to its HLO text."""
+
+    __slots__ = ("name", "perishable", "_text_fn", "_parsed")
+
+    def __init__(self, name: str, text_fn=None, scope_map: Optional[dict] = None,
+                 perishable: bool = False):
+        self.name = name
+        self.perishable = perishable  # the way to the text dies with its owner
+        # (scope map, every instruction name); a persisted map (AOT warm
+        # start) stands in for the text and knows only its own, scoped, names
+        self._parsed = (dict(scope_map), frozenset(scope_map)) if scope_map else None
+        # () -> HLO text or None; dropped once evaluated
+        self._text_fn = None if scope_map else text_fn
+
+    @property
+    def evaluated(self) -> bool:
+        return self._text_fn is None
+
+    def evaluate(self) -> None:
+        text_fn, self._text_fn = self._text_fn, None
+        if text_fn is None:
+            return
+        try:
+            text = text_fn()
+        except Exception as exc:
+            logger.warning("HLO text of program %s unavailable: %s", self.name, exc)
+            text = None
+        if text:
+            self._parsed = (
+                scope_map_from_text(text),
+                frozenset(_HLO_INSTRUCTION_RE.findall(text)),
+            )
+
+    def scope_map(self) -> dict:
+        self.evaluate()
+        return self._parsed[0] if self._parsed else {}
+
+    def instruction_names(self) -> frozenset:
+        self.evaluate()
+        return self._parsed[1] if self._parsed else frozenset()
+
+
+def register_program(name: str, text_fn, key=None, scope_map: Optional[dict] = None,
+                     perishable: bool = False) -> Program:
+    """Remember how to get the HLO text of the program whose module is named
+    ``name`` (``jit_traced``, ``jit__decode_jit``): ``text_fn()`` returns it,
+    or ``None`` once it cannot.  ``key`` (default ``name``) identifies the
+    program; registering a key again replaces the entry.  ``scope_map``
+    hands over an already parsed map instead (the AOT store's).
+    ``perishable`` marks a ``text_fn`` that stops answering when the
+    program's owner goes (:func:`compiled_text_fn`): ``settle_programs``
+    evaluates those.  Only the newest few programs are kept."""
+    key = name if key is None else key
+    program = Program(name, text_fn, scope_map, perishable)
+    with _programs_lock:
+        _programs.pop(key, None)
+        _programs[key] = program
+        while len(_programs) > _PROGRAMS_MAX:
+            del _programs[next(iter(_programs))]
+    return program
+
+
+def program_registered(key) -> bool:
+    return key in _programs
+
+
+def registered_programs() -> list:
+    with _programs_lock:
+        return list(_programs.values())
+
+
+def _find_program(module_needle: str) -> Optional[Program]:
+    for program in reversed(registered_programs()):
+        if module_needle in program.name:
+            return program
+    return None
+
+
+def settle_programs() -> None:
+    """Evaluate the perishable entries now.  Called where the owners of
+    weakly held programs are about to go (``Accelerator.free_memory``),
+    never on a timed path."""
+    for program in registered_programs():
+        if program.perishable:
+            program.evaluate()
+
+
+def scope_map(module_needle: str) -> dict:
+    """``{instruction: atpu scope}`` of the newest registered program whose
+    module name holds ``module_needle``; ``{}`` where none is known.  The
+    first call for a program fetches and parses its text."""
+    program = _find_program(module_needle)
+    return program.scope_map() if program is not None else {}
+
+
+def instruction_names(module_needle: str) -> frozenset:
+    """Every instruction name in that program's text, scoped or not: what a
+    reader checks a trace's names against before it trusts the map."""
+    program = _find_program(module_needle)
+    return program.instruction_names() if program is not None else frozenset()
+
+
+@contextlib.contextmanager
+def scopes_in_cache_key():
+    """Lower and compile inside this where the program's scopes will be read
+    from its executable.  JAX's persistent-cache key leaves metadata out by
+    default ("executables loaded from the cache may have stale metadata,
+    which may show up in, e.g., profiles"), so an executable compiled before
+    a scope existed (a parent commit's, on a shared cache) would be served
+    with its old names; here the key takes the metadata in.  The locations
+    themselves are left as they are: cutting them to one frame makes JAX
+    share primitive lowerings between call sites, and the shared copies lose
+    the scope path (found on the chip, PERF.md PR 27)."""
+    import jax
+
+    name = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, name)
+    jax.config.update(name, True)
+    try:
+        yield
+    finally:
+        jax.config.update(name, before)
+
+
+def compiled_text_fn(compiled):
+    """``text_fn`` for a compiled handle, held weakly: the registry must not
+    keep an executable (and its device memory) alive past its owner."""
+    ref = weakref.ref(compiled)
+
+    def text():
+        live = ref()
+        return live.as_text() if live is not None else None
+
+    return text
+
+
+def relowered_text_fn(jit_fn, args, statics: dict):
+    """``text_fn`` for a plain ``jax.jit`` function called with ``args`` and
+    ``statics``: keeps their shapes, dtypes and committed shardings — no
+    buffer — and lowers and compiles again from those when asked (a
+    persistent-cache hit where the cache is armed)."""
+    import jax
+
+    def spec(x):
+        if isinstance(x, jax.Array):
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=x.sharding if x.committed else None,
+                weak_type=x.weak_type,
+            )
+        return x
+
+    specs = jax.tree_util.tree_map(spec, args)
+
+    def text():
+        with scopes_in_cache_key():  # the key the first call compiled under
+            return jit_fn.lower(*specs, **statics).compile().as_text()
+
+    return text
 
 
 def split_phases(op_detail: dict, scope_map: dict) -> dict:

@@ -7,12 +7,16 @@ https://ui.perfetto.dev, "Trace Event Format" JSON):
 * **flight events** (``telemetry/flightrec.py``) — instant events on the
   ``flight events`` track, monotonic-stamped at the source; the
   ``step_begin``/``step_end`` pair per captured call is also the *anchor*
-  that places the other two streams on the absolute axis;
-* **host step phases** (``StepRecord`` — dataloader-wait / assembly /
-  trace / compile / dispatch ms) — complete ("X") events on the ``host
-  phases`` track, laid out inside the step's flight window in phase order
-  (dataloader wait sits *before* the begin stamp: it was paid between
-  calls);
+  that places the device stream on the absolute axis;
+* **host phases** — the ring's spans (``atpu/step/assemble``,
+  ``atpu/dispatch``, ``atpu/step/writeback``, ``atpu/trace``,
+  ``atpu/compile``, and the engine step's ``atpu/serve/*``) as complete
+  ("X") events on the ``host phases`` track, at the stamps they were taken
+  at.  A step whose spans the ring no longer holds (it wrapped, or the
+  recorder is off) falls back to its ``StepRecord``'s durations laid out in
+  phase order from the step's begin stamp; ``dataloader_wait_ms`` always
+  comes from the record and sits *before* the begin stamp (it was paid
+  between calls);
 * **device op timelines** (``DeviceStepRecord.top_ops`` from the sampled
   profiler) — complete events on the ``device ops`` track, laid
   sequentially from the step's begin stamp.  Placement within the step is
@@ -69,8 +73,19 @@ def build_trace(telemetry=None, recorder: Optional[flightrec.FlightRecorder] = N
     flight = rec.snapshot()
     step_begin: dict[int, float] = {}
     step_end: dict[int, float] = {}
+    spanned_steps = set()  # captured calls whose host phases the ring holds
     for ev in flight:
         t_us = ev["t"] * 1e6
+        if "dur_ms" in ev:
+            args = {k: v for k, v in ev.items() if k not in ("kind", "t", "dur_ms")}
+            events.append(
+                {"ph": "X", "pid": pid, "tid": _HOST_TID, "ts": t_us,
+                 "dur": ev["dur_ms"] * 1e3, "name": ev["kind"], "cat": "host",
+                 "args": args}
+            )
+            if ev["kind"] == "atpu/step/assemble" and "step" in ev:
+                spanned_steps.add(ev["step"])
+            continue
         if ev["kind"] == "step_begin" and "step" in ev:
             step_begin.setdefault(ev["step"], t_us)
         elif ev["kind"] == "step_end" and "step" in ev:
@@ -106,6 +121,8 @@ def build_trace(telemetry=None, recorder: Optional[flightrec.FlightRecorder] = N
                  "name": f"step {step}: dataloader_wait", "cat": "host",
                  "args": {"step": step}}
             )
+        if step in spanned_steps:
+            continue  # its phases are on the track already, at real stamps
         cursor = begin
         for phase in _PHASE_ORDER:
             ms = record.get(phase) or 0.0

@@ -199,9 +199,9 @@ class TelemetryKwargs(KwargsHandler):
     ``timeline_size`` bounds the per-step ring buffer; ``max_events`` bounds
     each event stream (recompiles / program stats / resource samples);
     ``sample_resources`` additionally snapshots per-device live bytes at
-    every capture; ``annotate_spans`` wraps each phase in a
-    ``jax.profiler.TraceAnnotation`` so xprof traces show named capture
-    phases; ``jsonl_path`` (or ``$ACCELERATE_TELEMETRY_JSONL``) auto-dumps
+    every capture (the capture phases' xprof spans are the flight
+    recorder's, always on: docs/telemetry.md §spans and scopes);
+    ``jsonl_path`` (or ``$ACCELERATE_TELEMETRY_JSONL``) auto-dumps
     the full history at ``end_training``/tracker ``finish``.
 
     ``profile_every_n`` (or ``$ACCELERATE_TELEMETRY_PROFILE_N``; 0 = off)
@@ -233,7 +233,6 @@ class TelemetryKwargs(KwargsHandler):
     timeline_size: int = 256
     max_events: int = 256
     sample_resources: bool = True
-    annotate_spans: bool = True
     jsonl_path: Optional[str] = None
     profile_every_n: Optional[int] = None  # None → env, default 0 (off)
     profile_dir: Optional[str] = None

@@ -137,6 +137,32 @@ def test_parse_trace_events_tpu_device_pids():
     assert all(name != "ExecuteOnDevice" for name, _ in parsed["top_ops"])
 
 
+@pytest.mark.parametrize("line, counts", [
+    ("XLA Ops", True), ("XLA Modules", False), ("Steps", False), ("Async XLA Ops", False),
+])
+def test_parse_trace_events_takes_the_op_line_alone(line, counts):
+    """A real v5e trace names its device threads: the module and step lines
+    span the gaps between ops and the async line runs beside them, so only
+    ``XLA Ops`` is device work (PERF.md, PR 26; benchmark/trace_reduce.py)."""
+    events = [
+        {"ph": "M", "pid": 7, "name": "process_name", "args": {"name": "/device:TPU:0"}},
+        {"ph": "M", "pid": 7, "tid": 1, "name": "thread_name", "args": {"name": "XLA Ops"}},
+        {"ph": "M", "pid": 7, "tid": 2, "name": "thread_name", "args": {"name": line}},
+        {"ph": "X", "pid": 7, "tid": 1, "ts": 0.0, "dur": 100.0, "name": "fusion.1"},
+        {"ph": "X", "pid": 7, "tid": 1, "ts": 900.0, "dur": 100.0, "name": "fusion.2"},
+    ]
+    if line != "XLA Ops":
+        # one event over the whole second op-gap and both ops
+        events.append({"ph": "X", "pid": 7, "tid": 2, "ts": 0.0, "dur": 1000.0,
+                       "name": "jit_traced(123)"})
+    parsed = parse_trace_events(events)
+    tpu = parsed["devices"]["/device:TPU:0"]
+    assert counts or line != "XLA Ops"
+    assert parsed["op_events"] == 2
+    assert tpu["busy_ms"] == pytest.approx(0.2)
+    assert all(name != "jit_traced(123)" for name, _ in parsed["top_ops"])
+
+
 def test_split_phases_joins_scope_map_and_buckets_unscoped():
     """Per-phase device attribution (docs/telemetry.md): sampled op
     durations joined to the program's HLO op->scope map, with ops outside
