@@ -2,8 +2,8 @@
 §paged-attention; the vLLM move).
 
 The reference decode (``serving/engine.py``) attends each slot with
-``kp_l[row]`` — a gather that MATERIALIZES the slot's full page span
-``(blocks_per_slot, n_kv, block_size, d)`` in HBM for every slot × every
+``kp[layer·NB + row]`` — a gather that MATERIALIZES the slot's full page
+span ``(blocks_per_slot, block_size, n_kv · d)`` in HBM for every slot × every
 layer × every token, then hands the copy to ``cached_attention``.  The
 kernel here runs one grid program per slot: it walks the slot's block-table
 row, streams each page into VMEM scratch (direct dynamic-index loads in
@@ -85,7 +85,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, positions, *, cfg,
     """Attend the whole slot batch one token against the paged KV pool.
 
     ``q: (slots, H, 1, d)``; ``k_pool/v_pool: (num_blocks, n_kv, bs, d)``
-    (ONE layer's pools — the caller's layer scan passes each layer);
+    (ONE layer's pools in the kernel's own layout — the engine's layer loop
+    builds that view of the current layer from the pool it carries);
     ``block_tables: (slots, blocks_per_slot)``; ``positions: (slots,)``.
     Returns ``(slots, H, 1, d)`` in the pool dtype, bitwise-equal to the
     reference gather-then-attend."""

@@ -4,10 +4,11 @@ Two separately jitted programs, split so a long incoming prompt never
 stalls token streaming for in-flight sequences:
 
 * ``run_prefill`` — ONE request's bucket-padded prompt through the layer
-  scan; writes its k/v into the pool blocks the scheduler reserved and
-  samples the request's first token.  One compiled variant per
-  ``(bucket_len, mode)`` — prompt lengths are bucketed by the scheduler
-  (``kv_blocks.bucket_length``), the TRUE length rides as a traced scalar.
+  scan; writes its k/v into the pool blocks the scheduler reserved (one
+  scatter of whole pages a layer) and samples the request's first token.
+  One compiled variant per ``(bucket_len, mode)`` — prompt lengths are
+  bucketed by the scheduler (``kv_blocks.bucket_length``), the TRUE length
+  rides as a traced scalar.
 * ``run_decode_n`` — the WHOLE slot batch ``decode_steps`` tokens forward
   inside ONE captured program: each micro-step embeds the slot's current
   token at its own position, scatters the new k/v into the pool
@@ -32,9 +33,20 @@ per-sequence identical to a single-request ``generate()``: same per-token
 math, same true positions, same mask formula; only the (masked, zero-prob)
 padding width differs.
 
-Pools are DONATED through both programs — the update is in-place at the XLA
-level, never a pool-sized copy per token.  The multi-token program's
-positions/tokens/rng streams are returned (the scheduler owns them as
+Pools are DONATED through both programs and the layer scan CARRIES them
+whole, as ``(L·NB, bs, n_kv·d)`` page rows (``_page_rows``: layer ``l``'s
+block ``b`` is row ``l·NB + b``), beside the layer counter: each layer
+scatters into and gathers from the carried buffer at its own rows, in place.
+They are never the scan's ``xs``/``ys`` — that slices every layer's pool out
+of the stack and writes it back, and with a ``[…, bs, d]`` page the chip's
+compiler also put the block index on the lanes and transposed the layer's
+whole pool around every access: 44 ms of a 155 ms GPT-2-XL decode step, and
+the same in every prefill (PERF.md, PR 28).  A page is one lane-dense
+``[bs, n_kv·d]`` slab; the gathered pages go to ``cached_attention`` as
+``(Hkv, S, d)``.  No pool-sized copy, slice or update is left in either
+program (held against the chip's compiler by tests/test_tpu_compile.py).
+
+The multi-token program's positions/tokens/rng streams are returned (the scheduler owns them as
 committed device arrays and feeds each call's outputs into the next, so a
 steady-state ``decode_steps > 1`` step uploads NOTHING host→device —
 regression-pinned with a ``jax.transfer_guard`` in tests/test_serving.py)
@@ -74,6 +86,14 @@ from ..models.generation import (
 )
 
 
+def _page_rows(pool):
+    """The ``(L, NB, bs, n_kv·d)`` pool as ``(L·NB, bs, n_kv·d)`` page rows:
+    layer ``l``'s block ``b`` is row ``l·NB + b``.  A leading-dimension
+    reshape — free, and what lets the layer loop carry the pool whole and
+    index it in place instead of slicing a layer's pool out and back."""
+    return pool.reshape(-1, *pool.shape[2:])
+
+
 @partial(
     jax.jit,
     static_argnames=("family", "cfg", "qbits", "temperature"),
@@ -95,35 +115,39 @@ def _prefill_jit(
     temperature: float,
 ):
     bucket_len = padded_ids.shape[1]
-    block_size = k_pool.shape[3]
+    pool_shape = k_pool.shape
+    num_blocks, block_size = pool_shape[1], pool_shape[2]
     n_blocks = bucket_len // block_size  # scheduler guarantees divisibility
     positions = jnp.arange(bucket_len)
     plain_layers, q_layers, s_layers = layers
+    kp, vp = _page_rows(k_pool), _page_rows(v_pool)
 
     # the atpu_serve_* scopes are HLO metadata only (numerics untouched): a
     # device trace is split by them (docs/telemetry.md §spans and scopes)
-    def prefill_layer(x, layer_in):
-        l_parts, kp_l, vp_l = layer_in
+    def prefill_layer(carry, l_parts):
+        x, kp, vp, layer = carry
         with jax.named_scope("atpu_serve_qkv"):
             l = _dequant_layer(*l_parts, qbits, x.dtype)
             q, k, v = family.attn_in(l, x, positions, cfg)
         with jax.named_scope("atpu_serve_attend"):
             att = cached_attention(q, k, v, positions, cfg)
         with jax.named_scope("atpu_serve_kv_write"):
-            # the bucket covers whole blocks: write them with one scatter each.
-            # Positions >= prompt_len hold pad-token k/v — invisible behind the
-            # causal mask until the decode loop overwrites them with real tokens
-            kb = k[0].transpose(1, 0, 2).reshape(n_blocks, block_size, k.shape[1], k.shape[3])
-            vb = v[0].transpose(1, 0, 2).reshape(n_blocks, block_size, v.shape[1], v.shape[3])
-            kp_l = kp_l.at[block_row[:n_blocks]].set(kb.transpose(0, 2, 1, 3).astype(kp_l.dtype))
-            vp_l = vp_l.at[block_row[:n_blocks]].set(vb.transpose(0, 2, 1, 3).astype(vp_l.dtype))
+            # the bucket covers whole blocks: write them with one scatter each,
+            # a page being the block's (bs, n_kv·d) slab.  Positions >=
+            # prompt_len hold pad-token k/v — invisible behind the causal mask
+            # until the decode loop overwrites them with real tokens
+            rows = layer * num_blocks + block_row[:n_blocks]
+            kb = k[0].transpose(1, 0, 2).reshape(n_blocks, block_size, -1)
+            vb = v[0].transpose(1, 0, 2).reshape(n_blocks, block_size, -1)
+            kp = kp.at[rows].set(kb.astype(kp.dtype))
+            vp = vp.at[rows].set(vb.astype(vp.dtype))
         with jax.named_scope("atpu_serve_out_mlp"):
-            return family.attn_out(l, x, att, cfg), (kp_l, vp_l)
+            return (family.attn_out(l, x, att, cfg), kp, vp, layer + 1), None
 
     with jax.named_scope("atpu_serve_embed"):
         x = family.embed(g, padded_ids, positions, cfg)
-    x, (k_pool, v_pool) = jax.lax.scan(
-        prefill_layer, x, ((plain_layers, q_layers, s_layers), k_pool, v_pool)
+    (x, kp, vp, _), _ = jax.lax.scan(
+        prefill_layer, (x, kp, vp, jnp.int32(0)), (plain_layers, q_layers, s_layers)
     )
     with jax.named_scope("atpu_serve_head"):
         # logits at the TRUE last prompt position (finalize reads x[:, -1], so
@@ -137,7 +161,7 @@ def _prefill_jit(
         else:
             rng_out, key = jax.random.split(rng)
             tok = jax.random.categorical(key, logits / temperature, axis=-1).astype(jnp.int32)
-    return k_pool, v_pool, tok[0], rng_out
+    return kp.reshape(pool_shape), vp.reshape(pool_shape), tok[0], rng_out
 
 
 def _decode_body(
@@ -160,8 +184,10 @@ def _decode_body(
     """ONE token for the whole slot batch — the micro-step body shared by
     every ``decode_steps`` variant, so an n-token block is bitwise the same
     math as n single-token dispatches (the parity contract)."""
-    block_size = k_pool.shape[3]
+    pool_shape = k_pool.shape
+    num_blocks, block_size = pool_shape[1], pool_shape[2]
     plain_layers, q_layers, s_layers = layers
+    kp, vp = _page_rows(k_pool), _page_rows(v_pool)
 
     # the atpu_serve_* scopes are HLO metadata only (numerics untouched): a
     # device trace is split by them (docs/telemetry.md §spans and scopes)
@@ -172,24 +198,27 @@ def _decode_body(
             tokens, positions
         )  # (slots, 1, c)
 
-    def decode_layer(x, layer_in):
-        l_parts, kp_l, vp_l = layer_in
+    def decode_layer(carry, l_parts):
+        x, kp, vp, layer = carry
+        base = layer * num_blocks  # this layer's first page row
         with jax.named_scope("atpu_serve_qkv"):
             l = _dequant_layer(*l_parts, qbits, x.dtype)
             q, k, v = jax.vmap(
                 lambda x_s, p_s: family.attn_in(l, x_s[None], p_s[None], cfg)
             )(x, positions)
             q, k, v = q[:, 0], k[:, 0], v[:, 0]  # (slots, H|Hkv, 1, d)
+            n_kv, d = k.shape[1], k.shape[3]
         with jax.named_scope("atpu_serve_kv_write"):
-            # scatter each slot's new k/v into its current block.  Inactive
-            # slots' tables point at trash block 0, so the unconditional write
-            # (and any duplicate trash indices) never touches live cache
-            blk = jnp.take_along_axis(
+            # scatter each slot's new k/v (one (n_kv·d) row) into its current
+            # page, in place on the carried pool.  Inactive slots' tables point
+            # at trash block 0, so the unconditional write (and any duplicate
+            # trash indices) never touches live cache
+            blk = base + jnp.take_along_axis(
                 block_tables, (positions // block_size)[:, None], axis=1
             )[:, 0]
             off = positions % block_size
-            kp_l = kp_l.at[blk, :, off].set(k[:, :, 0, :].astype(kp_l.dtype))
-            vp_l = vp_l.at[blk, :, off].set(v[:, :, 0, :].astype(vp_l.dtype))
+            kp = kp.at[blk, off].set(k[:, :, 0, :].reshape(-1, n_kv * d).astype(kp.dtype))
+            vp = vp.at[blk, off].set(v[:, :, 0, :].reshape(-1, n_kv * d).astype(vp.dtype))
 
         if paged:
             # paged-attention kernel (docs/kernels.md): walk the block table
@@ -205,10 +234,17 @@ def _decode_body(
                 )
             from ..native.kernels.paged_attention import paged_attention
 
+            def layer_view(pool):
+                # the kernel's own layout, (NB, n_kv, bs, d), built from the
+                # carried pool after the write.  A layer-sized copy: the
+                # kernel runs in interpret mode only (the TPU refuses it)
+                pages = jax.lax.dynamic_slice_in_dim(pool, base, num_blocks)
+                return pages.reshape(num_blocks, block_size, n_kv, d).transpose(0, 2, 1, 3)
+
             with jax.named_scope("atpu_serve_attend"):
                 att = paged_attention(
-                    q, kp_l, vp_l, block_tables, positions, cfg=cfg,
-                    interpret=kernel_interpret,
+                    q, layer_view(kp), layer_view(vp), block_tables, positions,
+                    cfg=cfg, interpret=kernel_interpret,
                 )
         else:
             # two vmaps where one would do, so that each phase's scope sits
@@ -219,9 +255,9 @@ def _decode_body(
                 # gather this slot's pages: table order IS logical order, so
                 # the flattened view is a virtually contiguous cache and the
                 # plain causal mask applies unchanged
-                kc = kp_l[row].transpose(1, 0, 2, 3).reshape(kp_l.shape[1], -1, kp_l.shape[3])
-                vc = vp_l[row].transpose(1, 0, 2, 3).reshape(vp_l.shape[1], -1, vp_l.shape[3])
-                return kc, vc
+                kc = kp[base + row].reshape(-1, n_kv, d).transpose(1, 0, 2)
+                vc = vp[base + row].reshape(-1, n_kv, d).transpose(1, 0, 2)
+                return kc, vc  # (Hkv, S, d)
 
             def attend_one(q_s, kc, vc, p_s):
                 return cached_attention(q_s[None], kc[None], vc[None], p_s[None], cfg)[0]
@@ -234,10 +270,10 @@ def _decode_body(
             x = jax.vmap(lambda x_s, a_s: family.attn_out(l, x_s[None], a_s[None], cfg)[0])(
                 x, att
             )
-        return x, (kp_l, vp_l)
+        return (x, kp, vp, layer + 1), None
 
-    x, (k_pool, v_pool) = jax.lax.scan(
-        decode_layer, x, ((plain_layers, q_layers, s_layers), k_pool, v_pool)
+    (x, kp, vp, _), _ = jax.lax.scan(
+        decode_layer, (x, kp, vp, jnp.int32(0)), (plain_layers, q_layers, s_layers)
     )
     with jax.named_scope("atpu_serve_head"):
         logits = family.finalize(g, x, cfg)  # (slots, V)
@@ -252,7 +288,7 @@ def _decode_body(
                 return nk, jax.random.categorical(sk, lg / temperature).astype(jnp.int32)
 
             rngs_out, nxt = jax.vmap(sample_one)(rngs, logits)
-    return k_pool, v_pool, nxt, rngs_out
+    return kp.reshape(pool_shape), vp.reshape(pool_shape), nxt, rngs_out
 
 
 @partial(

@@ -6,13 +6,15 @@ contiguous ``(b, n_kv, prompt+max_new, d)`` cache per call — the cache
 fresh program and two requests can never share a batch.  Serving inverts
 that: the cache is ONE preallocated pool of fixed-size blocks
 
-    ``k_pool, v_pool : (L, num_blocks, n_kv_head, block_size, head_dim)``
+    ``k_pool, v_pool : (L, num_blocks, block_size, n_kv_head · head_dim)``
 
-plus an int32 **block table** per batch slot mapping logical position
-``p`` to pool block ``table[slot, p // block_size]``.  Every shape the
-captured programs see (pool, tables, per-slot scalars) is fixed at service
-construction, so slots holding a 7-token and a 900-token sequence replay
-the SAME pinned program — the zero-recompile contract continuous batching
+(a page is one ``[block_size, n_kv_head · head_dim]`` slab; layer ``l``'s
+block ``b`` is row ``l · num_blocks + b`` of the ``(L · num_blocks, …)``
+view the programs index) plus an int32 **block table** per batch slot
+mapping logical position ``p`` to pool block
+``table[slot, p // block_size]``.  Every shape the captured programs see
+(pool, tables, per-slot scalars) is fixed at service construction, so slots
+holding a 7-token and a 900-token sequence replay the SAME pinned program — the zero-recompile contract continuous batching
 needs (PAPERS.md #1: serving economics are batch occupancy + recompile
 avoidance).
 
@@ -164,11 +166,15 @@ class BlockPool:
 
 def make_pools(n_layers: int, num_blocks: int, n_kv_head: int,
                block_size: int, head_dim: int, dtype):
-    """Zero-initialised device pools ``(L, NB, n_kv, bs, d)`` — zeros (not
-    empty) so never-written trash/stale positions stay finite: masked
-    attention multiplies their probs by exactly 0.0, and 0 * finite is 0
-    while 0 * inf would poison the row with NaN."""
+    """Zero-initialised device pools ``(L, NB, bs, n_kv·d)`` — a page is one
+    lane-dense ``[bs, n_kv·d]`` slab and the block index a major dimension,
+    so the programs gather and scatter pages in place (a ``[…, bs, d]`` tail
+    put the block index on the lanes, and every access paid a transpose of
+    the whole layer's pool).  Zeros (not empty) so never-written trash/stale
+    positions stay finite: masked attention multiplies their probs by
+    exactly 0.0, and 0 * finite is 0 while 0 * inf would poison the row with
+    NaN."""
     import jax.numpy as jnp
 
-    shape = (n_layers, num_blocks, n_kv_head, block_size, head_dim)
+    shape = (n_layers, num_blocks, block_size, n_kv_head * head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)

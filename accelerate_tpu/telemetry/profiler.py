@@ -38,6 +38,7 @@ import contextlib
 import glob
 import gzip
 import json
+import math
 import os
 import re
 import shutil
@@ -299,6 +300,35 @@ def scope_map_from_text(text: str) -> dict:
         if phase is not None:
             scope_map[name] = phase
     return scope_map
+
+
+# `%name = type[dims]{layout} opcode(` — an instruction's result shape(s) and
+# opcode; a tuple result lists several shapes before the opcode
+_HLO_RESULT_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (\(?[a-z]\w*\[[^=]*?) ([a-z][a-z\-]*)\(", re.MULTILINE
+)
+_HLO_DIMS_RE = re.compile(r"[a-z]\w*\[([\d,]*)\]")
+
+
+def instructions_of_size(text: str, opcodes, min_elements: int) -> list:
+    """``[(name, opcode, dims)]`` of every instruction of an HLO text —
+    entry, loop bodies and fused computations alike — whose opcode is one of
+    ``opcodes`` and whose largest result, of dimensions ``dims``, has
+    ``min_elements`` or more.  What a guard on a compiled program asks: is a
+    buffer of that size still copied, sliced out or written back anywhere
+    (tests/test_tpu_compile.py, tools/tpu_aot_check.py)."""
+    found = []
+    for name, shapes, opcode in _HLO_RESULT_RE.findall(text):
+        if opcode not in opcodes:
+            continue
+        dims = max(
+            (tuple(int(d) for d in shape.split(",") if d)
+             for shape in _HLO_DIMS_RE.findall(shapes)),
+            key=math.prod, default=(),
+        )
+        if dims and math.prod(dims) >= min_elements:
+            found.append((name, opcode, dims))
+    return found
 
 
 def scope_map_from_compiled(compiled) -> dict:

@@ -111,6 +111,113 @@ def test_continuous_batch_matches_single_request_generate(tiny_model):
     assert service.pool.free_blocks == service.pool.usable_blocks
 
 
+def _contiguous_cache(model, prompt, n_decode):
+    """What ``generate()`` keeps for one request, replayed eagerly: its
+    contiguous ``(L, n_kv, positions, d)`` k/v cache after the prefill and
+    ``n_decode`` greedy decode steps (``_generate_jit``'s own algorithm:
+    prefill the prompt, then one token a step written at its position), and
+    the tokens it sampled on the way."""
+    import jax
+    import jax.numpy as jnp
+
+    from accelerate_tpu.models.generation import (
+        _dequant_layer, cached_attention, stacked_params_for_mode,
+    )
+
+    spec = model._decoder_spec()
+    family, cfg = spec.family, spec.cfg
+    g, (plain, quant, scales) = stacked_params_for_mode(model, 0, spec.stack)
+    n_layers = next(iter(plain.values())).shape[0]
+    layer = lambda i, x: _dequant_layer(  # noqa: E731
+        *jax.tree_util.tree_map(lambda a: a[i], (plain, quant, scales)), 0, x.dtype
+    )
+    p_len = len(prompt)
+    positions = jnp.arange(p_len)
+    x = family.embed(g, jnp.asarray(prompt)[None], positions, cfg)
+    k_cache, v_cache = [], []
+    pad = [(0, 0), (0, 0), (0, n_decode), (0, 0)]
+    for i in range(n_layers):
+        l = layer(i, x)
+        q, k, v = family.attn_in(l, x, positions, cfg)
+        x = family.attn_out(l, x, cached_attention(q, k, v, positions, cfg), cfg)
+        k_cache.append(jnp.pad(k, pad))
+        v_cache.append(jnp.pad(v, pad))
+    tokens = [int(jnp.argmax(family.finalize(g, x[:, -1:], cfg), axis=-1)[0])]
+    for step in range(n_decode):
+        q_pos = jnp.asarray([p_len + step])
+        x = family.embed(g, jnp.asarray([[tokens[-1]]], jnp.int32), q_pos, cfg)
+        for i in range(n_layers):
+            l = layer(i, x)
+            q, k, v = family.attn_in(l, x, q_pos, cfg)
+            k_cache[i] = jax.lax.dynamic_update_slice(k_cache[i], k, (0, 0, p_len + step, 0))
+            v_cache[i] = jax.lax.dynamic_update_slice(v_cache[i], v, (0, 0, p_len + step, 0))
+            x = family.attn_out(l, x, cached_attention(q, k_cache[i], v_cache[i], q_pos, cfg), cfg)
+        tokens.append(int(jnp.argmax(family.finalize(g, x, cfg), axis=-1)[0]))
+    stacked = lambda cache: np.stack([np.asarray(c[0]) for c in cache])  # noqa: E731
+    return stacked(k_cache), stacked(v_cache), tokens
+
+
+def _tiny_llama():
+    from accelerate_tpu.models import LlamaConfig, LlamaForCausalLM
+
+    return LlamaForCausalLM(LlamaConfig.tiny()).eval()  # 4 heads over 2 kv heads
+
+
+@pytest.mark.parametrize("make_model", [lambda: GPTLMHeadModel(GPTConfig.tiny()).eval(), _tiny_llama],
+                         ids=["mha", "gqa"])
+def test_pool_pages_hold_generates_contiguous_cache(make_model):
+    """The pool is ``(L, NB, bs, n_kv·d)`` — a page is one ``[bs, n_kv·d]``
+    slab, the block index a major dimension — and after a prefill and N
+    decode steps the request's pages, read through its block table, hold
+    position for position the k/v of ``generate()``'s contiguous cache (a
+    page off by one block or one row would differ in the first digit)."""
+    nn.manual_seed(0)
+    model = make_model()
+    dcfg = model._decoder_spec().cfg
+    block, n_decode = 4, 7
+    service = DecodeService(model, ServingConfig(max_slots=2, block_size=block, prompt_bucket=8))
+    n_layers = next(iter(service._layers[0].values())).shape[0]
+    assert service._k_pool.shape == service._v_pool.shape == (
+        n_layers, service.pool.num_blocks, block, dcfg.n_kv_head * dcfg.head_dim
+    )
+    # a short request that leaves and a long one that stays, so the request
+    # under test gets the first one's blocks, then blocks past the second's:
+    # its pages are neither in order from 1 nor next to each other
+    service.submit(_prompts([3], seed=3)[0], max_new_tokens=2)
+    service.submit(_prompts([5], seed=5)[0], max_new_tokens=40)
+    service.step()
+    service.step()
+    prompt = _prompts([11], seed=4)[0]
+    rid = service.submit(prompt, max_new_tokens=n_decode + 5)
+    for _ in range(n_decode):  # the first step admits, prefills AND decodes once
+        service.step()
+    slot = next(i for i, r in enumerate(service._slot_req) if r is not None and r.rid == rid)
+    row = service.pool.row(slot)
+    assert any(b - a != 1 for a, b in zip(row, row[1:])), row  # the table is doing work
+    held = len(prompt) + n_decode  # positions written so far
+    assert int(service._positions[slot]) == held
+
+    k_want, v_want, tokens = _contiguous_cache(model, prompt, n_decode)
+    want_ids = np.asarray(model.generate(prompt[None], max_new_tokens=n_decode + 1))[0]
+    np.testing.assert_array_equal(tokens, want_ids[len(prompt):])  # the replay IS generate()
+    for pool, want in ((service._k_pool, k_want), (service._v_pool, v_want)):
+        pool = np.asarray(pool)
+        for page in range(-(-held // block)):
+            lo, hi = page * block, min((page + 1) * block, held)
+            got = pool[:, row[page], : hi - lo].reshape(n_layers, hi - lo, dcfg.n_kv_head, dcfg.head_dim)
+            # to round-off: the replay runs eagerly, the service's programs fused
+            np.testing.assert_allclose(
+                got.transpose(0, 2, 1, 3), want[:, :, lo:hi], rtol=1e-5, atol=1e-5,
+                err_msg=f"page {page} (block {row[page]})",
+            )
+    service.run()
+    np.testing.assert_array_equal(
+        service.results[rid].output_ids,
+        np.asarray(model.generate(prompt[None], max_new_tokens=n_decode + 5))[0],
+    )
+    service.pool.check_no_leaks()
+
+
 def test_zero_recompiles_in_steady_state(tiny_model):
     """After one decode build + one prefill build per prompt bucket, every
     further call replays — the CompileWatcher forensics count stays 0."""

@@ -266,3 +266,74 @@ def test_collective_matmul_policy_arms_on_tpu_refused_kernels_do_not(monkeypatch
         with pytest.raises(NotImplementedError) as refused:
             KernelPolicy(**{name: True}).interpret
         assert refusal_words(name) in str(refused.value)
+
+
+# ---------------------------------------------------------------------------
+# the serving programs hold the KV pool in place (docs/serving.md §1)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def xl_serving_programs(one_chip):
+    """``_decode_jit`` and one ``_prefill_jit`` bucket compiled for the
+    described v5e at GPT-2-XL width (1600, 25 heads) and the serve cell's pool
+    geometry, 2 layers deep (the loop body is the same at 48).  The
+    vocabulary is cut to 2048 rows: at the published 50,304 the token table
+    has six times a layer's pool of elements, and it is not a pool."""
+    import accelerate_tpu.nn as nn
+    from accelerate_tpu.models import GPTConfig, GPTLMHeadModel
+    from accelerate_tpu.models.generation import stacked_params_for_mode
+    from accelerate_tpu.serving import engine, make_pools
+
+    n_layer, slots, block, bps, num_blocks = 2, 16, 16, 64, 513  # gpt2-xl.serve-steady's pool
+    nn.manual_seed(0)
+    model = GPTLMHeadModel(
+        GPTConfig(vocab_size=2048, n_positions=1024, n_embd=1600, n_layer=n_layer, n_head=25)
+    ).eval()
+    for p in model.parameters():  # what prepare(mixed_precision="bf16") serves
+        p.data = p.data.astype(BF16)
+    spec = model._decoder_spec()
+    weights = stacked_params_for_mode(model, 0, spec.stack)
+    pools = jax.eval_shape(lambda: make_pools(
+        n_layer, num_blocks, spec.cfg.n_kv_head, block, spec.cfg.head_dim, BF16
+    ))
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype, one_chip), tree)
+
+    def ints(*shape):
+        return sds(shape, jnp.int32, one_chip)
+
+    statics = dict(family=spec.family, cfg=spec.cfg, qbits=0, temperature=0.0)
+    decode = engine._decode_jit.lower(
+        *abstract(pools), *abstract(weights), ints(slots, bps), ints(slots), ints(slots),
+        sds((slots, 2), jnp.uint32, one_chip), **statics,
+    ).compile()
+    prefill = engine._prefill_jit.lower(
+        *abstract(pools), *abstract(weights), ints(1, 256), ints(bps), ints(),
+        sds((2,), jnp.uint32, one_chip), **statics,
+    ).compile()
+    layer_pool_elements = int(np.prod(pools[0].shape[1:]))
+    return {"decode": decode, "prefill": prefill}, layer_pool_elements
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_serving_programs_move_no_pool_sized_buffer(xl_serving_programs, program):
+    """The layer loop carries the pools whole and indexes them in place: no
+    ``copy``, ``dynamic-slice`` or ``dynamic-update-slice`` of a layer's pool
+    or more is left, inside the loop or outside it, and the donated pools
+    come back in their own buffers.  (A pool with the block index on the
+    lanes, scanned as ``xs``/``ys``, had eight of them a layer and two whole
+    -pool copies after the loop: 44 ms of a 155 ms decode step, PERF.md.)"""
+    from accelerate_tpu.telemetry.profiler import instructions_of_size
+
+    programs, layer_pool_elements = xl_serving_programs
+    text = programs[program].as_text()
+    moved = instructions_of_size(
+        text, ("copy", "dynamic-slice", "dynamic-update-slice"), layer_pool_elements
+    )
+    assert moved == []
+    # the pools ARE in the program, written by the two scatters alone
+    assert len(instructions_of_size(text, ("scatter",), layer_pool_elements)) == 2
+    header = text.split("\n", 1)[0]
+    for i in (0, 1):
+        assert re.search(rf"\{{{i}\}}: \({i}, \{{\}}, (may|must)-alias\)", header), header[:400]

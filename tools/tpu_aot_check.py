@@ -204,6 +204,7 @@ def check_serving(topo):
     from accelerate_tpu.models import GPTConfig, GPTLMHeadModel
     from accelerate_tpu.models.generation import stacked_params_for_mode
     from accelerate_tpu.serving import engine, make_pools
+    from accelerate_tpu.telemetry.profiler import instructions_of_size
 
     one = SingleDeviceSharding(topo.devices[0])
     nn.manual_seed(0)
@@ -243,6 +244,16 @@ def check_serving(topo):
     t0 = time.time()
     compiled = engine._decode_jit.lower(*decode_args, **statics).compile()
     report("serve decode_steps=1", compiled, time.time() - t0)
+    # the layer loop indexes the carried pools in place (docs/serving.md §1);
+    # tests/test_tpu_compile.py holds this at GPT-2-XL width.  At this width a
+    # layer's weights outweigh its 65 pages: count buffers made of pages only
+    moved = [
+        (name, opcode, dims) for name, opcode, dims in instructions_of_size(
+            compiled.as_text(), ("copy", "dynamic-slice", "dynamic-update-slice"),
+            k_pool[0].size,
+        ) if dims[-2:] == k_pool.shape[-2:]
+    ]
+    print(f"    copies or slices of a layer's pool or more: {len(moved)} {moved}", flush=True)
     t0 = time.time()
     compiled = engine._decode_n_jit.lower(
         *decode_args, decode_steps=8, **statics
