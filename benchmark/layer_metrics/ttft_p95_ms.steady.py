@@ -1,14 +1,10 @@
-"""95th percentile over all requests due in the window of first token minus
-time due; a request with no first token lies above every finite value.  Below
-the knee with some sixty requests a window it swings with the order of the
-arrivals (a few requests wait for a slot or not), so it is read here and the
-median is the end-to-end metric."""
+"""95th percentile of the TTFT (``readers.ttft_percentile_ms``).  Below the knee
+with some hundred requests a window it swings with the order of the arrivals (a
+few requests wait for a slot or for blocks, or none does), so it is read here;
+``ttft_p50_ms.steady`` and ``ttft_p80_ms.steady`` stand beside it."""
 
-from benchmark import stats
+from benchmark import readers
 
 
 def read(ctx):
-    c = ctx["counters"]
-    if "ttft_ms" not in c:
-        return None
-    return stats.percentile_with_missing(c["ttft_ms"], c["ttft_missing"], 95)
+    return readers.ttft_percentile_ms(ctx, 95)

@@ -5,7 +5,7 @@ from __future__ import annotations
 
 import statistics
 
-from . import flops, trace_reduce
+from . import flops, stats, trace_reduce
 
 DECODE_MODULE = "_decode_jit"
 
@@ -34,3 +34,13 @@ def decode_step_ms(ctx) -> float | None:
         return None
     times = trace_reduce.kernel_durations(ctx["planes"], DECODE_MODULE, trace_reduce.MODULE_LINE)
     return statistics.median(times) * 1e3 if times else None
+
+
+def ttft_percentile_ms(ctx, pct: float) -> float | None:
+    """Percentile over all requests due in the window (in a traced run: before
+    the profiler started) of first token minus time due; a request with no
+    first token lies above every finite value."""
+    c = ctx["counters"]
+    if "ttft_ms" not in c:
+        return None
+    return stats.percentile_with_missing(c["ttft_ms"], c["ttft_missing"], pct)

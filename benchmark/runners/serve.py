@@ -137,8 +137,9 @@ def measure(cell, service, requests, run: dict) -> tuple:
     early = [r for r in first if r.submitted_t <= part["t"]]
     n_early = sum(1 for r in requests if run["t0"] + r.due_s <= part["t"])
     prompt_in_part = sum(r.prompt_len for r in first if r.first_token_t <= part["t"])
-    # BENCHMARK.json says which of these a cell reports (p80 has ten requests
-    # beyond it at 61 a window, p95 wants hundreds); all go to the log
+    by_due = [r.ttft_ms if r is not None and r.first_token_t is not None else float("inf") for r in results]
+    # BENCHMARK.json says which of these a cell reports (benchmark/README.md:
+    # only what its runs can bound); all go to the log, where noise.py reads them
     metrics = {
         **{f"serve_ttft_p{p}_ms": stats.percentile_with_missing(ttft, n - len(ttft), p)
            for p in (50, 80, 95)},
@@ -164,8 +165,24 @@ def measure(cell, service, requests, run: dict) -> tuple:
         "recompile_events": service.recompile_events,
         "requests": n, "finished": len(finished),
         "drain_s": max(0.0, run["steps"][-1][1] - closed["t"]) if run["steps"] else 0.0,
+        # under the knee the queue is empty at the close and the halves agree (sweep.py)
+        "queue_depth_at_close": closed["queue_depth"],
+        "ttft_p50_halves_ms": [stats.percentile_with_missing(h, 0, 50) for h in (by_due[: n // 2], by_due[n // 2:])],
     }
     return metrics, counters, finished
+
+
+def _stalls(steps: list) -> str:
+    """For the log: where a run that reads far off lost its time (a step that
+    took seconds, or a pause between steps while requests were in flight)."""
+    took = np.asarray([e - s for s, e in steps]) * 1e3
+    pauses = np.asarray([b[0] - a[1] for a, b in zip(steps, steps[1:])]) * 1e3
+    if not len(pauses):
+        return "under two steps"
+    slow = took[took > 2 * np.median(took)]
+    return (f"{len(took)} steps, median {np.median(took):.1f} ms, longest {took.max():.1f} ms, "
+            f"{len(slow)} over twice the median taking {slow.sum():.0f} ms in all; "
+            f"pauses between steps over 50 ms: {np.sort(pauses[pauses > 50]).round().tolist()[-8:]}")
 
 
 def reference_gaps(cell, seed: int, sample: list, precision="float32") -> list:
@@ -226,7 +243,9 @@ def timed(cell, seed: int, seconds: float, tracer, t_start: float, step_wrapper=
     metrics["setup_s"] = setup_s
     harness.log(
         f"window closed: {counters['finished']}/{counters['requests']} finished, "
-        f"drain {counters['drain_s']:.2f}s, {metrics}"
+        f"drain {counters['drain_s']:.2f}s, {metrics}; queue at close {counters['queue_depth_at_close']}, "
+        f"TTFT p50 of the halves {counters['ttft_p50_halves_ms']}; "
+        f"{counters['recompile_events']} recompiles; {_stalls(run_['steps'])}"
     )
     out = {
         "metrics": metrics, "counters": counters,

@@ -5,8 +5,9 @@ per offered rate, the mix otherwise as its file says.
     python3 benchmark/sweep.py --workload <cell> --rates 4,6,8,10,12 --seconds 30
 
 The knee is the highest rate at which the queue is not growing when the window
-closes and every request due in its first three quarters had completed by
-then.  The cell's fixed rate is 0.8 of it, written into the traffic file by
+closes, every request due in its first three quarters had completed by then,
+and the median TTFT of the window's two halves agree (PR 30 took: within 1.25x
+of each other, at most 2 requests waiting at the close, in every sweep made).  The cell's fixed rate is 0.8 of it, written into the traffic file by
 hand with this table in PERF.md; the benchmark never searches for a rate.
 """
 
@@ -52,16 +53,13 @@ def main(argv=None) -> int:
             if (q := service.results.get(rid)) is not None and q.done_t is not None and q.done_t <= close
         )
         waits = sorted(counters["queue_wait_ms"])
-        half = len(run["rids"]) // 2
-        got = [service.results.get(rid) for rid in run["rids"]]
-        ttft = [q.ttft_ms if q is not None and q.ttft_ms is not None else float("inf") for q in got]
+        first_half, second_half = counters["ttft_p50_halves_ms"]
         print(json.dumps({
             "rate_per_s": rate, "device": device["kind"], "requests": len(requests),
-            "queue_depth_at_close": run["closed"]["queue_depth"],
+            "queue_depth_at_close": counters["queue_depth_at_close"],
             "early_requests": len(early), "early_done_by_close": early_done,
-            "ttft_p50_first_half_ms": stats.percentile_with_missing(ttft[:half], 0, 50),
-            "ttft_p50_second_half_ms": stats.percentile_with_missing(ttft[half:], 0, 50),
-            **metrics, "serve_ttft_p95_ms": stats.percentile_with_missing(ttft, 0, 95),
+            "ttft_p50_first_half_ms": first_half, "ttft_p50_second_half_ms": second_half,
+            **metrics,
             "occupancy_mean": counters["occupancy_mean"],
             "queue_wait_p95_ms": stats.percentile_with_missing(waits, 0, 95),
             "generator_late_p95_ms": stats.percentile_with_missing(counters["generator_late_ms"], 0, 95),

@@ -1,4 +1,5 @@
-"""flops.py against hand counts, the percentile arithmetic, the generator."""
+"""flops.py against hand counts, the percentile arithmetic, the generator, the
+admission of a metric by its runs' spread."""
 
 import collections
 import json
@@ -8,7 +9,7 @@ import os
 import numpy as np
 import pytest
 
-from benchmark import flops, stats, traffic
+from benchmark import cells, flops, noise, stats, traffic
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -81,6 +82,14 @@ def test_percentile_with_missing():
     assert stats.spread([10, 10.1, 10.2, 9.9, 9.8, 10]) == pytest.approx(0.025, abs=1e-9)
 
 
+@pytest.mark.parametrize("pct", [50, 80, 95])
+def test_ttft_percentile_readers(pct):
+    read = cells.load_module("layer_metrics", f"ttft_p{pct}_ms.steady").read
+    assert read({"counters": {"ttft_ms": list(range(99, 0, -1)), "ttft_missing": 1}}) == pct
+    assert read({"counters": {"ttft_ms": [5.0], "ttft_missing": 99}}) == math.inf  # never served: above all
+    assert read({"counters": {}}) is None  # a cell whose runner keeps no TTFTs: the metric is left out
+
+
 @pytest.mark.parametrize("name", ["serve-steady"])
 def test_serve_traffic_is_a_pure_function_of_the_seed(name):
     m = mix(name)
@@ -127,3 +136,49 @@ def test_train_rows_and_sample():
     picked = traffic.sample_indices(40, 12, 9, always=7)
     assert picked[0] == 7 and len(set(picked)) == 12
     assert picked == traffic.sample_indices(40, 12, 9, always=7)
+
+
+def around(share, far=None):
+    """Six runs about 100 whose ``stats.spread`` is ``share`` (six runs put the
+    quartiles a quarter of the way out from the 2nd and the 5th); ``far`` takes
+    the place of the highest."""
+    h = 100 * share / 2.5
+    return [100 - 2 * h, 100 - h, 100 - h / 9, 100 + h / 9, 100 + h, far or 100 + 2 * h]
+
+
+@pytest.mark.parametrize("shares,far,end_to_end", [
+    ((0.04, 0.04), None, True),
+    ((0.07,), None, True),  # the rule's own line
+    ((0.04, 0.12), None, False),  # one set over it is enough
+    ((0.12,), None, False),
+    ((0.04,), 140.0, True),  # one run far off moves a quartile of six (0.132); the check leaves it out
+])
+def test_a_metric_is_admitted_by_the_spread_of_every_set(shares, far, end_to_end):
+    sets = [around(share, far) for share in shares]
+    if far is None:
+        assert [stats.spread(s) for s in sets] == pytest.approx(list(shares))
+    assert noise.admitted(sets) is end_to_end
+
+
+def test_the_trimmed_range_leaves_out_the_farthest_run():
+    assert stats.spread(around(0.04, far=140.0)) == pytest.approx(0.132)
+    assert noise.trimmed_range(around(0.04)) == pytest.approx(0.048)  # 96.8 is as far as 103.2: one of them goes
+    assert noise.trimmed_range(around(0.04, far=140.0)) == pytest.approx(0.048)
+    assert noise.trimmed_range([10.0, 10.0, 10.0, 1.0]) == 0.0
+    # the check's spread: without the farthest run where that narrows it, and never wider for it
+    assert noise.check_spread(around(0.04, far=140.0)) == pytest.approx(stats.spread(around(0.04)[:-1]))
+    assert noise.check_spread(around(0.04)) <= stats.spread(around(0.04))
+    two_far = around(0.04, far=140.0)[1:] + [60.0]
+    assert noise.check_spread(two_far) > 0.07 and not noise.admitted([two_far])  # two far-off runs do harm
+
+
+@pytest.mark.parametrize("text,expected", [
+    ('noise\n{"correct": true, "metrics": {"a_ms": {"value": 2.5, "unit": "ms"}}, "device": {}}\n', {"a_ms": 2.5}),
+    ("[bench 10:00:00] window closed: 3/3 finished, drain 0.10s, {'serve_ttft_p80_ms': 81.25, 'x': inf}\n"
+     "[bench 10:00:05] reference done: {'reference_s': 5.0}\n", {"serve_ttft_p80_ms": 81.25, "x": math.inf}),
+    ("[bench 10:00:00] window closed: 294 steps in 51.4s\n", {}),
+])
+def test_noise_reads_a_result_line_or_the_serve_log(tmp_path, text, expected):
+    path = tmp_path / "run.txt"
+    path.write_text(text)
+    assert noise.values_of(str(path)) == expected

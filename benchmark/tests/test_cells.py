@@ -6,6 +6,7 @@ import filecmp
 import json
 import os
 import re
+import types
 
 import pytest
 
@@ -74,6 +75,24 @@ def test_manifest_keeps_to_the_contract(manifest):
     for w in manifest["workloads"]:
         assert len(w["why"]) <= 200 and w["config"] in {c["name"] for c in manifest["configs"]}
         assert any(w["name"] in m["workloads"] for m in manifest["per_layer"])
+
+
+def test_a_serve_cell_lists_what_its_runner_measures_and_its_layers_move_that(manifest):
+    """A percentile that a benchmark PR moves from ``end_to_end`` to ``per_layer``
+    (or back) must leave no cell asking the runner for a metric it does not
+    compute, and no per-layer metric moving one the cell does not report."""
+    serve = [cells.resolve(w["name"]) for w in manifest["workloads"]]
+    serve = [cell for cell in serve if cell.mix["kind"] == "serve"]
+    assert serve
+    moves = {m["name"]: m["moves"] for m in manifest["per_layer"]}
+    zero = dict.fromkeys(("decode_tokens", "admitted", "steps", "occupancy_sum", "decode_syncs"), 0)
+    at = {"t": 1.0, "stats": zero, "queue_depth": 0}
+    idle = {"t0": 0.0, "closed": at, "untraced": at, "before": zero, "rids": [], "late_ms": [], "steps": []}
+    for cell in serve:
+        service = types.SimpleNamespace(results={}, recompile_events=0)
+        metrics, _, _ = cell.runner.measure(cell, service, [], idle)  # a window with no request
+        assert set(cell.end_to_end) <= set(metrics) | {"setup_s"}, cell.name
+        assert {moves[name] for name in cell.per_layer} <= set(cell.end_to_end), cell.name
 
 
 def test_run_refuses_a_machine_without_the_chip(capsys):

@@ -247,7 +247,7 @@ def test_every_new_metric_has_a_reader_and_an_entry():
             assert entries[name]["workloads"] == [cell_name] and name in cell.per_layer
             assert entries[name]["source"] == ("program_span" if name in HOST[kind] else "device_trace")
             assert callable(cell.layer_metric(name).read)
-    assert len(manifest["per_layer"]) == 15 + 14
+    assert len(manifest["per_layer"]) >= 15 + 14  # later PRs append
     assert [m["name"] for m in manifest["per_layer"]][:15] == [
         "host_dispatch_ms.train", "recompiles_in_window.train", "data_wait_ms.train", "train_step_mfu",
         "train_step_device_ms", "flash_fwd_roofline", "flash_bwd_roofline", "decode_step_ms",
