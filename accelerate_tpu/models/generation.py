@@ -39,6 +39,9 @@ logger = get_logger(__name__)
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
+ATTENTION, MAMBA2, EXPERTS = "attention", "mamba2", "experts"
+
+
 @dataclasses.dataclass(frozen=True)
 class DecoderFamily:
     """Pure-math hooks one model family exports for cached decoding.
@@ -56,6 +59,27 @@ class DecoderFamily:
     - ``finalize(g, x, cfg) -> (b, V)`` — final norm + LM head on the LAST
       position of ``x: (b, s, c)``
 
+    **The layer plan.**  ``plan(cfg)`` gives the kind of every layer in
+    order (``ATTENTION``, ``MAMBA2``, ``EXPERTS``); ``None`` is the plan
+    "attention × L", which is every family that has only the four hooks
+    above.  A layer of kind ``ATTENTION`` is ``attn_in`` / cached attention /
+    ``attn_out``; the other kinds bring their own hooks, each a whole layer
+    (pre-norm and residual included):
+
+    - ``mamba_prefill(l, x, true_len, cfg) -> (x, state, tail)`` — one
+      bucket-padded sequence ``x: (1, s, c)`` from a zero state; positions at
+      or past ``true_len`` must not move the state, and ``tail`` is what the
+      next token's convolution reads (the last true rows)
+    - ``mamba_step(l, x, state, tail, cfg) -> (x, state, tail)`` — one token
+      for every slot, ``x: (slots, 1, c)``; slots never mix
+    - ``ffn(l, x, valid, cfg) -> (x, load)`` — a token-wise layer (sparse
+      experts); ``valid: (b, s)`` marks the tokens that count, ``load`` is a
+      small int vector the engine sums over the layers and hands the host
+
+    With a mixed plan the layers are a tuple of per-layer dicts in plan
+    order, each layer's weights arrays of their own: the plan is unrolled, and
+    a static slice of a stack that feeds a kernel would be a copy.
+
     Declared frozen so the whole family object is a stable static argument
     to ``jax.jit`` (module-level singletons hash by function identity).
     """
@@ -64,6 +88,20 @@ class DecoderFamily:
     attn_in: Callable
     attn_out: Callable
     finalize: Callable
+    plan: Optional[Callable] = None
+    mamba_prefill: Optional[Callable] = None
+    mamba_step: Optional[Callable] = None
+    ffn: Optional[Callable] = None
+
+
+def layer_plan(family: DecoderFamily, cfg) -> Optional[tuple]:
+    """The kinds of a MIXED plan in layer order, or ``None`` where every layer
+    is attention: one ``lax.scan`` over the one stack serves that, and the
+    engines keep it."""
+    kinds = tuple(family.plan(cfg)) if family.plan is not None else None
+    if kinds is None or set(kinds) == {ATTENTION}:
+        return None
+    return kinds
 
 
 @dataclasses.dataclass
@@ -125,6 +163,11 @@ def _quantize_stacked_layers(layers: dict, bits: int) -> tuple[dict, dict, dict]
     from utils/quantization.quantize_weight (numpy, 2-D, load-time); the
     per-step DEQUANT below reuses that module's exact kernel.
     """
+    if isinstance(layers, tuple):
+        raise NotImplementedError(
+            "quantize_weights is not implemented for a mixed layer plan "
+            "(per-layer weights, 3-D expert stacks among them); serve it unquantized"
+        )
     plain, qd, sd = {}, {}, {}
     qmax = 127.0 if bits == 8 else 7.0
     for key, arr in layers.items():
@@ -351,6 +394,11 @@ def generate(
     if max_new_tokens < 1:
         raise ValueError(f"max_new_tokens must be >= 1, got {max_new_tokens}")
     spec: DecoderSpec = model._decoder_spec()
+    if layer_plan(spec.family, spec.cfg) is not None:
+        raise NotImplementedError(
+            "generate() runs plans of attention layers only; serve a mixed "
+            "layer plan (state-space or expert layers) through DecodeService"
+        )
     ids = jnp.asarray(
         input_ids.data if hasattr(input_ids, "data") else input_ids, jnp.int32
     )

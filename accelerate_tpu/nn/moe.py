@@ -175,3 +175,83 @@ class MixtureOfExperts(Module):
             f"MixtureOfExperts(d_model={self.d_model}, d_ff={self.d_ff}, "
             f"experts={self.num_experts}, top_k={self.top_k})"
         )
+
+
+# ---------------------------------------------------------------------------
+# the share of an expert layer that one chip holds (dropless, sigmoid router)
+# ---------------------------------------------------------------------------
+def route_sigmoid_topk(x, router_w, correction_bias, *, top_k: int, scale: float):
+    """DeepSeek-V3 / Nemotron-H routing over ALL experts, in float32.
+
+    ``s = sigmoid(x W_r^T)``; the ``top_k`` experts of ``s + correction_bias``
+    are chosen; their weights are ``s`` (without the bias) divided by their sum
+    and multiplied by ``scale``.  ``x: (T, d)``, ``router_w: (E, d)``.
+    Returns ``(chosen (T, k) int32, weights (T, k) float32)``."""
+    f32 = jnp.float32
+    s = jax.nn.sigmoid(jnp.einsum(
+        "td,ed->te", x.astype(f32), router_w.astype(f32),
+        precision=jax.lax.Precision.HIGHEST,
+    ))
+    _, chosen = jax.lax.top_k(s + correction_bias.astype(f32)[None], top_k)
+    w = jnp.take_along_axis(s, chosen, axis=-1)
+    return chosen.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True) * scale
+
+
+def relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+def held_experts_apply(x, chosen, weights, w_up, w_down, *, expert_offset: int = 0, valid=None):
+    """The weighted outputs of the experts held HERE (``expert_offset ..
+    expert_offset + E_held - 1``; ``w_up: (E_held, d, f)``, ``w_down:
+    (E_held, f, d)``; an expert is not gated: ``w_down relu(w_up u)^2``),
+    summed per token.  A pick of an expert held elsewhere, or of a token that
+    ``valid: (T,)`` leaves out (padding, a dead slot), adds nothing.  No
+    capacity and no drop, whatever the router does: every held expert
+    multiplies every token in one batched product, and the picks' weights
+    choose among the results.
+
+    A chip reads an expert's weights in the time it multiplies some 500 rows
+    by them (TPU v5e: 197 TFLOP/s over 819 GB/s is 240 FLOP a byte), so for a
+    decode step and for a prefill bucket the rows no pick asked for cost no
+    more than the weights' bytes; the compiler's grouped product over the
+    sorted picks (``jax.lax.ragged_dot``) was 4-5x off those bytes on the
+    chip (PERF.md, PR 31).  The temporaries are ``T * E_held * (f + d)``
+    float32 numbers: split longer inputs before the call.
+
+    Returns ``(y (T, d) float32, tokens each held expert got (E_held,) int32)``."""
+    f32 = jnp.float32
+    held = w_up.shape[0]
+    local = chosen - expert_offset
+    here = (local >= 0) & (local < held)
+    if valid is not None:
+        here = here & valid[:, None]
+    group = jnp.where(here, local, held)
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[group.reshape(-1)].add(1)[:held]
+    combine = jnp.sum(
+        jnp.where(here, weights, 0.0)[..., None] * jax.nn.one_hot(group, held, dtype=f32), axis=1
+    )  # (T, E_held)
+    h = relu2(jnp.einsum("td,edf->etf", x, w_up, preferred_element_type=f32)).astype(x.dtype)
+    out = jnp.einsum("etf,efd->etd", h, w_down, preferred_element_type=f32)
+    return jnp.sum(out * combine.T[:, :, None], axis=0), sizes
+
+
+def held_experts_ffn(x, router_w, correction_bias, w_up, w_down, *, top_k: int,
+                     scale: float, expert_offset: int = 0, valid=None):
+    """The routed part of an expert layer that THIS chip's experts give.
+
+    The router is as wide as the model has experts; ``w_up`` and ``w_down``
+    are the experts held here.  A pick of an expert held elsewhere adds
+    nothing here — that chip adds it, and the partial sums are added across
+    the chips that share the layer.  Returns ``held_experts_apply``'s pair."""
+    chosen, weights = route_sigmoid_topk(
+        x, router_w, correction_bias, top_k=top_k, scale=scale
+    )
+    return held_experts_apply(x, chosen, weights, w_up, w_down, expert_offset=expert_offset, valid=valid)
+
+
+def shared_expert_ffn(x, w_up, w_down):
+    """The expert every token meets, whole on every chip: ``w_up: (d, f)``,
+    ``w_down: (f, d)``, ``relu^2`` between; float32 out."""
+    h = relu2(jnp.dot(x, w_up, preferred_element_type=jnp.float32)).astype(x.dtype)
+    return jnp.dot(h, w_down, preferred_element_type=jnp.float32)

@@ -30,7 +30,7 @@ recompile forensics (``CompileWatcher``), benched by bench.py's serving
 block, and smoke-tested by ``make serve-smoke``.
 """
 
-from .kv_blocks import BlockPool, blocks_for_request, bucket_length, make_pools
+from .kv_blocks import BlockPool, blocks_for_request, bucket_length, make_pools, make_state_pool
 from .recovery import QueueFullError, RequestJournal, replay_journal
 from .scheduler import DecodeService, Request, ServingConfig
 
@@ -44,5 +44,6 @@ __all__ = [
     "blocks_for_request",
     "bucket_length",
     "make_pools",
+    "make_state_pool",
     "replay_journal",
 ]

@@ -62,6 +62,14 @@ compiled binary) must stay byte-identical to the pre-multi-token service,
 or cross-program bitwise parity with ``generate()`` is at the mercy of an
 independent XLA compile (see ``_decode_jit``).
 
+Both programs walk the family's **layer plan** (``models.generation.layer_plan``;
+docs/serving.md §layer plan).  A plan of attention layers is the scan above
+and nothing else.  A mixed plan (state-space and expert layers among them) is
+unrolled by ``_walk_plan`` over per-layer weights, takes a second donated
+cache — the per-slot state pool of ``kv_blocks.make_state_pool``, updated at
+each layer's rows in place like the KV pool — and returns it with the expert
+layers' summed load beside the tokens.
+
 Zero-recompile forensics: the scheduler routes every call through
 :class:`CompileWatcher`, which diffs the jit cache size around the call.
 First compiles of a not-yet-seen signature are warmup; any growth on a seen
@@ -80,9 +88,12 @@ import jax
 import jax.numpy as jnp
 
 from ..models.generation import (
+    ATTENTION,
+    MAMBA2,
     DecoderFamily,
     _dequant_layer,
     cached_attention,
+    layer_plan,
 )
 
 
@@ -94,10 +105,32 @@ def _page_rows(pool):
     return pool.reshape(-1, *pool.shape[2:])
 
 
+def _walk_plan(kinds, layers, x, kp, vp, state, attention, mamba, ffn):
+    """A MIXED layer plan, unrolled: ``layers[j]`` is layer ``j``'s own weights,
+    and the layer does what its kind does to the activations and to its own
+    cache — ``attention(l, x, kp, vp, i)`` the paged pool, ``mamba(l, x, state,
+    i)`` the state pool, ``i`` its rank among its kind; ``ffn(l, x)`` neither.
+    Unrolled and not scanned by runs: the runs of this repo's one mixed family
+    are one or two layers long, 26 layers compile in seconds, and a static
+    rank lets every layer touch its rows of both pools in place.  Returns
+    ``(x, kp, vp, state, load)``, ``load`` the ffn layers' loads summed."""
+    seen, load = {}, None
+    for kind, l in zip(kinds, layers, strict=True):
+        i = seen[kind] = seen.get(kind, -1) + 1
+        if kind == ATTENTION:
+            x, kp, vp = attention(l, x, kp, vp, i)
+        elif kind == MAMBA2:
+            x, state = mamba(l, x, state, i)
+        else:
+            x, got = ffn(l, x)
+            load = got if load is None else load + got
+    return x, kp, vp, state, load
+
+
 @partial(
     jax.jit,
     static_argnames=("family", "cfg", "qbits", "temperature"),
-    donate_argnums=(0, 1),
+    donate_argnums=(0, 1, 9),
 )
 def _prefill_jit(
     k_pool,
@@ -108,6 +141,9 @@ def _prefill_jit(
     block_row,  # (blocks_per_slot,) int32 — this slot's pool blocks
     prompt_len,  # () int32 TRUE length; dynamic, so one program per bucket
     rng,
+    slot=None,  # () int32 — the slot whose state this prefill writes (mixed plans)
+    state=None,  # the state pool (kv_blocks.make_state_pool), donated; None
+    # for a plan of attention layers, whose program it leaves as it was
     *,
     family: DecoderFamily,
     cfg,
@@ -146,9 +182,31 @@ def _prefill_jit(
 
     with jax.named_scope("atpu_serve_embed"):
         x = family.embed(g, padded_ids, positions, cfg)
-    (x, kp, vp, _), _ = jax.lax.scan(
-        prefill_layer, (x, kp, vp, jnp.int32(0)), (plain_layers, q_layers, s_layers)
-    )
+    kinds = layer_plan(family, cfg)
+    if kinds is None:
+        (x, kp, vp, _), _ = jax.lax.scan(
+            prefill_layer, (x, kp, vp, jnp.int32(0)), (plain_layers, q_layers, s_layers)
+        )
+    else:
+        def attention(l, x, kp, vp, i):
+            (x, kp, vp, _), _ = prefill_layer((x, kp, vp, i), (l, {}, {}))
+            return x, kp, vp
+
+        def mamba(l, x, state, i):
+            # from a zero state, whatever the slot held: this write IS the
+            # slot's reset.  Padding moves neither the state nor the tail
+            x, ssm, tail = family.mamba_prefill(l, x, prompt_len, cfg)
+            with jax.named_scope("atpu_serve_ssm_scan"):
+                return x, {
+                    "ssm": state["ssm"].at[i, slot].set(ssm),
+                    "conv": state["conv"].at[i, slot].set(tail.astype(state["conv"].dtype)),
+                }
+
+        valid = (positions < prompt_len)[None]
+        x, kp, vp, state, load = _walk_plan(
+            kinds, plain_layers, x, kp, vp, state, attention, mamba,
+            lambda l, x: family.ffn(l, x, valid, cfg),
+        )
     with jax.named_scope("atpu_serve_head"):
         # logits at the TRUE last prompt position (finalize reads x[:, -1], so
         # hand it the one gathered position) — identical math to an unpadded
@@ -161,7 +219,9 @@ def _prefill_jit(
         else:
             rng_out, key = jax.random.split(rng)
             tok = jax.random.categorical(key, logits / temperature, axis=-1).astype(jnp.int32)
-    return kp.reshape(pool_shape), vp.reshape(pool_shape), tok[0], rng_out
+    if kinds is None:
+        return kp.reshape(pool_shape), vp.reshape(pool_shape), tok[0], rng_out
+    return kp.reshape(pool_shape), vp.reshape(pool_shape), tok[0], rng_out, state, load
 
 
 def _decode_body(
@@ -173,6 +233,7 @@ def _decode_body(
     positions,  # (slots,) int32 — position of the token being fed
     tokens,  # (slots,) int32 — last sampled token per slot
     rngs,  # (slots, 2) uint32 — per-slot RNG streams
+    state=None,  # the state pool of a mixed plan (see _prefill_jit)
     *,
     family: DecoderFamily,
     cfg,
@@ -183,7 +244,8 @@ def _decode_body(
 ):
     """ONE token for the whole slot batch — the micro-step body shared by
     every ``decode_steps`` variant, so an n-token block is bitwise the same
-    math as n single-token dispatches (the parity contract)."""
+    math as n single-token dispatches (the parity contract).  A mixed plan
+    also returns its state pool and the ffn layers' summed load."""
     pool_shape = k_pool.shape
     num_blocks, block_size = pool_shape[1], pool_shape[2]
     plain_layers, q_layers, s_layers = layers
@@ -272,9 +334,37 @@ def _decode_body(
             )
         return (x, kp, vp, layer + 1), None
 
-    (x, kp, vp, _), _ = jax.lax.scan(
-        decode_layer, (x, kp, vp, jnp.int32(0)), (plain_layers, q_layers, s_layers)
-    )
+    kinds = layer_plan(family, cfg)
+    if kinds is None:
+        (x, kp, vp, _), _ = jax.lax.scan(
+            decode_layer, (x, kp, vp, jnp.int32(0)), (plain_layers, q_layers, s_layers)
+        )
+    else:
+        # a live slot's table starts at a block of its own, a dead one's at
+        # the trash block: dead slots' tokens go to no expert
+        live = (block_tables[:, 0] > 0)[:, None]
+
+        def attention(l, x, kp, vp, i):
+            # dead slots write their k/v to the trash block, which live slots'
+            # tables name past their own pages: a masked key is weighted 0.0,
+            # and 0 * NaN is NaN, so what a dead slot's state made of its
+            # activations must not get there
+            x = jnp.where(live[:, :, None], x, 0)
+            (x, kp, vp, _), _ = decode_layer((x, kp, vp, i), (l, {}, {}))
+            return x, kp, vp
+
+        def mamba(l, x, state, i):
+            # every slot's state, read and written at this layer's rows of the
+            # carried pool.  A dead slot computes on what it holds: states
+            # never mix across slots, and its next prefill writes it whole
+            x, ssm, tail = family.mamba_step(l, x, state["ssm"][i], state["conv"][i], cfg)
+            with jax.named_scope("atpu_serve_ssm_step"):
+                return x, {"ssm": state["ssm"].at[i].set(ssm), "conv": state["conv"].at[i].set(tail)}
+
+        x, kp, vp, state, load = _walk_plan(
+            kinds, plain_layers, x, kp, vp, state, attention, mamba,
+            lambda l, x: family.ffn(l, x, live, cfg),
+        )
     with jax.named_scope("atpu_serve_head"):
         logits = family.finalize(g, x, cfg)  # (slots, V)
         if temperature == 0.0:
@@ -288,14 +378,16 @@ def _decode_body(
                 return nk, jax.random.categorical(sk, lg / temperature).astype(jnp.int32)
 
             rngs_out, nxt = jax.vmap(sample_one)(rngs, logits)
-    return kp.reshape(pool_shape), vp.reshape(pool_shape), nxt, rngs_out
+    if kinds is None:
+        return kp.reshape(pool_shape), vp.reshape(pool_shape), nxt, rngs_out
+    return kp.reshape(pool_shape), vp.reshape(pool_shape), nxt, rngs_out, state, load
 
 
 @partial(
     jax.jit,
     static_argnames=("family", "cfg", "qbits", "temperature", "paged",
                      "kernel_interpret"),
-    donate_argnums=(0, 1),
+    donate_argnums=(0, 1, 8),
 )
 def _decode_jit(
     k_pool,
@@ -306,6 +398,7 @@ def _decode_jit(
     positions,
     tokens,
     rngs,
+    state=None,
     *,
     family: DecoderFamily,
     cfg,
@@ -327,7 +420,7 @@ def _decode_jit(
     sidesteps the whole class: byte-identical programs, byte-identical
     cache entries, byte-identical tokens."""
     return _decode_body(
-        k_pool, v_pool, g, layers, block_tables, positions, tokens, rngs,
+        k_pool, v_pool, g, layers, block_tables, positions, tokens, rngs, state,
         family=family, cfg=cfg, qbits=qbits, temperature=temperature,
         paged=paged, kernel_interpret=kernel_interpret,
     )
@@ -485,15 +578,22 @@ def _dispatch(label: str, sig, jit_fn, args, statics, watcher, aot):
 
 def run_prefill(k_pool, v_pool, g, layers, padded_ids, block_row, prompt_len,
                 rng, *, family, cfg, qbits, temperature,
-                watcher: Optional[CompileWatcher] = None, aot=None):
+                watcher: Optional[CompileWatcher] = None, aot=None,
+                slot=None, state=None):
     """One request's bucketed prefill; see ``_prefill_jit``.  ``padded_ids``
     must already be bucket-padded (``kv_blocks.bucket_length``) — raw
     request-length shapes here compile one program per distinct length
     (graftlint: recompile-hazard serving contract).  ``aot`` (an
     :class:`~..native.aot_cache.AOTServingPrograms`) replaces the jit
     dispatch with the persistent-executable path: signature hits run the
-    deserialized program, misses compile explicitly and store it."""
+    deserialized program, misses compile explicitly and store it.
+
+    A mixed layer plan (``models.generation.layer_plan``) also takes the
+    ``state`` pool and the ``slot`` this prefill writes in it, and returns
+    ``(k_pool, v_pool, tok, rng, state, load)``."""
     args = (k_pool, v_pool, g, layers, padded_ids, block_row, prompt_len, rng)
+    if state is not None:
+        args += (jnp.asarray(slot, jnp.int32), state)
     statics = dict(family=family, cfg=cfg, qbits=qbits, temperature=temperature)
     sig = ("prefill", padded_ids.shape[1], qbits, float(temperature))
     return _dispatch("prefill", sig, _prefill_jit, args, statics, watcher, aot)
@@ -502,7 +602,7 @@ def run_prefill(k_pool, v_pool, g, layers, padded_ids, block_row, prompt_len,
 def run_decode(k_pool, v_pool, g, layers, block_tables, positions, tokens,
                rngs, *, family, cfg, qbits, temperature,
                watcher: Optional[CompileWatcher] = None, aot=None,
-               kernels=None):
+               kernels=None, state=None):
     """One token for the whole slot batch; see ``_decode_jit``.  The
     ``decode_steps=1`` (default) dispatch path — signature, program and
     AOT entries byte-identical to the pre-multi-token service.
@@ -510,8 +610,13 @@ def run_decode(k_pool, v_pool, g, layers, block_tables, positions, tokens,
     ``kernels`` (a :class:`~..native.kernels.KernelPolicy`) arms the
     paged-attention decode kernel — a STATIC compile-mode choice, so it
     rides the watcher/AOT signature: flipping it is a new program, never a
-    silent steady-state recompile."""
+    silent steady-state recompile.
+
+    A mixed layer plan also takes the ``state`` pool and returns
+    ``(k_pool, v_pool, tokens, rngs, state, load)``."""
     args = (k_pool, v_pool, g, layers, block_tables, positions, tokens, rngs)
+    if state is not None:
+        args += (state,)
     statics = dict(family=family, cfg=cfg, qbits=qbits, temperature=temperature)
     paged = bool(kernels is not None and kernels.paged_attention)
     if paged:

@@ -102,12 +102,19 @@ class BlockPool:
     block_size: int
     max_slots: int
     blocks_per_slot: int
+    # a plan with recurrent layers keeps a second kind of cache, which is not
+    # paged: one state a slot (``make_state_pool``).  The slot that holds
+    # blocks holds its state: one map owns both, so neither can leak alone.
+    # The slot's prefill writes the state whole (the reset: a prefill starts
+    # from a zero state); ``state_resets`` counts them
+    has_state: bool = False
 
     def __post_init__(self):
         if self.num_blocks < 2:
             raise ValueError("BlockPool needs >= 2 blocks (block 0 is trash)")
         self._free: list[int] = list(range(self.num_blocks - 1, 0, -1))
         self._rows: dict[int, list[int]] = {}
+        self.state_resets = 0
 
     @property
     def free_blocks(self) -> int:
@@ -137,6 +144,7 @@ class BlockPool:
             )
         row = [self._free.pop() for _ in range(n_blocks)]
         self._rows[slot] = row
+        self.state_resets += self.has_state
         return row
 
     def free_slot(self, slot: int) -> int:
@@ -178,3 +186,18 @@ def make_pools(n_layers: int, num_blocks: int, n_kv_head: int,
 
     shape = (n_layers, num_blocks, block_size, n_kv_head * head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+
+def make_state_pool(n_layers: int, slots: int, state_shape, tail_shape, tail_dtype):
+    """The recurrent layers' cache: ``{"ssm": (L, slots, *state_shape) float32,
+    "conv": (L, slots, *tail_shape)}`` — per layer and slot the state-space
+    state (Mamba-2: heads x head size x state size) and the rows the next
+    token's convolution reads (kernel - 1 rows of the convolved channels).
+    Not paged: its size does not grow with the sequence.  Zeros, so that a
+    slot nobody has claimed yet computes on finite numbers."""
+    import jax.numpy as jnp
+
+    return {
+        "ssm": jnp.zeros((n_layers, slots, *state_shape), jnp.float32),
+        "conv": jnp.zeros((n_layers, slots, *tail_shape), tail_dtype),
+    }

@@ -51,7 +51,7 @@ import numpy as np
 
 from ..logging import get_logger
 from ..telemetry import flightrec
-from .kv_blocks import BlockPool, blocks_for_request, bucket_length, make_pools
+from .kv_blocks import BlockPool, blocks_for_request, bucket_length, make_pools, make_state_pool
 
 logger = get_logger(__name__)
 
@@ -193,7 +193,7 @@ class DecodeService:
 
     def __init__(self, model, config: Optional[ServingConfig] = None, telemetry=None,
                  aot_cache=None, kernels=None, preemption_guard=None):
-        from ..models.generation import stacked_params_for_mode
+        from ..models.generation import ATTENTION, MAMBA2, layer_plan, stacked_params_for_mode
 
         # Pallas paged-attention decode (docs/kernels.md): explicit handle
         # or the process-active policy; None (the default) keeps run_decode
@@ -227,6 +227,15 @@ class DecodeService:
             )
         self.spec = spec = model._decoder_spec()
         self._qbits = cfg.quantize_weights or 0
+        # the layer plan (docs/serving.md §layer plan): None where every
+        # layer is attention, else the kinds in order
+        kinds = layer_plan(spec.family, spec.cfg)
+        if kinds is not None and (cfg.decode_steps != 1 or self._kernels is not None):
+            raise NotImplementedError(
+                "a mixed layer plan is served one token a dispatch on the "
+                "gather path: decode_steps > 1 and the paged-attention kernel "
+                "do not carry its state pool yet"
+            )
         self._g, self._layers = stacked_params_for_mode(
             model, self._qbits, spec.stack
         )
@@ -239,8 +248,10 @@ class DecodeService:
             )
         blocks_per_slot = self.capacity // cfg.block_size
         num_blocks = cfg.num_blocks or (cfg.max_slots * blocks_per_slot + 1)
+        n_state_layers = kinds.count(MAMBA2) if kinds is not None else 0
         self.pool = BlockPool(
-            num_blocks, cfg.block_size, cfg.max_slots, blocks_per_slot
+            num_blocks, cfg.block_size, cfg.max_slots, blocks_per_slot,
+            has_state=n_state_layers > 0,
         )
 
         import jax
@@ -249,12 +260,17 @@ class DecodeService:
         from .engine import CompileWatcher
 
         dcfg = spec.cfg
-        n_layers = next(iter(self._layers[0].values())).shape[0]
+        # the paged pool is as deep as the plan has attention layers
+        n_layers = (
+            next(iter(self._layers[0].values())).shape[0] if kinds is None
+            else kinds.count(ATTENTION)
+        )
         # activation dtype drives the pool dtype: one tiny eager embed
         # (params may be bf16 under a mixed-precision prepare)
-        act_dtype = spec.family.embed(
+        probe = spec.family.embed(
             self._g, jnp.zeros((1, 1), jnp.int32), jnp.zeros((1,), jnp.int32), dcfg
-        ).dtype
+        )
+        act_dtype = probe.dtype
         self._k_pool, self._v_pool = make_pools(
             n_layers, num_blocks, dcfg.n_kv_head, cfg.block_size,
             dcfg.head_dim, act_dtype,
@@ -284,6 +300,27 @@ class DecodeService:
         self._pool_sharding = (
             replicated if param_sharding is not None else None
         )
+
+        # the second kind of cache: per slot and recurrent layer a state and
+        # a convolution tail, not paged (kv_blocks.make_state_pool).  Their
+        # shapes are what the family's own prefill returns for one layer
+        def _rebuild_state():
+            if not n_state_layers:
+                return None
+            first = self._layers[0][kinds.index(MAMBA2)]
+            _, ssm, tail = jax.eval_shape(
+                lambda l: spec.family.mamba_prefill(
+                    l, jnp.zeros((1, cfg.prompt_bucket, probe.shape[-1]), act_dtype),
+                    jnp.int32(1), dcfg,
+                ), first,
+            )
+            pool = make_state_pool(n_state_layers, cfg.max_slots, ssm.shape, tail.shape, act_dtype)
+            if self._pool_sharding is not None:
+                pool = jax.device_put(pool, self._pool_sharding)
+            return pool
+
+        self._state_factory = _rebuild_state
+        self._state = _rebuild_state()
 
         # pool rebuild hook for the retry-exhaustion recovery path: a fault
         # that fires MID-EXECUTION may have consumed the donated pools; the
@@ -459,6 +496,9 @@ class DecodeService:
             "decode_retries": 0,
             "requeued": 0,
             "pool_rebuilds": 0,
+            # a mixed layer plan: (token, held expert) products the expert
+            # layers did, from the load vector each program returns
+            "expert_tokens": 0,
             "metrics_snapshot_retry_exhausted": 0,
         }
         # sliding (ttft_ms, tpot_ms) window behind metrics() — the live
@@ -625,9 +665,6 @@ class DecodeService:
         next (no shorter request overtakes it — predictable tail latency),
         gated on a free slot AND its block reservation fitting the pool."""
         import jax
-        import jax.numpy as jnp
-
-        from .engine import run_prefill
 
         admitted = []
         while self._queue:
@@ -659,22 +696,15 @@ class DecodeService:
             table_row[: len(row)] = row
             about = dict(rid=req.rid, bucket_len=req.bucket_len, prompt_len=req.prompt_len)
             with flightrec.span("atpu/serve/prefill_launch", **about):
-                padded_ids = np.full((1, req.bucket_len), self.config.pad_token_id, np.int32)
-                padded_ids[0, : req.prompt_len] = req.prompt
-                self._k_pool, self._v_pool, tok, rng_out = run_prefill(
-                    self._k_pool, self._v_pool, self._g, self._layers,
-                    jnp.asarray(padded_ids), jnp.asarray(table_row),
-                    jnp.asarray(req.prompt_len, jnp.int32),
+                tok, rng_out, load = self._launch_prefill(
+                    req, slot, req.prompt, table_row,
                     jax.random.fold_in(self._base_rng, 2 * req.rid + 1),
-                    family=self.spec.family, cfg=self.spec.cfg,
-                    qbits=self._qbits,
-                    temperature=float(self.config.temperature),
-                    watcher=self.watcher, aot=self._aot,
                 )
             self.stats["host_syncs"] += 1
             self._programs_warmed = True
             with flightrec.span("atpu/serve/prefill_sync", **about):
                 first = int(tok)
+            self._record_load("prefill", load, tokens=req.prompt_len)
             req.first_token_t = time.perf_counter()
             req.tokens.append(first)
             req.state = "running"
@@ -697,6 +727,48 @@ class DecodeService:
             self._state_dirty = True  # new slot row: re-commit before decode
             self._rngs = self._rngs.at[slot].set(rng_out)
         return admitted
+
+    def _launch_prefill(self, req: Request, slot: int, seq, table_row, rng):
+        """Dispatch one bucketed prefill of ``seq`` (the prompt, or on
+        recovery the prompt and the tokens so far) into ``slot``: its pages
+        of the KV pool and, under a mixed layer plan, the slot's row of the
+        state pool — written whole from a zero state, which is the slot's
+        reset.  Returns ``(first token, rng, load)``, all still on the device."""
+        import jax.numpy as jnp
+
+        from .engine import run_prefill
+
+        padded_ids = np.full((1, req.bucket_len), self.config.pad_token_id, np.int32)
+        padded_ids[0, : len(seq)] = seq
+        out = run_prefill(
+            self._k_pool, self._v_pool, self._g, self._layers,
+            jnp.asarray(padded_ids), jnp.asarray(table_row),
+            jnp.asarray(len(seq), jnp.int32), rng,
+            family=self.spec.family, cfg=self.spec.cfg, qbits=self._qbits,
+            temperature=float(self.config.temperature),
+            watcher=self.watcher, aot=self._aot, slot=slot, state=self._state,
+        )
+        self._k_pool, self._v_pool, tok, rng_out = out[:4]
+        load = None
+        if self._state is not None:
+            self._state, load = out[4:]
+        return tok, rng_out, load
+
+    def _record_load(self, phase: str, load, **about) -> None:
+        """The expert layers' load of one program execution onto the ring
+        (docs/telemetry.md §moe_load): ``load`` is the small int vector the
+        program returned beside its tokens — the tokens each held expert got,
+        summed over the expert layers, then how many (layer, expert) pairs
+        got any.  Read after the step's one blocking read, so it waits for
+        nothing."""
+        if load is None:
+            return
+        load = np.asarray(load)
+        self.stats["expert_tokens"] += int(load[:-1].sum())
+        flightrec.record(
+            "atpu/serve/moe_load", phase=phase, step=self.stats["steps"],
+            per_expert=load[:-1].tolist(), touched=int(load[-1]), **about,
+        )
 
     def _evict(self, slot: int) -> None:
         """Free the slot the moment its request finishes: table back to the
@@ -818,9 +890,7 @@ class DecodeService:
         the stream at position ``k-1`` lands its internal split exactly at
         ``k`` (recovery.advance_rng)."""
         import jax
-        import jax.numpy as jnp
 
-        from .engine import run_prefill
         from .recovery import advance_rng
 
         k = len(req.tokens)
@@ -833,23 +903,14 @@ class DecodeService:
         table_row[: len(row)] = row
         about = dict(rid=req.rid, bucket_len=req.bucket_len, prompt_len=seq_len)
         with flightrec.span("atpu/serve/prefill_launch", **about):
-            padded_ids = np.full((1, req.bucket_len), self.config.pad_token_id, np.int32)
-            padded_ids[0, :seq_len] = seq
             rng = jax.random.fold_in(self._base_rng, 2 * req.rid + 1)
             if float(self.config.temperature) > 0.0:
                 rng = advance_rng(rng, k - 1)
-            self._k_pool, self._v_pool, tok, rng_out = run_prefill(
-                self._k_pool, self._v_pool, self._g, self._layers,
-                jnp.asarray(padded_ids), jnp.asarray(table_row),
-                jnp.asarray(seq_len, jnp.int32), rng,
-                family=self.spec.family, cfg=self.spec.cfg,
-                qbits=self._qbits,
-                temperature=float(self.config.temperature),
-                watcher=self.watcher, aot=self._aot,
-            )
+            tok, rng_out, load = self._launch_prefill(req, slot, seq, table_row, rng)
         self.stats["host_syncs"] += 1
         with flightrec.span("atpu/serve/prefill_sync", **about):
             int(tok)  # block for the prefill; the sample itself is teacher-forced away
+        self._record_load("prefill", load, tokens=seq_len)
         self._programs_warmed = True
         req.state = "running"
         if req.first_token_t is None:
@@ -895,6 +956,9 @@ class DecodeService:
                 self._evict(slot)
         if self._k_pool.is_deleted():
             self._k_pool, self._v_pool = self._pool_factory()
+            # the state pool was donated beside them; the re-prefills write
+            # every slot's state anew
+            self._state = self._state_factory()
             self.stats["pool_rebuilds"] += 1
         self._queue_recovery(reqs, front=True)
         self.stats["requeued"] += len(reqs)
@@ -1098,6 +1162,7 @@ class DecodeService:
         active = [i for i, r in enumerate(self._slot_req) if r is not None]
         about.update(active=len(active), queue_depth=len(self._queue))
         emitting = None
+        load = None  # a mixed plan's expert load, beside the tokens
         uploads_before = self.stats["h2d_uploads"]
         if active:
             flightrec.record(
@@ -1142,11 +1207,15 @@ class DecodeService:
                             # here and amortized n-fold on the n>1 path below.
                             import jax.numpy as jnp
 
-                            (self._k_pool, self._v_pool, nxt, self._rngs) = run_decode(
+                            out = run_decode(
                                 self._k_pool, self._v_pool, self._g, self._layers,
                                 jnp.asarray(self._tables), jnp.asarray(self._positions),
-                                jnp.asarray(self._tokens), self._rngs, **common,
+                                jnp.asarray(self._tokens), self._rngs, state=self._state,
+                                **common,
                             )
+                            self._k_pool, self._v_pool, nxt, self._rngs = out[:4]
+                            if self._state is not None:
+                                self._state, load = out[4:]
                             self.stats["h2d_uploads"] += 1
                             self._state_dirty = True  # mirrors stay the source of truth
                             tok_block = nxt  # reshaped host-side below
@@ -1199,6 +1268,7 @@ class DecodeService:
                     block_host = np.asarray(tok_block).reshape(
                         self.config.max_slots, n
                     )
+                self._record_load("decode", load, active=len(active))
                 # closed at the end of the step: the slot loop and the
                 # step's bookkeeping after it
                 emitting = flightrec.span("atpu/serve/emit").__enter__()

@@ -1,0 +1,20 @@
+"""Least time for the chunked scan over a prefill's bucket
+(``flops_nemotron_h.ssd_prefill_cost``, mean over the prefills launched inside
+the traced window) over the prefill programs' device time under
+``atpu_serve_ssm_scan``.  The program runs the scan's small products in float32
+at highest precision (six bfloat16 passes); the algorithm's operations count once."""
+
+import statistics
+
+from benchmark import flops, hybrid_readers
+from benchmark import flops_nemotron_h as costs
+
+
+def read(ctx):
+    got, buckets = hybrid_readers.scope_ms(ctx, hybrid_readers.PREFILL, "atpu_serve_ssm_scan"), hybrid_readers.traced_prefill_buckets(ctx)
+    if got is None or buckets is None or not got[0]:
+        return None
+    least = statistics.fmean(
+        flops.roofline_seconds(*costs.ssd_prefill_cost(ctx["cell"].config, b), ctx["peaks"])[0] for b in buckets
+    )
+    return 100.0 * least / (got[0] / 1e3)

@@ -337,3 +337,98 @@ def test_serving_programs_move_no_pool_sized_buffer(xl_serving_programs, program
     header = text.split("\n", 1)[0]
     for i in (0, 1):
         assert re.search(rf"\{{{i}\}}: \({i}, \{{\}}, (may|must)-alias\)", header), header[:400]
+
+
+def test_a_plan_of_attention_layers_keeps_the_scan_and_takes_no_state(xl_serving_programs):
+    """GPT-2's family is the plan "attention x L" (``layer_plan`` gives
+    ``None``): its programs scan the one stack of layers and take no
+    state-pool argument — the pools, the 4 + 12 stacked weights and four small
+    inputs are all they take.  (Lowered from the
+    parent commit and from this tree, the three programs' StableHLO text is
+    byte for byte the same: CHANGES.md, PR 31.)"""
+    import dataclasses
+
+    from accelerate_tpu.models.generation import ATTENTION, layer_plan
+    from accelerate_tpu.models.gpt import GPT_DECODER
+
+    assert layer_plan(GPT_DECODER, None) is None
+    spelled_out = dataclasses.replace(GPT_DECODER, plan=lambda cfg: (ATTENTION,) * 48)
+    assert layer_plan(spelled_out, None) is None  # one kind: the scan, whoever says it
+    programs, _ = xl_serving_programs
+    for name, n_args in (("decode", 2 + 4 + 12 + 4), ("prefill", 2 + 4 + 12 + 4)):
+        text = programs[name].as_text()
+        entry = text[text.index("ENTRY"):]
+        assert len(re.findall(r"\bparameter\(\d+\)", entry)) == n_args, name
+
+
+# ---------------------------------------------------------------------------
+# a mixed layer plan holds both of its caches in place (docs/serving.md §layer plan)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def hybrid_serving_programs(one_chip):
+    """``_decode_jit`` and the 512-token ``_prefill_jit`` (the serve-chat
+    cell's largest bucket) of Nemotron-H compiled for the described v5e at the published widths and the serve-chat cell's
+    pools (64 slots, 3073 blocks of 16), five layers deep (``MEM*E``: the
+    plan is unrolled, every layer of a kind does the same) and 2048 rows of
+    vocabulary."""
+    from accelerate_tpu.models.nemotron_h import NEMOTRON_H_DECODER, NemotronHConfig, layer_shapes
+    from accelerate_tpu.serving import engine, make_pools, make_state_pool
+
+    cfg = NemotronHConfig(vocab_size=2048, pattern="MEM*E", experts_held=32)
+    slots, block, bps, num_blocks = 64, 16, 48, 3073
+
+    def shapes(tree, dtype=None):
+        return jax.tree_util.tree_map(lambda x: sds(x.shape, dtype or x.dtype, one_chip), tree)
+
+    def weights(kind):
+        return {k: sds(s, BF16, one_chip) for k, s in layer_shapes(cfg, kind).items()}
+
+    pools = shapes(jax.eval_shape(lambda: make_pools(1, num_blocks, cfg.n_kv_head, block, cfg.head_dim, BF16)))
+    state = shapes(jax.eval_shape(lambda: make_state_pool(
+        2, slots, (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_state_size),
+        (cfg.conv_kernel - 1, cfg.conv_width), BF16,
+    )))
+    layers = (tuple(weights(kind) for kind in cfg.kinds), {}, {})
+
+    def ints(*shape):
+        return sds(shape, jnp.int32, one_chip)
+
+    statics = dict(family=NEMOTRON_H_DECODER, cfg=cfg, qbits=0, temperature=0.0)
+    decode = engine._decode_jit.lower(
+        *pools, weights("globals"), layers, ints(slots, bps), ints(slots), ints(slots),
+        sds((slots, 2), jnp.uint32, one_chip), state, **statics,
+    ).compile()
+    prefill = engine._prefill_jit.lower(
+        *pools, weights("globals"), layers, ints(1, 512), ints(bps), ints(),
+        sds((2,), jnp.uint32, one_chip), ints(), state, **statics,
+    ).compile()
+    sizes = {
+        "kv": int(np.prod(pools[0].shape[1:])), "state": int(np.prod(state["ssm"].shape[1:])),
+        "experts": int(np.prod(layers[0][1]["up_w"].shape)),
+    }
+    return {"decode": decode, "prefill": prefill}, sizes
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_hybrid_programs_move_no_cache_or_expert_sized_buffer(hybrid_serving_programs, program):
+    """Both caches are donated, carried whole and touched at their own rows, and
+    every layer's weights are arrays of their own: no ``copy`` of a layer's KV
+    pool, of a layer's state pool or of an expert stack is left, no instruction
+    of the ENTRY computation holds a layer's state (the reads and the update
+    are fused, in place), and the four cache buffers come back aliased.  (The
+    expert stacks as one ``(L, 32, 2688, 1856)`` parameter were sliced out, 640
+    MB a layer a step: PERF.md, PR 31.)  The experts' products are the
+    compiler's own: no custom kernel in either program."""
+    from accelerate_tpu.telemetry.profiler import instructions_of_size
+
+    programs, sizes = hybrid_serving_programs
+    text = programs[program].as_text()
+    assert instructions_of_size(text, ("copy",), min(sizes["kv"], sizes["state"])) == []
+    assert instructions_of_size(text, ("copy", "slice", "dynamic-slice", "transpose"), sizes["experts"]) == []
+    entry = text[text.index("ENTRY"):]
+    held = instructions_of_size(entry, ("slice", "dynamic-slice", "copy", "fusion", "dynamic-update-slice"), sizes["state"])
+    assert all(dims == (2, 64, 64, 64, 128) for _, _, dims in held), held  # the pool itself, updated in place
+    header = text.split("\n", 1)[0]
+    assert len(re.findall(r"(?:may|must)-alias", header)) == 4, header[:600]
+    assert 'custom_call_target="tpu_custom_call"' not in text
