@@ -183,10 +183,9 @@ class Accelerator:
             self.compression_handler, ddp_handler=self.ddp_handler
         )
         # Pallas hot-path kernels (docs/kernels.md): one default-off policy
-        # for the collective-matmul ZeRO-1 gather, the fused quantize+RS
-        # wire, and serving's paged-attention decode — resolved here so the
-        # optimizer relayout, the serving engine, and the AOT-cache
-        # fingerprint all read ONE armed set
+        # for the collective-matmul ZeRO-1 gather and the fused quantize+RS
+        # wire — resolved here so the optimizer relayout and the AOT-cache
+        # fingerprint read ONE armed set
         from .native.kernels import _set_active_kernels, resolve_kernel_policy
 
         self.kernels = resolve_kernel_policy(self.kernels_handler)
@@ -827,7 +826,6 @@ class Accelerator:
         targets = {
             "collective_matmul": "zero1 all-gather → chunked ring + partial matmuls",
             "quantized_rs": "compress reduce-scatter → fused scale+round region",
-            "paged_attention": "serving decode gather → VMEM block-table walk",
         }
         for name in self.kernels.armed():
             self.telemetry.record_kernel(

@@ -1,8 +1,7 @@
-"""Pallas hot-path kernels behind one :class:`KernelPolicy` surface
-(docs/kernels.md).
+"""Pallas hot-path kernels (docs/kernels.md).
 
-The three hottest paths in the stack leave device time on the table because
-XLA will not fuse across a collective or a block-table gather on its own:
+Two training paths leave device time on the table because XLA will not fuse
+across a collective on its own; they sit behind one :class:`KernelPolicy`:
 
 * **collective-matmul** (``collective_matmul.py``) — the ZeRO-1 all-gather
   expressed as a chunked ring (``shard_map`` + per-hop transport: RDMA
@@ -12,10 +11,12 @@ XLA will not fuse across a collective or a block-table gather on its own:
   ``parallel/compress.py``'s per-block scale compute, rounding and widening
   collapsed into ONE kernel region so scale+round ride the shard boundary
   instead of round-tripping HBM between separate XLA ops; also carries the
-  stochastic-rounding wire that reopens the ZeRO-2 first scatter;
-* **paged-attention decode** (``paged_attention.py``) — serving's
-  materialize-full-page-span gather-then-attend replaced by a kernel that
-  walks the block table in VMEM (the vLLM move), one grid program per slot.
+  stochastic-rounding wire that reopens the ZeRO-2 first scatter.
+
+**Paged-attention decode** (``paged_attention.py``) is not behind the policy:
+it is how the serving decode program of an all-attention layer plan attends,
+each slot's live pages read where they lie, and the plan alone decides it
+(docs/serving.md §decode attention).
 
 Policy discipline (same as telemetry/resilience/aot-cache/fleet): the
 policy is resolved from ``KernelKwargs`` / ``$ACCELERATE_KERNELS`` and is
@@ -46,8 +47,8 @@ __all__ = [
     "_reset_active_kernels",
 ]
 
-# the three hot-path fusions, in the order ROADMAP names them
-KERNEL_NAMES = ("collective_matmul", "quantized_rs", "paged_attention")
+# the policy's hot-path fusions, in the order ROADMAP names them
+KERNEL_NAMES = ("collective_matmul", "quantized_rs")
 
 # Kernels the TPU compiler refuses today, with its own words (asked of it
 # for a described v5e:2x2 — tests/test_tpu_compile.py keeps each message
@@ -60,11 +61,6 @@ TPU_REFUSED = {
         "inside the captured step the kernel is called on a dp-sharded "
         "array, and the lowering refuses: 'Mosaic kernels cannot be "
         "automatically partitioned. Please wrap the call in a shard_map.'"
-    ),
-    "paged_attention": (
-        "the attend math (models.generation.cached_attention, grouped "
-        "einsums) does not lower: \"'tpu.matmul' op Not implemented: Up to "
-        "1 batch dim supported\""
     ),
 }
 
@@ -84,17 +80,15 @@ class KernelPolicy:
         self,
         collective_matmul: bool = False,
         quantized_rs: bool = False,
-        paged_attention: bool = False,
         interpret: Optional[bool] = None,
     ):
         self.collective_matmul = bool(collective_matmul)
         self.quantized_rs = bool(quantized_rs)
-        self.paged_attention = bool(paged_attention)
         self._interpret = interpret
 
     @property
     def enabled(self) -> bool:
-        return self.collective_matmul or self.quantized_rs or self.paged_attention
+        return self.collective_matmul or self.quantized_rs
 
     @property
     def interpret(self) -> bool:
@@ -155,8 +149,8 @@ def resolve_kernel_policy(handler=None) -> KernelPolicy:
     ``$ACCELERATE_KERNELS`` env var it reads).
 
     Grammar: a comma/plus-separated subset of ``collective_matmul``,
-    ``quantized_rs``, ``paged_attention``; ``all`` (or ``1``) arms all
-    three; empty / ``none`` / ``0`` (the default) arms nothing.
+    ``quantized_rs``; ``all`` (or ``1``) arms both; empty / ``none`` / ``0``
+    (the default) arms nothing.
     """
     if handler is None:
         from ...utils.dataclasses import KernelKwargs
@@ -181,8 +175,8 @@ def resolve_kernel_policy(handler=None) -> KernelPolicy:
 
 
 # process-active policy (the Accelerator publishes its resolution here,
-# mirroring native/aot_cache's _set_active) — what a standalone
-# DecodeService or a bare Optimizer relayout picks up without a handle.
+# mirroring native/aot_cache's _set_active) — what a bare Optimizer
+# relayout picks up without a handle.
 # The _UNSET sentinel distinguishes "no Accelerator resolved anything yet"
 # (fall back to the env) from "an Accelerator explicitly disarmed kernels"
 # (None — the env must NOT re-arm a policy the user opted out of).

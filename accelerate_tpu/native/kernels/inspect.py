@@ -2,8 +2,7 @@
 (docs/kernels.md §IR contract).
 
 A kernel that silently de-fuses — an all-gather the compiler re-separated
-from its consuming matmuls, a page walk that re-materialized the full span
-— would still pass every numerics test, because the reference and the
+from its consuming matmuls — would still pass every numerics test, because the reference and the
 kernel compute the same values by design.  The only place the fusion is
 visible is the IR the program commits to, so each check here lowers the
 kernel path (``jax.jit(...).lower().compiler_ir()``) and asserts the
@@ -20,13 +19,12 @@ structural fact that IS the optimization:
   rounding op lives INSIDE the kernel region (the grid loop the
   interpreter lowers to), not as a free-floating top-level op between HBM
   round-trips.
-* ``check_paged_attention`` — no tensor of the batched full-page-span
-  gather shape ``(slots, blocks_per_slot, n_kv, block_size, d)`` exists in
-  the kernel path's IR; the reference path's IR contains exactly that
-  materialization.
 
 Every check returns the dict of facts it asserted (the smoke target prints
-them); ``main()`` runs all three on a small geometry.
+them); ``main()`` runs them on a small geometry.  (The decode program's
+paged attention is held against the chip's compiler instead:
+tests/test_tpu_compile.py asserts one Mosaic kernel a layer body and no
+tensor of the gathered span's size in the compiled program.)
 """
 
 from __future__ import annotations
@@ -43,7 +41,6 @@ __all__ = [
     "jaxpr_text",
     "check_collective_matmul",
     "check_quantize_rs",
-    "check_paged_attention",
     "check_pipeline_layout",
     "run_all",
 ]
@@ -161,53 +158,6 @@ def check_quantize_rs(*, shape=(32, 16), wire_dtype=jnp.int8,
     return facts
 
 
-def check_paged_attention(*, slots: int = 3, bps: int = 4, n_kv: int = 2,
-                          block_size: int = 8, d: int = 16, heads: int = 4,
-                          num_blocks: int = 10, interpret: bool = True) -> dict:
-    """No full-span page materialization: the batched gather shape
-    ``(slots, bps, n_kv, block_size, d)`` must not exist in the kernel
-    path's IR (and must exist in the reference's — proving the assertion
-    bites)."""
-    from ...models.generation import cached_attention  # noqa: F401 (doc link)
-    from .paged_attention import paged_attention, reference_paged_attention
-
-    class _Cfg:
-        sliding_window = 0
-
-    q = jnp.ones((slots, heads, 1, d), jnp.float32)
-    kp = jnp.ones((num_blocks, n_kv, block_size, d), jnp.float32)
-    vp = jnp.ones((num_blocks, n_kv, block_size, d), jnp.float32)
-    tables = jnp.zeros((slots, bps), jnp.int32)
-    positions = jnp.zeros((slots,), jnp.int32)
-    span_shape = f"tensor<{slots}x{bps}x{n_kv}x{block_size}x{d}x"
-
-    def fused(q, kp, vp, t, p):
-        return paged_attention(q, kp, vp, t, p, cfg=_Cfg(), interpret=interpret)
-
-    def ref(q, kp, vp, t, p):
-        return reference_paged_attention(q, kp, vp, t, p, cfg=_Cfg())
-
-    fused_text = stablehlo_text(fused, q, kp, vp, tables, positions)
-    ref_text = stablehlo_text(ref, q, kp, vp, tables, positions)
-    facts = {
-        "span_shape": span_shape + "...>",
-        "fused_materializes_span": span_shape in fused_text,
-        "reference_materializes_span": span_shape in ref_text,
-        "pallas_call_in_jaxpr": "pallas_call"
-        in jaxpr_text(fused, q, kp, vp, tables, positions),
-    }
-    assert not facts["fused_materializes_span"], (
-        "paged-attention lowering materializes the batched full page span — "
-        "the gather the kernel exists to remove"
-    )
-    assert facts["reference_materializes_span"], (
-        "reference path no longer materializes the span — the inspection "
-        "contrast lost its meaning; update the harness"
-    )
-    assert facts["pallas_call_in_jaxpr"]
-    return facts
-
-
 def check_pipeline_layout(mesh=None, *, num_stages: int = 2, virtual: int = 3,
                           num_layers: int = 6, dim: int = 8,
                           microbatches: int = 4) -> dict:
@@ -279,9 +229,8 @@ def check_pipeline_layout(mesh=None, *, num_stages: int = 2, virtual: int = 3,
 
 
 def run_all(interpret: bool = True) -> dict:
-    """All three checks on a small geometry (the kernel-smoke entry)."""
+    """The checks on a small geometry (the kernel-smoke entry)."""
     out = {"quantize_rs": check_quantize_rs(interpret=interpret)}
-    out["paged_attention"] = check_paged_attention(interpret=interpret)
     if len(jax.devices()) > 1:
         out["collective_matmul"] = check_collective_matmul(interpret=interpret)
     else:

@@ -12,9 +12,10 @@ stalls token streaming for in-flight sequences:
 * ``run_decode_n`` — the WHOLE slot batch ``decode_steps`` tokens forward
   inside ONE captured program: each micro-step embeds the slot's current
   token at its own position, scatters the new k/v into the pool
-  (``block_tables[slot][pos // bs]`` at offset ``pos % bs``), gathers each
-  slot's pages back as a virtually contiguous cache, reuses
-  ``cached_attention`` unchanged, samples — and feeds the sampled token
+  (``block_tables[slot][pos // bs]`` at offset ``pos % bs``), attends over
+  each slot's LIVE pages where they lie (one Mosaic kernel a layer,
+  ``native/kernels/paged_attention.py``: no gathered span, no relayout, no
+  product over dead positions), samples — and feeds the sampled token
   back into the next micro-step's embed IN-PROGRAM, advancing positions
   in-program too.  The host sees one ``(slots, n)`` token block per
   dispatch instead of one scalar per token: dispatch overhead and the
@@ -27,24 +28,29 @@ stalls token streaming for in-flight sequences:
 Both reuse the single-request engine's contracts wholesale: the
 ``DecoderFamily`` pure math, ``stacked_params_for_mode`` (so int8/int4
 quantized weight modes compose — the stacks are shared with ``generate()``),
-``_dequant_layer`` widening inside the scan, and ``cached_attention`` — the
-one attention implementation, which is what makes serving greedy tokens
-per-sequence identical to a single-request ``generate()``: same per-token
-math, same true positions, same mask formula; only the (masked, zero-prob)
-padding width differs.
+``_dequant_layer`` widening inside the scan, and ``cached_attention``'s
+mathematics: prefill calls it on the bucket's fresh k/v, decode's kernel
+states the same q·k in float32, the same mask formula at the same true
+positions and the same float32 softmax, summed in chunks of pages.  So
+serving's greedy tokens are per-sequence the same as a single-request
+``generate()``'s on the tests' models, and its logits agree to float32
+summation order — not bitwise (tests/test_serving.py holds both).
 
 Pools are DONATED through both programs and the layer scan CARRIES them
 whole, as ``(L·NB, bs, n_kv·d)`` page rows (``_page_rows``: layer ``l``'s
 block ``b`` is row ``l·NB + b``), beside the layer counter: each layer
-scatters into and gathers from the carried buffer at its own rows, in place.
+scatters into the carried buffer at its own rows, in place, and its
+attention kernel reads its pages from the same buffer, left in HBM.
 They are never the scan's ``xs``/``ys`` — that slices every layer's pool out
 of the stack and writes it back, and with a ``[…, bs, d]`` page the chip's
 compiler also put the block index on the lanes and transposed the layer's
 whole pool around every access: 44 ms of a 155 ms GPT-2-XL decode step, and
 the same in every prefill (PERF.md, PR 28).  A page is one lane-dense
-``[bs, n_kv·d]`` slab; the gathered pages go to ``cached_attention`` as
-``(Hkv, S, d)``.  No pool-sized copy, slice or update is left in either
-program (held against the chip's compiler by tests/test_tpu_compile.py).
+``[bs, lanes]`` slab (``kv_blocks.page_lanes``: a token's ``n_kv·d``, zeros up
+to whole tiles), which the kernel takes as it lies.  No pool-sized copy, slice
+or update is left in either program, and nothing of the gathered span's size
+in the decode program (held against the chip's compiler by
+tests/test_tpu_compile.py).
 
 The multi-token program's positions/tokens/rng streams are returned (the scheduler owns them as
 committed device arrays and feeds each call's outputs into the next, so a
@@ -57,10 +63,7 @@ input buffer was reused for one output while another output still read it,
 silently freezing degenerate sequences mid-stream in SOME processes (the
 per-process coin flip came from allocator layout).  They are three tiny
 int arrays; the copy costs nothing.  The single-token program keeps the
-legacy per-step mirror uploads — its inputs' avals (and therefore its
-compiled binary) must stay byte-identical to the pre-multi-token service,
-or cross-program bitwise parity with ``generate()`` is at the mercy of an
-independent XLA compile (see ``_decode_jit``).
+per-step mirror uploads (see ``_decode_jit``).
 
 Both programs walk the family's **layer plan** (``models.generation.layer_plan``;
 docs/serving.md §layer plan).  A plan of attention layers is the scan above
@@ -69,6 +72,20 @@ unrolled by ``_walk_plan`` over per-layer weights, takes a second donated
 cache — the per-slot state pool of ``kv_blocks.make_state_pool``, updated at
 each layer's rows in place like the KV pool — and returns it with the expert
 layers' summed load beside the tokens.
+
+**The plan also decides how a decode layer attends** (``decode_layer``), and
+nothing else does — no argument, no policy, no model's name.  Under the scan
+it is the kernel above.  Under ``_walk_plan`` it is what it was: gather the
+slot's whole table row, re-lay it as ``(Hkv, S, d)``, ``cached_attention``
+over all of it.  Few of a mixed plan's layers attend, over few kv heads (3 of
+Nemotron's 26, 2 heads: 0.94 of a 20.7 ms step), and unrolled they would pay
+one kernel lowering each and the Pallas import at every start; so the kernel
+module is imported in the scan's branch at trace time, a process that serves a
+mixed plan never imports ``jax.experimental.pallas``, and its programs lower
+to the text they lowered to before the kernel came (PERF.md, PR 33).  The
+writers pad a token's row to the page's lanes only where the page has pad
+lanes (``_pad_lanes``), so a family whose ``n_kv·d`` is a multiple of 128
+traces the same writes either way.
 
 Zero-recompile forensics: the scheduler routes every call through
 :class:`CompileWatcher`, which diffs the jit cache size around the call.
@@ -103,6 +120,18 @@ def _page_rows(pool):
     reshape — free, and what lets the layer loop carry the pool whole and
     index it in place instead of slicing a layer's pool out and back."""
     return pool.reshape(-1, *pool.shape[2:])
+
+
+def _pad_lanes(rows, pool):
+    """``rows``' last dimension, a token's ``n_kv·d``, up to the lanes of
+    ``pool``'s pages (``kv_blocks.page_lanes``) with zeros.  Decided on the
+    static shapes: where the page has no pad lanes (``n_kv·d`` a multiple of
+    128) this is ``rows`` itself, and the writers trace what they always
+    did."""
+    pad = pool.shape[-1] - rows.shape[-1]
+    if pad == 0:
+        return rows
+    return jnp.pad(rows, ((0, 0),) * (rows.ndim - 1) + ((0, pad),))
 
 
 def _walk_plan(kinds, layers, x, kp, vp, state, attention, mamba, ffn):
@@ -175,8 +204,8 @@ def _prefill_jit(
             rows = layer * num_blocks + block_row[:n_blocks]
             kb = k[0].transpose(1, 0, 2).reshape(n_blocks, block_size, -1)
             vb = v[0].transpose(1, 0, 2).reshape(n_blocks, block_size, -1)
-            kp = kp.at[rows].set(kb.astype(kp.dtype))
-            vp = vp.at[rows].set(vb.astype(vp.dtype))
+            kp = kp.at[rows].set(_pad_lanes(kb, kp).astype(kp.dtype))
+            vp = vp.at[rows].set(_pad_lanes(vb, vp).astype(vp.dtype))
         with jax.named_scope("atpu_serve_out_mlp"):
             return (family.attn_out(l, x, att, cfg), kp, vp, layer + 1), None
 
@@ -239,8 +268,7 @@ def _decode_body(
     cfg,
     qbits: int,
     temperature: float,
-    paged: bool = False,  # paged-attention kernel (docs/kernels.md)
-    kernel_interpret: Optional[bool] = None,
+    mesh=None,  # the pools' mesh, where it has several devices (_pool_mesh)
 ):
     """ONE token for the whole slot batch — the micro-step body shared by
     every ``decode_steps`` variant, so an n-token block is bitwise the same
@@ -250,6 +278,9 @@ def _decode_body(
     num_blocks, block_size = pool_shape[1], pool_shape[2]
     plain_layers, q_layers, s_layers = layers
     kp, vp = _page_rows(k_pool), _page_rows(v_pool)
+    # the plan decides how a layer attends (decode_layer): None where every
+    # layer is attention and the layers are scanned
+    kinds = layer_plan(family, cfg)
 
     # the atpu_serve_* scopes are HLO metadata only (numerics untouched): a
     # device trace is split by them (docs/telemetry.md §spans and scopes)
@@ -271,54 +302,51 @@ def _decode_body(
             q, k, v = q[:, 0], k[:, 0], v[:, 0]  # (slots, H|Hkv, 1, d)
             n_kv, d = k.shape[1], k.shape[3]
         with jax.named_scope("atpu_serve_kv_write"):
-            # scatter each slot's new k/v (one (n_kv·d) row) into its current
-            # page, in place on the carried pool.  Inactive slots' tables point
-            # at trash block 0, so the unconditional write (and any duplicate
-            # trash indices) never touches live cache
+            # scatter each slot's new k/v (one (n_kv·d) row, zeros on the
+            # page's pad lanes) into its current page, in place on the carried
+            # pool.  Inactive slots' tables point at trash block 0, so the
+            # unconditional write (and any duplicate trash indices) never
+            # touches live cache
             blk = base + jnp.take_along_axis(
                 block_tables, (positions // block_size)[:, None], axis=1
             )[:, 0]
             off = positions % block_size
-            kp = kp.at[blk, off].set(k[:, :, 0, :].reshape(-1, n_kv * d).astype(kp.dtype))
-            vp = vp.at[blk, off].set(v[:, :, 0, :].reshape(-1, n_kv * d).astype(vp.dtype))
+            k_row = _pad_lanes(k[:, :, 0, :].reshape(-1, n_kv * d), kp)
+            kp = kp.at[blk, off].set(k_row.astype(kp.dtype))
+            v_row = _pad_lanes(v[:, :, 0, :].reshape(-1, n_kv * d), vp)
+            vp = vp.at[blk, off].set(v_row.astype(vp.dtype))
 
-        if paged:
-            # paged-attention kernel (docs/kernels.md): walk the block table
-            # in VMEM instead of materializing each slot's full page span —
-            # per-slot logits bitwise-identical to the gather path below.
-            # The lowering mode has no default here: it comes from the
-            # KernelPolicy (run_decode*), so no caller can forget it and
-            # interpret a TPU's kernel
-            if kernel_interpret is None:
-                raise ValueError(
-                    "paged=True needs kernel_interpret from the KernelPolicy "
-                    "(KernelPolicy.interpret); it has no default"
-                )
+        if kinds is None:
+            # the scanned plan: each slot's live pages, read where they lie,
+            # under a running softmax — one Mosaic kernel a layer, no gathered
+            # span (docs/serving.md §decode attention).  Imported here, at
+            # trace time: a process that serves a mixed plan never pays for
+            # jax.experimental.pallas
             from ..native.kernels.paged_attention import paged_attention
-
-            def layer_view(pool):
-                # the kernel's own layout, (NB, n_kv, bs, d), built from the
-                # carried pool after the write.  A layer-sized copy: the
-                # kernel runs in interpret mode only (the TPU refuses it)
-                pages = jax.lax.dynamic_slice_in_dim(pool, base, num_blocks)
-                return pages.reshape(num_blocks, block_size, n_kv, d).transpose(0, 2, 1, 3)
 
             with jax.named_scope("atpu_serve_attend"):
                 att = paged_attention(
-                    q, layer_view(kp), layer_view(vp), block_tables, positions,
-                    cfg=cfg, interpret=kernel_interpret,
-                )
+                    q[:, :, 0, :], kp, vp, block_tables, positions, base, cfg,
+                    n_kv=n_kv, mesh=mesh,
+                )[:, :, None, :]  # (slots, H, 1, d)
         else:
-            # two vmaps where one would do, so that each phase's scope sits
+            # a mixed plan's attention layers: gather the slot's whole table
+            # row and attend over it.  Few of its layers attend, over few kv
+            # heads: the kernel would buy 0.75 ms of a 20.7 ms step and cost
+            # a lowering a layer at every start (PERF.md, PR 33).
+            # Two vmaps where one would do, so that each phase's scope sits
             # OUTSIDE its vmap: a scope entered inside reads ``vmap(<scope>)``
             # in the op's path and is lost to the map.  Same batched
             # primitives in the same order either way
+            def token_lanes(pages):  # a token's n_kv·d, without the pad lanes
+                return pages if pages.shape[-1] == n_kv * d else pages[..., : n_kv * d]
+
             def gather_one(row):
                 # gather this slot's pages: table order IS logical order, so
                 # the flattened view is a virtually contiguous cache and the
                 # plain causal mask applies unchanged
-                kc = kp[base + row].reshape(-1, n_kv, d).transpose(1, 0, 2)
-                vc = vp[base + row].reshape(-1, n_kv, d).transpose(1, 0, 2)
+                kc = token_lanes(kp[base + row]).reshape(-1, n_kv, d).transpose(1, 0, 2)
+                vc = token_lanes(vp[base + row]).reshape(-1, n_kv, d).transpose(1, 0, 2)
                 return kc, vc  # (Hkv, S, d)
 
             def attend_one(q_s, kc, vc, p_s):
@@ -334,7 +362,6 @@ def _decode_body(
             )
         return (x, kp, vp, layer + 1), None
 
-    kinds = layer_plan(family, cfg)
     if kinds is None:
         (x, kp, vp, _), _ = jax.lax.scan(
             decode_layer, (x, kp, vp, jnp.int32(0)), (plain_layers, q_layers, s_layers)
@@ -385,8 +412,7 @@ def _decode_body(
 
 @partial(
     jax.jit,
-    static_argnames=("family", "cfg", "qbits", "temperature", "paged",
-                     "kernel_interpret"),
+    static_argnames=("family", "cfg", "qbits", "temperature", "mesh"),
     donate_argnums=(0, 1, 8),
 )
 def _decode_jit(
@@ -404,32 +430,24 @@ def _decode_jit(
     cfg,
     qbits: int,
     temperature: float,
-    paged: bool = False,
-    kernel_interpret: Optional[bool] = None,
+    mesh=None,
 ):
     """The classic single-token program — ``_decode_body`` jitted with the
-    SAME signature, donation split and outputs the service has always
-    pinned.  ``decode_steps=1`` dispatches THIS program, not a length-1
-    loop: a degenerate ``_decode_n_jit`` returns extra outputs that alias
-    each other (``positions + 1``, the token block AND the trailing token
-    both being ``nxt``), a pattern that intermittently corrupted token
-    streams on XLA:CPU (see the module docstring's aliasing note) and at
-    best compiles to a DIFFERENT binary than the seed program — and
-    cross-program bitwise parity with ``generate()`` is only ever as
-    stable as the exact binary it was proven on.  The legacy shape
-    sidesteps the whole class: byte-identical programs, byte-identical
-    cache entries, byte-identical tokens."""
+    signature, donation split and outputs the service has always pinned.
+    ``decode_steps=1`` dispatches THIS program, not a length-1 loop: a
+    degenerate ``_decode_n_jit`` returns extra outputs that alias each other
+    (``positions + 1``, the token block AND the trailing token both being
+    ``nxt``), a pattern that intermittently corrupted token streams on
+    XLA:CPU (see the module docstring's aliasing note)."""
     return _decode_body(
         k_pool, v_pool, g, layers, block_tables, positions, tokens, rngs, state,
-        family=family, cfg=cfg, qbits=qbits, temperature=temperature,
-        paged=paged, kernel_interpret=kernel_interpret,
+        family=family, cfg=cfg, qbits=qbits, temperature=temperature, mesh=mesh,
     )
 
 
 @partial(
     jax.jit,
-    static_argnames=("family", "cfg", "qbits", "temperature", "decode_steps",
-                     "paged", "kernel_interpret"),
+    static_argnames=("family", "cfg", "qbits", "temperature", "decode_steps", "mesh"),
     donate_argnums=(0, 1),
 )
 def _decode_n_jit(
@@ -447,8 +465,7 @@ def _decode_n_jit(
     qbits: int,
     temperature: float,
     decode_steps: int = 1,
-    paged: bool = False,
-    kernel_interpret: Optional[bool] = None,
+    mesh=None,
 ):
     """``decode_steps`` micro-steps of ``_decode_body`` in one captured
     program: the sampled token feeds the next embed and positions advance
@@ -468,10 +485,7 @@ def _decode_n_jit(
     (``tokens`` out == ``tok_block[:, -1]``), and donating them tripped an
     allocation-dependent XLA:CPU aliasing corruption (module docstring) —
     they stay undonated, three tiny int arrays."""
-    statics = dict(
-        family=family, cfg=cfg, qbits=qbits, temperature=temperature,
-        paged=paged, kernel_interpret=kernel_interpret,
-    )
+    statics = dict(family=family, cfg=cfg, qbits=qbits, temperature=temperature, mesh=mesh)
 
     def micro(carry, _):
         kp, vp, pos, tok, rg = carry
@@ -576,6 +590,17 @@ def _dispatch(label: str, sig, jit_fn, args, statics, watcher, aot):
         return watcher.call(label, sig, jit_fn, *args, **statics)
 
 
+def _pool_mesh(pool):
+    """The mesh ``pool`` is committed to, if it has several devices (the
+    service commits its pools replicated on a sharded model's mesh), else
+    None.  The decode programs take it as a static: their attention is a
+    Mosaic kernel, which on several devices has to sit inside ``shard_map``
+    (``native/kernels/paged_attention.py``), and a traced pool no longer says
+    where it lives."""
+    mesh = getattr(pool.sharding, "mesh", None)
+    return mesh if mesh is not None and mesh.size > 1 else None
+
+
 def run_prefill(k_pool, v_pool, g, layers, padded_ids, block_row, prompt_len,
                 rng, *, family, cfg, qbits, temperature,
                 watcher: Optional[CompileWatcher] = None, aot=None,
@@ -602,37 +627,24 @@ def run_prefill(k_pool, v_pool, g, layers, padded_ids, block_row, prompt_len,
 def run_decode(k_pool, v_pool, g, layers, block_tables, positions, tokens,
                rngs, *, family, cfg, qbits, temperature,
                watcher: Optional[CompileWatcher] = None, aot=None,
-               kernels=None, state=None):
+               state=None):
     """One token for the whole slot batch; see ``_decode_jit``.  The
-    ``decode_steps=1`` (default) dispatch path — signature, program and
-    AOT entries byte-identical to the pre-multi-token service.
-
-    ``kernels`` (a :class:`~..native.kernels.KernelPolicy`) arms the
-    paged-attention decode kernel — a STATIC compile-mode choice, so it
-    rides the watcher/AOT signature: flipping it is a new program, never a
-    silent steady-state recompile.
+    ``decode_steps=1`` (default) dispatch path.
 
     A mixed layer plan also takes the ``state`` pool and returns
     ``(k_pool, v_pool, tokens, rngs, state, load)``."""
     args = (k_pool, v_pool, g, layers, block_tables, positions, tokens, rngs)
     if state is not None:
         args += (state,)
-    statics = dict(family=family, cfg=cfg, qbits=qbits, temperature=temperature)
-    paged = bool(kernels is not None and kernels.paged_attention)
-    if paged:
-        statics.update(paged=True, kernel_interpret=kernels.interpret)
-    # the lowering mode rides the signature too: interpret is normally
-    # backend-derived, but KernelKwargs(interpret=...) can force it, and
-    # two services with opposite modes must not share one program
-    sig = ("decode", block_tables.shape, qbits, float(temperature),
-           paged and ("interpret" if kernels.interpret else "mosaic"))
+    statics = dict(family=family, cfg=cfg, qbits=qbits, temperature=temperature,
+                   mesh=_pool_mesh(k_pool))
+    sig = ("decode", block_tables.shape, qbits, float(temperature))
     return _dispatch("decode", sig, _decode_jit, args, statics, watcher, aot)
 
 
 def run_decode_n(k_pool, v_pool, g, layers, block_tables, positions, tokens,
                  rngs, *, family, cfg, qbits, temperature, decode_steps=1,
-                 watcher: Optional[CompileWatcher] = None, aot=None,
-                 kernels=None):
+                 watcher: Optional[CompileWatcher] = None, aot=None):
     """``decode_steps`` tokens for the whole slot batch in one dispatch;
     see ``_decode_n_jit``.  Returns ``(k_pool, v_pool, tok_block,
     positions, tokens, rngs)`` with ``tok_block`` of shape ``(slots,
@@ -644,26 +656,19 @@ def run_decode_n(k_pool, v_pool, g, layers, block_tables, positions, tokens,
     with two tiny eager device ops; the scheduler calls ``run_decode``
     directly on that path instead, skipping the adaptation.
 
-    ``kernels`` (a :class:`~..native.kernels.KernelPolicy`) arms the
-    paged-attention decode kernel — a STATIC compile-mode choice, so it
-    rides the watcher/AOT signature: flipping it is a new program, never a
-    silent steady-state recompile.  ``decode_steps`` rides the signature
-    for the same reason."""
+    ``decode_steps`` is a STATIC compile-mode choice, so it rides the
+    watcher/AOT signature: flipping it is a new program, never a silent
+    steady-state recompile."""
     decode_steps = int(decode_steps)
     if decode_steps == 1:
         k_pool, v_pool, nxt, rngs = run_decode(
             k_pool, v_pool, g, layers, block_tables, positions, tokens, rngs,
             family=family, cfg=cfg, qbits=qbits, temperature=temperature,
-            watcher=watcher, aot=aot, kernels=kernels,
+            watcher=watcher, aot=aot,
         )
         return k_pool, v_pool, nxt[:, None], positions + 1, nxt, rngs
     args = (k_pool, v_pool, g, layers, block_tables, positions, tokens, rngs)
-    statics = dict(family=family, cfg=cfg, qbits=qbits,
-                   temperature=temperature, decode_steps=decode_steps)
-    paged = bool(kernels is not None and kernels.paged_attention)
-    if paged:
-        statics.update(paged=True, kernel_interpret=kernels.interpret)
-    sig = ("decode", block_tables.shape, qbits, float(temperature),
-           paged and ("interpret" if kernels.interpret else "mosaic"),
-           decode_steps)
+    statics = dict(family=family, cfg=cfg, qbits=qbits, temperature=temperature,
+                   decode_steps=decode_steps, mesh=_pool_mesh(k_pool))
+    sig = ("decode", block_tables.shape, qbits, float(temperature), decode_steps)
     return _dispatch("decode", sig, _decode_n_jit, args, statics, watcher, aot)

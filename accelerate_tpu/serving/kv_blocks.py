@@ -74,9 +74,11 @@ def blocks_for_request(prompt_len: int, max_new: int, bucket_len: int,
     ``blocks_per_slot`` clamps the result to the slot's table length: a
     near-capacity request's overrun horizon may round past the table, and
     those tail writes are already safe without blocks behind them (table
-    entries past the row are the trash block; a position past the whole
-    table clamps into the slot's own last block — both masked stale data
-    for any future owner)."""
+    entries past the row are the trash block: masked stale data for any
+    future owner; a position past the whole table is written nowhere — no
+    column names a block for it and the scatter drops the row — and the
+    attention kernel reads it as the table's last position, clamped there
+    because nothing on the chip checks a table index)."""
     # prefill emits token 1; the decode loop emits the remaining max_new-1
     # in ceil((max_new-1)/n) blocks of n micro-steps
     steps = max(1, decode_steps)
@@ -172,19 +174,29 @@ class BlockPool:
             )
 
 
+def page_lanes(n_kv_head: int, head_dim: int) -> int:
+    """Lanes of a page: the ``n_kv·d`` of one token's keys (or values), up to
+    whole 128-lane tiles.  The pad lanes cost no memory the chip was not
+    already spending (its tiled layout pads the minor dimension itself: 1600
+    lanes lie as 1664), they hold zeros for ever, and with them a page is a
+    slab the decode kernel can copy whole (Mosaic refuses a DMA of 1600
+    lanes out of 1664: docs/kernels.md)."""
+    return -(-n_kv_head * head_dim // 128) * 128
+
+
 def make_pools(n_layers: int, num_blocks: int, n_kv_head: int,
                block_size: int, head_dim: int, dtype):
-    """Zero-initialised device pools ``(L, NB, bs, n_kv·d)`` — a page is one
-    lane-dense ``[bs, n_kv·d]`` slab and the block index a major dimension,
-    so the programs gather and scatter pages in place (a ``[…, bs, d]`` tail
-    put the block index on the lanes, and every access paid a transpose of
-    the whole layer's pool).  Zeros (not empty) so never-written trash/stale
-    positions stay finite: masked attention multiplies their probs by
-    exactly 0.0, and 0 * finite is 0 while 0 * inf would poison the row with
-    NaN."""
+    """Zero-initialised device pools ``(L, NB, bs, lanes)`` — a page is one
+    lane-dense ``[bs, lanes]`` slab (``page_lanes``: a token's ``n_kv·d`` first,
+    zeros up to whole tiles) and the block index a major dimension, so the
+    programs scatter and read pages in place (a ``[…, bs, d]`` tail put the
+    block index on the lanes, and every access paid a transpose of the whole
+    layer's pool).  Zeros (not empty) so never-written trash/stale positions
+    stay finite: masked attention multiplies their probs by exactly 0.0, and
+    0 * finite is 0 while 0 * inf would poison the row with NaN."""
     import jax.numpy as jnp
 
-    shape = (n_layers, num_blocks, block_size, n_kv_head * head_dim)
+    shape = (n_layers, num_blocks, block_size, page_lanes(n_kv_head, head_dim))
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 

@@ -192,21 +192,8 @@ class DecodeService:
     """
 
     def __init__(self, model, config: Optional[ServingConfig] = None, telemetry=None,
-                 aot_cache=None, kernels=None, preemption_guard=None):
+                 aot_cache=None, preemption_guard=None):
         from ..models.generation import ATTENTION, MAMBA2, layer_plan, stacked_params_for_mode
-
-        # Pallas paged-attention decode (docs/kernels.md): explicit handle
-        # or the process-active policy; None (the default) keeps run_decode
-        # on the gather-then-attend path byte-identically
-        if kernels is None:
-            from ..native.kernels import current_kernel_policy
-
-            kernels = current_kernel_policy()
-        self._kernels = (
-            kernels
-            if (kernels is not None and getattr(kernels, "paged_attention", False))
-            else None
-        )
 
         self.config = cfg = config or ServingConfig()
         if cfg.block_size < 1 or cfg.max_slots < 1:
@@ -230,11 +217,10 @@ class DecodeService:
         # the layer plan (docs/serving.md §layer plan): None where every
         # layer is attention, else the kinds in order
         kinds = layer_plan(spec.family, spec.cfg)
-        if kinds is not None and (cfg.decode_steps != 1 or self._kernels is not None):
+        if kinds is not None and cfg.decode_steps != 1:
             raise NotImplementedError(
-                "a mixed layer plan is served one token a dispatch on the "
-                "gather path: decode_steps > 1 and the paged-attention kernel "
-                "do not carry its state pool yet"
+                "a mixed layer plan is served one token a dispatch: "
+                "decode_steps > 1 does not carry its state pool yet"
             )
         self._g, self._layers = stacked_params_for_mode(
             model, self._qbits, spec.stack
@@ -397,19 +383,6 @@ class DecodeService:
                 "family": type(self.spec.family).__name__,
                 "cfg": repr(dcfg),
                 "qbits": self._qbits,
-                # a kernel-armed decode is a different program: flipping the
-                # kernel — or forcing the lowering mode — must be a loud
-                # serving-cache miss (docs/kernels.md).  Only the kernel the
-                # decode path actually consumes rides the key: arming a
-                # TRAINING kernel (collective_matmul/quantized_rs) changes
-                # nothing about these programs and must not cold-compile a
-                # warm replica.
-                "kernels": (
-                    "paged_attention:"
-                    + ("interpret" if self._kernels.interpret else "mosaic")
-                    if self._kernels is not None
-                    else "none"
-                ),
                 "temperature": float(cfg.temperature),
                 "block_size": cfg.block_size,
                 "max_slots": cfg.max_slots,
@@ -486,6 +459,14 @@ class DecodeService:
             "decode_syncs": 0,
             "decode_tokens": 0,
             "h2d_uploads": 0,
+            # how far decode attention's mechanism engages (docs/telemetry.md
+            # §serving): pages a scanned plan's kernel walks (each decoding
+            # slot's own length, positions // block_size + 1 a token) against
+            # pages those slots' table rows span (blocks_per_slot each: what
+            # the gather path attends over) — host arithmetic on the mirrors,
+            # no device read
+            "kv_pages_walked": 0,
+            "kv_pages_tabled": 0,
             # fault-tolerance accounting (docs/serving.md §fault
             # tolerance): shed completions, recovered (re-prefilled)
             # admissions, retry attempts, exhaustion requeues, pool
@@ -1177,7 +1158,6 @@ class DecodeService:
                     qbits=self._qbits,
                     temperature=float(self.config.temperature),
                     watcher=self.watcher, aot=self._aot,
-                    kernels=self._kernels,
                 )
                 # transient-fault retry (docs/serving.md §fault tolerance):
                 # the injected/classified-transient fault fires BEFORE the
@@ -1264,6 +1244,12 @@ class DecodeService:
                 # per-token ratio
                 self.stats["host_syncs"] += 1
                 self.stats["decode_syncs"] += len(active)
+                fed = self._positions[active][:, None] + np.arange(n)  # (active, n)
+                walked = int((fed // self.config.block_size + 1).sum())
+                tabled = n * len(active) * self._tables.shape[1]
+                self.stats["kv_pages_walked"] += walked
+                self.stats["kv_pages_tabled"] += tabled
+                about.update(kv_pages_walked=walked, kv_pages_tabled=tabled)
                 with flightrec.span("atpu/serve/decode_sync"):
                     block_host = np.asarray(tok_block).reshape(
                         self.config.max_slots, n

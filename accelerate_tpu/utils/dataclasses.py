@@ -493,12 +493,10 @@ class KernelKwargs(KwargsHandler):
     No reference counterpart — custom-kernel fusion is an XLA/Mosaic-native
     concern.  ``kernels`` names the armed set: a comma/plus-separated
     subset of ``collective_matmul`` (the ZeRO-1 all-gather as a chunked
-    ring feeding partial matmuls), ``quantized_rs`` (compress.py's
+    ring feeding partial matmuls) and ``quantized_rs`` (compress.py's
     per-block scale+round fused into one kernel region at the shard
-    boundary, plus the stochastic-rounding ZeRO-2 wire) and
-    ``paged_attention`` (serving decode walks the block table in VMEM
-    instead of materializing each slot's full page span); ``all`` arms all
-    three.  When left ``None`` it resolves from ``$ACCELERATE_KERNELS``
+    boundary, plus the stochastic-rounding ZeRO-2 wire); ``all`` arms
+    both.  When left ``None`` it resolves from ``$ACCELERATE_KERNELS``
     (default off) — off means every hot path runs its pre-kernel code
     byte-for-byte, matching the telemetry/resilience/aot-cache/fleet
     precedent.

@@ -1058,13 +1058,10 @@ def _kernels_ab_block(on_accel: bool) -> dict:
     """Per-kernel on/off A/B rows for the primary JSON (docs/kernels.md):
     the SAME GPT geometry trained with each training kernel armed vs off
     (``kernel_<name>_step_ms_{off,on}`` + ``kernel_<name>_speedup`` + dp
-    bytes), and the decode service driven with paged attention armed vs off
-    (tokens/s).  On the CPU interpreter the kernels exist for correctness,
+    bytes).  On the CPU interpreter the kernels exist for correctness,
     not speed — the A/B is the harness the first on-TPU window fills with
     the real fusion win.  ``BENCH_KERNELS=0`` disables the block; rows are
     fail-soft per kernel like the compression A/B."""
-    import time as _t
-
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1148,61 +1145,6 @@ def _kernels_ab_block(on_accel: bool) -> dict:
     else:
         out["kernel_training_skipped"] = "dp=1: no dp collective pair to fuse"
 
-    if "paged_attention" in refused:
-        return out
-    try:
-        from accelerate_tpu.native.kernels import KernelPolicy
-        from accelerate_tpu.serving import DecodeService, ServingConfig
-
-        Accelerator._reset_state()
-        nn.manual_seed(0)
-        model = GPTLMHeadModel(cfg)
-        scfg = ServingConfig(
-            max_slots=8, block_size=16, prompt_bucket=32,
-            max_request_len=min(256, cfg.n_positions),
-        )
-        rng = np.random.default_rng(0)
-        prompts = [
-            rng.integers(1, cfg.vocab_size, (int(n),)).astype(np.int32)
-            for n in rng.integers(4, 28, 8)
-        ]
-
-        def decode_tok_s(kernels):
-            svc = DecodeService(model, scfg, kernels=kernels)
-            rids = [svc.submit(p, max_new_tokens=16) for p in prompts]
-
-            def tokens_total():
-                # finished AND in-flight: warmup-produced tokens must not be
-                # credited to the timed window
-                done = sum(
-                    len(svc.results[r].tokens) for r in rids if r in svc.results
-                )
-                live = sum(
-                    len(req.tokens) for req in svc._slot_req if req is not None
-                )
-                return done + live
-
-            for _ in range(4):
-                svc.step()  # warmup: admit + compile both programs
-            warm_tokens = tokens_total()
-            t0 = _t.perf_counter()
-            for _ in range(200):
-                svc.step()
-                if all(r in svc.results for r in rids):
-                    break
-            dt = _t.perf_counter() - t0
-            decoded = tokens_total() - warm_tokens
-            return (decoded / dt if dt > 0 else 0.0), svc.watcher.recompile_events
-
-        off_tok, _ = decode_tok_s(None)
-        on_tok, on_rec = decode_tok_s(KernelPolicy(paged_attention=True))
-        out["kernel_paged_attention_tok_s_off"] = round(off_tok, 1)
-        out["kernel_paged_attention_tok_s_on"] = round(on_tok, 1)
-        if off_tok > 0:
-            out["kernel_paged_attention_speedup"] = round(on_tok / off_tok, 3)
-        out["kernel_paged_attention_recompile_events"] = on_rec
-    except Exception as exc:
-        _record_failure(out, "kernel_paged_attention", exc)
     return out
 
 

@@ -17,12 +17,18 @@ Run with no arguments on a machine with one TPU chip:
    compiled step's HLO as ``tpu_custom_call``, zero recompiles after warm-up.
 3. **serve** — ``DecodeService`` over the trained model, paged KV cache,
    staggered requests of different prompt lengths at ``decode_steps`` 1 and
-   8.  Greedy tokens equal ``model.generate()`` per request, zero recompile
-   events, no leaked cache blocks.
+   8.  Greedy tokens equal ``model.generate()`` per request — or leave it
+   at a tie, the two tokens one step of the logits' dtype apart
+   (``first_divergence``) — zero recompile events, no leaked cache blocks.
 
 ``--chips 4`` runs, and runs only, the sharded path: the same model and
 batches under ``ParallelismConfig(fsdp_size=4)`` and under dp=4, compared
-with each other and with a plain single-device ``jax.jit`` forward.
+with each other and with a plain single-device ``jax.jit`` forward; then
+the serve phase over the model through ``shard_for_inference`` on an
+fsdp=4 mesh (weights sharded, the KV pools replicated on the mesh: the
+decode program's attention kernel runs per device under ``shard_map``, and
+only a TPU's lowering refuses it bare).  ``--sharded train`` or ``--sharded
+serve`` runs one of the two.
 
 ``--rehearse-cpu`` is the rehearsal of the on-chip-measurement guide (§2
 step 1, and step 2 with ``--chips 4``): the same control flow at tiny sizes
@@ -70,6 +76,9 @@ class Sizes:
     seq: int
     steps: int
     worker_steps: int
+    # the last request fills its slot: prompt + budget = max_request_len, so
+    # at decode_steps 8 its last block overruns to a position past the
+    # slot's table (docs/serving.md §multi-token: overrun safety)
     prompt_lens: tuple
     budgets: tuple
     max_request_len: int
@@ -85,13 +94,13 @@ def sizes_for(rehearse: bool) -> Sizes:
         return Sizes(
             cfg=dataclasses.replace(GPTConfig.tiny(), n_head=2),
             batch=4, seq=128, steps=8, worker_steps=3,
-            prompt_lens=(5, 19, 40, 33), budgets=(12, 17, 9, 20),
+            prompt_lens=(5, 19, 40, 33, 105), budgets=(12, 17, 9, 20, 23),
             max_request_len=128,
         )
     return Sizes(
         cfg=GPTConfig.small(),
         batch=12, seq=1024, steps=8, worker_steps=3,
-        prompt_lens=(5, 19, 40, 70, 33, 12), budgets=(20, 33, 17, 25, 40, 9),
+        prompt_lens=(5, 19, 40, 70, 33, 12, 233), budgets=(20, 33, 17, 25, 40, 9, 23),
         max_request_len=256,
     )
 
@@ -312,7 +321,14 @@ def launch_phase(rehearse: bool) -> None:
 # ---------------------------------------------------------------------------
 def first_divergence(model, got, want, prompt_len: int) -> dict:
     """Where a served request left ``generate()``, and how close the call
-    was: the top-2 margin of teacher-forced logits at that position."""
+    was: the top-2 margin of teacher-forced logits at that position.
+    ``tie``: the two tokens ARE that top 2 and lie one step of the logits'
+    dtype apart or less.  The decode kernel sums in chunks, so its logits
+    agree with ``generate()``'s to summation order and not bitwise
+    (docs/serving.md §parity): at such a position either token is the
+    argmax of some program, this forward included, and what follows a flip
+    is another sequence."""
+    import jax.numpy as jnp
     import numpy as np
 
     import accelerate_tpu.nn as nn
@@ -320,18 +336,23 @@ def first_divergence(model, got, want, prompt_len: int) -> dict:
     n = min(len(got), len(want))
     pos = next((i for i in range(n) if got[i] != want[i]), n)
     with nn.no_grad():
-        logits = np.asarray(
-            model(np.asarray(want[:pos])[None])["logits"].data[0, -1], np.float32
-        )
-    top2 = np.sort(logits)[-2:]
+        raw = model(np.asarray(want[:pos])[None])["logits"].data[0, -1]
+    logits = np.asarray(raw, np.float32)
+    order = np.argsort(logits)
+    margin = float(logits[order[-1]] - logits[order[-2]])
+    # one step of the dtype at the best logit's size
+    step = float(jnp.finfo(raw.dtype).eps) * 2.0 ** math.floor(math.log2(abs(float(logits[order[-1]])) or 1.0))
+    both = pos < len(got) and pos < len(want)
     return {
         "position": pos,
         "new_token_index": pos - prompt_len,
         "served": int(got[pos]) if pos < len(got) else None,
         "generate": int(want[pos]) if pos < len(want) else None,
-        "top2_margin": float(top2[1] - top2[0]),
+        "top2_margin": margin,
         "logit_served": float(logits[got[pos]]) if pos < len(got) else None,
         "logit_generate": float(logits[want[pos]]) if pos < len(want) else None,
+        "tie": both and {int(got[pos]), int(want[pos])} == {int(order[-1]), int(order[-2])}
+        and margin <= step,
     }
 
 
@@ -357,7 +378,8 @@ def serve_leg(model, sizes: Sizes, decode_steps: int) -> list:
     # warm-up: one request per prefill bucket, and the decode program
     t0 = time.perf_counter()
     for b in sorted({bucket_length(n, bucket) for n in sizes.prompt_lens}):
-        service.submit(np.ones(b, np.int32), max_new_tokens=decode_steps + 1)
+        room = sizes.max_request_len - (decode_steps + 1)  # the last bucket is the capacity
+        service.submit(np.ones(min(b, room), np.int32), max_new_tokens=decode_steps + 1)
     service.run()
     warm_s = time.perf_counter() - t0
     warm_compiles = service.watcher.compiles_total
@@ -373,20 +395,22 @@ def serve_leg(model, sizes: Sizes, decode_steps: int) -> list:
         service.step()
     serve_s = time.perf_counter() - t0
 
-    diverged = []
+    left = []
     for rid, prompt, budget in zip(rids, prompts, sizes.budgets):
         want = np.asarray(model.generate(prompt[None], max_new_tokens=budget))[0]
         got = service.results[rid].output_ids
         if not np.array_equal(got, want):
-            diverged.append(
+            left.append(
                 {"request": rid, "prompt_len": len(prompt),
                  **first_divergence(model, got, want, len(prompt))}
             )
+    diverged = [d for d in left if not d["tie"]]
     service.pool.check_no_leaks()
     say(
         "serve", decode_steps=decode_steps, requests=len(rids),
         prompt_lens=list(sizes.prompt_lens), new_tokens=list(sizes.budgets),
-        equal_to_generate=len(rids) - len(diverged), diverged=diverged,
+        equal_to_generate=len(rids) - len(left),
+        left_at_a_tie=[d for d in left if d["tie"]], diverged=diverged,
         warmup_compiles=warm_compiles, warmup_s=round(warm_s, 2),
         recompile_events=service.recompile_events,
         host_syncs_per_token=round(service.host_syncs_per_token, 3),
@@ -572,11 +596,39 @@ def sharded_phase(sizes: Sizes, on_tpu: bool) -> None:
             )
 
 
+def sharded_serve_phase(sizes: Sizes) -> None:
+    """The serve phase over a model whose weights are sharded on every chip
+    (fsdp): the service commits its pools replicated on that mesh, and the
+    decode program is one program over all of them."""
+    import jax
+    import jax.numpy as jnp
+
+    import accelerate_tpu.nn as nn
+    from accelerate_tpu import shard_for_inference
+    from accelerate_tpu.models import GPTLMHeadModel
+    from accelerate_tpu.parallel.mesh import make_mesh
+
+    nn.manual_seed(SEED)
+    model = GPTLMHeadModel(sizes.cfg)
+    for p in model.parameters():
+        p.data = p.data.astype(jnp.bfloat16)
+    model = shard_for_inference(model, mesh=make_mesh({"fsdp": len(jax.devices())}))
+    spread = sorted({len(p.data.sharding.device_set) for p in model.parameters()})
+    say("sharded-serve", mesh={k: v for k, v in model.atpu_mesh.shape.items() if v > 1},
+        devices_a_parameter_spans=spread)
+    if spread[-1] != len(jax.devices()):
+        raise AssertionError(f"no parameter spans every device: {spread}")
+    serve_phase(model, sizes)
+
+
 # ---------------------------------------------------------------------------
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--chips", type=int, default=1, choices=(1, 4),
                         help="4: run only the sharded path and what it is compared with")
+    parser.add_argument("--sharded", default="all", choices=("all", "train", "serve"),
+                        help="with --chips 4: the sharded train legs, the sharded serve "
+                        "phase, or both")
     parser.add_argument("--rehearse-cpu", action="store_true",
                         help="same control flow at tiny sizes on the CPU backend; "
                         "the result line names platform cpu")
@@ -613,7 +665,10 @@ def main(argv=None) -> int:
     if args.chips == 4:
         device = require_device(expect_platform, 4)
         require_native_loader()
-        sharded_phase(sizes, on_tpu=not args.rehearse_cpu)
+        if args.sharded != "serve":
+            sharded_phase(sizes, on_tpu=not args.rehearse_cpu)
+        if args.sharded != "train":
+            sharded_serve_phase(sizes)
     else:
         launch_phase(args.rehearse_cpu)  # before this process touches a backend
         device = require_device(expect_platform, 1)

@@ -52,6 +52,9 @@ def test_rehearsal_control_flow_passes(rehearsal):
     for leg in seen["serve"]:
         assert leg["requests"] >= 4 and leg["equal_to_generate"] == leg["requests"]
         assert leg["recompile_events"] == 0 and len(set(leg["prompt_lens"])) >= 4
+        # one request fills its slot: at decode_steps 8 its last block is fed a
+        # position past the slot's table
+        assert leg["prompt_lens"][-1] + leg["new_tokens"][-1] == 128
 
 
 def test_result_line_is_exactly_the_schema_and_names_the_cpu(rehearsal):
@@ -131,7 +134,7 @@ def test_four_chip_rehearsal_on_four_virtual_devices():
     }
     seen = phases(proc.stdout)
     # the sharded path and what it is compared with — and no one-chip phase
-    assert list(seen) == ["start", "sharded", "sharded-compare", "done"]
+    assert list(seen) == ["start", "sharded", "sharded-compare", "sharded-serve", "serve", "done"]
     fsdp, dp = seen["sharded"]
     assert fsdp["mesh"] == {"fsdp": 4} and dp["mesh"] == {"dp": 4}
     assert fsdp["params_sharded"] > 0 and fsdp["opt_state_sharded"] > 0
@@ -140,6 +143,42 @@ def test_four_chip_rehearsal_on_four_virtual_devices():
     for a, b in zip(compare["fsdp"], compare["dp"]):
         assert abs(a - b) <= compare["loss_rtol"] * abs(b)
     assert abs(compare["single_device_forward_step0"] - compare["fsdp"][0]) < 1e-3
+    # the serve phase over weights sharded on all four: one decode program over
+    # the mesh, the pools replicated on it, the kernel under shard_map
+    (served,) = seen["sharded-serve"]
+    assert served["mesh"] == {"fsdp": 4} and served["devices_a_parameter_spans"][-1] == 4
+    assert [leg["decode_steps"] for leg in seen["serve"]] == [1, 8]
+    for leg in seen["serve"]:
+        assert leg["equal_to_generate"] == leg["requests"] >= 4 and leg["recompile_events"] == 0
+
+
+@pytest.mark.parametrize(
+    "logits, served, tie",
+    [
+        ([1.0, 2.3125, 2.296875, 0.5], 1, True),  # one bfloat16 step apart: either is some program's argmax
+        ([1.0, 2.34375, 2.296875, 0.5], 1, False),  # three steps: a divergence
+        ([2.3125, 2.3125, 2.296875, 0.5], 3, False),  # the served token is not one of the two
+    ],
+    ids=["one-step", "three-steps", "not-the-runner-up"],
+)
+def test_a_tie_is_told_from_a_divergence(logits, served, tie):
+    """``first_divergence``: a served stream that leaves ``generate()`` where
+    the two tokens are the forward's top 2, one step of the logits' dtype
+    apart, left it at a tie (the decode kernel's logits agree to summation
+    order, not bitwise); anything else is a divergence and fails the smoke."""
+    import types
+
+    import jax.numpy as jnp
+
+    sys.path.insert(0, REPO)
+    import chip_smoke
+
+    def model(ids):
+        return {"logits": types.SimpleNamespace(data=jnp.asarray(logits, jnp.bfloat16)[None, None])}
+
+    found = chip_smoke.first_divergence(model, [7, 7, served], [7, 7, 2], prompt_len=2)
+    assert found["position"] == 2 and found["new_token_index"] == 0
+    assert found["tie"] is tie
 
 
 def test_launch_parent_never_initialises_a_backend(tmp_path):

@@ -5,7 +5,9 @@ arming a kernel, the armed path is **bitwise-identical** to its reference
 path under jit (interpreter mode on CPU — the tier-1 surface), the
 lowered IR proves the fusion structurally (``native/kernels/inspect.py``),
 replays stay zero-recompile, the AOT-cache fingerprint keys on the policy,
-and the default-off path is byte-identical to the pre-kernel library.
+and the default-off path is byte-identical to the pre-kernel library.  The
+decode program's paged attention is behind no policy: it is held to
+``cached_attention`` on the gathered span, to its dtype's rounding.
 
 Runs on any virtual CPU mesh extent: the default suite forces 8 devices
 (tests/conftest.py) and ``make multichip`` re-runs this file at dp=4.
@@ -41,10 +43,7 @@ from accelerate_tpu.native.kernels.collective_matmul import (
     ring_all_gather,
     zero1_gather_eligible,
 )
-from accelerate_tpu.native.kernels.paged_attention import (
-    paged_attention,
-    reference_paged_attention,
-)
+from accelerate_tpu.native.kernels.paged_attention import paged_attention
 from accelerate_tpu.native.kernels.quantize_rs import (
     fused_quantize_dequantize,
     fused_reduce_scatter,
@@ -84,21 +83,22 @@ def test_policy_default_off(monkeypatch):
 
 
 def test_policy_resolution_env_kwargs_and_errors(monkeypatch):
-    monkeypatch.setenv("ACCELERATE_KERNELS", "paged_attention, quantized_rs")
+    monkeypatch.setenv("ACCELERATE_KERNELS", "quantized_rs, collective_matmul")
     env_policy = resolve_kernel_policy()
-    assert env_policy.armed() == ("quantized_rs", "paged_attention")
+    assert env_policy.armed() == ("collective_matmul", "quantized_rs")
     assert resolve_kernel_policy(KernelKwargs(kernels="all")).armed() == (
-        "collective_matmul", "quantized_rs", "paged_attention",
+        "collective_matmul", "quantized_rs",
     )
     # explicit kwargs beat the env (the handler never reads it when set)
     assert not resolve_kernel_policy(KernelKwargs(kernels="none")).enabled
-    with pytest.raises(ValueError, match="unknown kernel"):
-        resolve_kernel_policy(KernelKwargs(kernels="flash_decode"))
+    for gone in ("flash_decode", "paged_attention"):  # the latter is no switch any more
+        with pytest.raises(ValueError, match="unknown kernel"):
+            resolve_kernel_policy(KernelKwargs(kernels=gone))
     # the env-armed policy is visible process-wide without an Accelerator
     assert current_kernel_policy() is not None
-    # ...but an Accelerator's EXPLICIT disarm beats the env: a later
-    # standalone DecodeService must not re-arm a policy the user opted
-    # out of (the active slot distinguishes disarmed from never-resolved)
+    # ...but an Accelerator's EXPLICIT disarm beats the env: a later bare
+    # Optimizer must not re-arm a policy the user opted out of (the active
+    # slot distinguishes disarmed from never-resolved)
     _set_active_kernels(None)
     assert current_kernel_policy() is None
     _reset_active_kernels()
@@ -127,7 +127,7 @@ def test_fingerprint_keys_on_kernel_policy():
     mesh = _dp_mesh()
     off = topology_fingerprint(mesh=mesh, compression="none", kernels="none")
     on = topology_fingerprint(
-        mesh=mesh, compression="none", kernels="collective_matmul+paged_attention"
+        mesh=mesh, compression="none", kernels="collective_matmul+quantized_rs"
     )
     assert off != on
     cause = fingerprint_mismatch(off, on)
@@ -265,69 +265,93 @@ def test_stochastic_wire_deterministic_and_unbiased():
 
 
 # ---------------------------------------------------------------------------
-# kernel 3: paged attention
+# the decode program's attention: each slot's live pages, as they lie
 # ---------------------------------------------------------------------------
 class _AttnCfg:
-    sliding_window = 0
+    def __init__(self, sliding_window=0):
+        self.sliding_window = sliding_window
 
 
-def test_paged_attention_bitwise_vs_gather_path():
-    slots, bps, n_kv, bs, d, heads = 3, 4, 2, 8, 16, 4
-    key = jax.random.PRNGKey(0)
-    kp = jax.random.normal(key, (10, n_kv, bs, d), jnp.float32)
-    vp = jax.random.normal(jax.random.fold_in(key, 1), (10, n_kv, bs, d), jnp.float32)
-    q = jax.random.normal(jax.random.fold_in(key, 2), (slots, heads, 1, d), jnp.float32)
-    tables = jnp.asarray([[1, 2, 0, 0], [3, 4, 5, 0], [6, 7, 8, 9]], jnp.int32)
-    positions = jnp.asarray([9, 17, 30], jnp.int32)
-    cfg = _AttnCfg()
-    ref = jax.jit(
-        lambda *a: reference_paged_attention(*a, cfg=cfg)
-    )(q, kp, vp, tables, positions)
-    fused = jax.jit(
-        lambda *a: paged_attention(*a, cfg=cfg, interpret=True)
-    )(q, kp, vp, tables, positions)
-    np.testing.assert_array_equal(np.asarray(ref), np.asarray(fused))
+def _span_attention(q, k_rows, v_rows, tables, positions, first_row, cfg, n_kv):
+    """The plain reference: gather every slot's whole table as one contiguous
+    ``(Hkv, S, d)`` cache and run ``cached_attention`` over the full span
+    under its mask — what the decode program did before the kernel."""
+    from accelerate_tpu.models.generation import cached_attention
 
+    d = q.shape[-1]
 
-def test_ir_paged_attention_no_span_materialization():
-    facts = kernel_inspect.check_paged_attention()
-    assert facts["fused_materializes_span"] is False
-    assert facts["reference_materializes_span"] is True
+    def one(q_s, row, p_s):
+        def span(rows):
+            return rows[first_row + row][..., : n_kv * d].reshape(-1, n_kv, d).transpose(1, 0, 2)
 
-
-def test_serving_paged_decode_token_parity_and_zero_recompiles():
-    from accelerate_tpu.serving import DecodeService, ServingConfig
-
-    model = GPTLMHeadModel(GPTConfig.tiny())
-    model.eval()
-    rng = np.random.default_rng(0)
-    prompts = [
-        rng.integers(1, 100, (int(n),)).astype(np.int32) for n in (5, 11, 3, 17)
-    ]
-
-    def serve(kernels):
-        svc = DecodeService(
-            model,
-            ServingConfig(max_slots=4, block_size=8, prompt_bucket=16,
-                          max_request_len=64),
-            kernels=kernels,
+        att = cached_attention(
+            q_s[None, :, None, :], span(k_rows)[None], span(v_rows)[None], p_s[None], cfg
         )
-        rids = [svc.submit(p, max_new_tokens=8) for p in prompts]
-        for _ in range(40):
-            svc.step()
-            if all(r in svc.results for r in rids):
-                break
-        toks = [list(svc.results[r].tokens) for r in rids]
-        return toks, svc.watcher.recompile_events, svc
+        return att[0, :, 0]
 
-    ref_toks, _, ref_svc = serve(None)
-    paged_toks, paged_recompiles, paged_svc = serve(
-        KernelPolicy(paged_attention=True)
+    return jax.vmap(one)(q, tables, positions)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize(
+    "heads, n_kv, d, block, bps, window",
+    [(25, 25, 64, 16, 20, 0), (32, 2, 128, 16, 12, 0), (8, 2, 16, 8, 40, 70)],
+    ids=["25x64-multi-head", "32on2x128-grouped", "sliding-window"],
+)
+def test_paged_attention_is_cached_attention_over_the_live_pages(heads, n_kv, d, block, bps, window, dtype):
+    """The kernel (interpreter) against ``cached_attention`` on the gathered
+    span, at the shapes that run it, with ragged slot lengths: 1, one ending
+    exactly on a page edge, one just past a chunk's edge, the longest the
+    table allows, and a dead slot (zeros).  NaN fills the trash block and every page past a
+    slot's length — the kernel's pool only: none may reach a live output.
+    The last slot is fed a position PAST its table (an overrun micro-step of
+    ``decode_steps > 1`` at capacity): it attends as the table's last position
+    does, and reads no table entry past its row (on the chip that entry would
+    be a DMA address).  Float32 to float32 rounding, bfloat16 to its own."""
+    from accelerate_tpu.serving.kv_blocks import page_lanes
+
+    lengths = [1, 3 * block, 128 + 3, bps * block, 0, block + 1, bps * block]  # tokens held; 0 = dead
+    layers, layer, lanes = 2, 1, page_lanes(n_kv, d)
+    num_blocks = 1 + sum(-(-n // block) for n in lengths) + 3
+    rng = np.random.default_rng(0)
+
+    def pool():
+        rows = rng.standard_normal((layers * num_blocks, block, lanes)).astype(np.float32)
+        rows[..., n_kv * d:] = 0.0  # the pad lanes hold zeros
+        return rows
+
+    k_rows, v_rows = pool(), pool()
+    q = jnp.asarray(rng.standard_normal((len(lengths), heads, d)), dtype)
+    tables = np.zeros((len(lengths), bps), np.int32)  # unused entries name the trash block
+    free = iter(rng.permutation(np.arange(1, num_blocks)))  # out of order: the table does work
+    for slot, n in enumerate(lengths):
+        tables[slot, : -(-n // block)] = [next(free) for _ in range(-(-n // block))]
+    last = [max(n - 1, 0) for n in lengths]
+    positions = jnp.asarray(last[:-1] + [bps * block + 6], jnp.int32)  # the overrun
+    first_row = jnp.int32(layer * num_blocks)
+    cfg = _AttnCfg(window)
+
+    def poisoned(rows):
+        rows = rows.copy()
+        dead = np.setdiff1d(np.arange(num_blocks), tables[tables > 0])  # trash + never handed out
+        rows[layer * num_blocks + dead] = np.nan
+        rows[: layer * num_blocks] = np.nan  # another layer's rows
+        return jnp.asarray(rows, dtype)
+
+    got = jax.jit(lambda *a: paged_attention(*a, cfg, n_kv=n_kv))(
+        q, poisoned(k_rows), poisoned(v_rows), jnp.asarray(tables), positions, first_row
     )
-    assert ref_toks == paged_toks
-    assert paged_recompiles == 0
-    assert paged_svc._kernels is not None and ref_svc._kernels is None
-    paged_svc.pool.check_no_leaks()  # raises on a leaked block
+    want = jax.jit(lambda *a: _span_attention(*a, cfg, n_kv))(
+        q, jnp.asarray(k_rows, dtype), jnp.asarray(v_rows, dtype), jnp.asarray(tables),
+        jnp.asarray(last, jnp.int32), first_row,
+    )
+    assert got.shape == (len(lengths), heads, d) and got.dtype == dtype
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got).all()
+    assert not got[4].any()  # the dead slot: zeros
+    live = [0, 1, 2, 3, 5, 6]
+    tol = 2e-6 if dtype == jnp.float32 else 2e-2  # bfloat16: its 8 bits on values of 1-3
+    np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
 
 
 # ---------------------------------------------------------------------------
@@ -424,7 +448,7 @@ def test_all_kernels_compose_zero_recompile():
     assert armed["losses"][:3] == ref["losses"]
     assert armed["recompiles"] == 0
     assert [r.kernel for r in armed["kernel_records"]] == [
-        "collective_matmul", "quantized_rs", "paged_attention",
+        "collective_matmul", "quantized_rs",
     ]
     assert all(
         r.stats.get("interpret") is True for r in armed["kernel_records"]
@@ -433,7 +457,7 @@ def test_all_kernels_compose_zero_recompile():
 
 def test_default_off_byte_identical():
     """$ACCELERATE_KERNELS unset: no kernel module on the hot path — the
-    optimizer pins None, serving resolves None, the capture-state pytree
+    optimizer pins None, the capture-state pytree
     carries nothing new, and the run is bitwise the pre-kernel library
     (the parity tests above pin that by construction of `ref`)."""
     state = _train(None)

@@ -115,19 +115,40 @@ def test_kernel_policy_interpret_does_not_swallow(monkeypatch):
         KernelPolicy(collective_matmul=True, interpret=True).interpret
 
 
-def test_serving_engine_has_no_interpret_default():
-    """``paged=True`` without the policy's lowering mode is an error: there
-    is no ``kernel_interpret=True`` a caller can forget to override."""
+def test_decode_attention_asks_its_backend_and_takes_no_switch(monkeypatch):
+    """The decode program has one attention path and no argument that picks
+    it or its lowering: the kernel asks what the flash kernels ask
+    (``ops/flash_attention.py::_interpret``), which interprets exactly where
+    the backend is not a TPU, and never on one."""
     import inspect
 
+    from accelerate_tpu.native.kernels import paged_attention
     from accelerate_tpu.serving import engine
 
-    for fn in (engine._decode_body, engine._decode_jit, engine._decode_n_jit):
-        target = inspect.unwrap(fn)
-        default = inspect.signature(target).parameters["kernel_interpret"].default
-        assert default is None, fn
-    source = inspect.getsource(engine._decode_body)
-    assert "kernel_interpret is None" in source and "raise ValueError" in source
+    for fn in (engine._decode_body, engine._decode_jit, engine._decode_n_jit,
+               engine.run_decode, engine.run_decode_n, paged_attention.paged_attention):
+        taken = set(inspect.signature(inspect.unwrap(fn)).parameters)
+        assert not taken & {"paged", "kernel_interpret", "interpret", "kernels"}, fn
+    # what the kernel's pallas_call is told, traced under either answer
+    told = []
+    real = paged_attention.pl.pallas_call
+    monkeypatch.setattr(
+        paged_attention.pl, "pallas_call",
+        lambda *a, **kw: told.append(kw["interpret"]) or real(*a, **kw),
+    )
+    rows = jax.ShapeDtypeStruct((5, 8, 128), jnp.float32)
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+
+    def trace():
+        jax.eval_shape(
+            lambda *a: paged_attention.paged_attention(*a, None, n_kv=2),
+            jax.ShapeDtypeStruct((3, 4, 64), jnp.float32), rows, rows, ints(3, 2), ints(3), ints(),
+        )
+
+    trace()  # this backend is the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    trace()
+    assert told == [True, False]
 
 
 def test_mesh_keeps_the_plain_reshape_for_the_cpu_only(monkeypatch):
@@ -230,3 +251,45 @@ def test_aot_store_loads_onto_the_meshs_devices_not_the_backends():
     entry = {"payload": payload, "in_tree": in_tree, "out_tree": out_tree}
     loaded = _deserialize(entry, half)
     np.testing.assert_array_equal(np.asarray(loaded(x)), np.asarray(x) * 2)
+
+
+_IMPORT_THE_DECODE_KERNEL = """
+import importlib
+import sys
+
+import jax
+import jax.numpy as jnp
+
+import accelerate_tpu.serving.engine  # what a serving process has before its first decode trace
+
+assert "jax.experimental.pallas" not in sys.modules
+answer = sys.argv[1]
+jax.default_backend = lambda: answer
+from accelerate_tpu.native.kernels import paged_attention as kernel
+
+print("whole" if "jax._src.pallas.mosaic_gpu.core" in sys.modules else "without the GPU interpreter")
+rows = jax.ShapeDtypeStruct((5, 8, 128), jnp.float32)
+ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+out = jax.eval_shape(
+    lambda *a: kernel.paged_attention(*a, None, n_kv=2),
+    jax.ShapeDtypeStruct((3, 4, 64), jnp.float32), rows, rows, ints(3, 2), ints(3), ints(),
+)
+assert out.shape == (3, 4, 64)
+importlib.import_module(kernel._GPU_INTERPRETER)  # the name is free again: who asks later gets it
+"""
+
+
+@pytest.mark.parametrize("answer, imported", [("tpu", "without the GPU interpreter"), ("cpu", "whole")])
+def test_the_decode_kernels_pallas_import_follows_the_backend(answer, imported):
+    """A serving process imports Pallas when its first decode program is
+    traced, at every start.  On a TPU the kernel module leaves out jax's
+    Mosaic-GPU interpreter (0.7 of the import's 1.0 s: nothing there can ask
+    for it), the way jax itself allows for, and the kernel traces all the
+    same.  Anywhere else the import is jax's own, whole.  A fresh interpreter
+    each: the decision is made once, where Pallas is first imported."""
+    proc = subprocess.run(
+        [sys.executable, "-c", _IMPORT_THE_DECODE_KERNEL, answer],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, capture_output=True, text=True, cwd=REPO, timeout=300,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.strip().splitlines()[-1] == imported

@@ -166,19 +166,24 @@ def _tiny_llama():
 @pytest.mark.parametrize("make_model", [lambda: GPTLMHeadModel(GPTConfig.tiny()).eval(), _tiny_llama],
                          ids=["mha", "gqa"])
 def test_pool_pages_hold_generates_contiguous_cache(make_model):
-    """The pool is ``(L, NB, bs, n_kv·d)`` — a page is one ``[bs, n_kv·d]``
-    slab, the block index a major dimension — and after a prefill and N
-    decode steps the request's pages, read through its block table, hold
-    position for position the k/v of ``generate()``'s contiguous cache (a
-    page off by one block or one row would differ in the first digit)."""
+    """The pool is ``(L, NB, bs, lanes)`` — a page is one ``[bs, lanes]`` slab,
+    a token's ``n_kv·d`` first and zeros up to whole 128-lane tiles
+    (``page_lanes``), the block index a major dimension — and after a prefill
+    and N decode steps the request's pages, read through its block table,
+    hold position for position the k/v of ``generate()``'s contiguous cache
+    (a page off by one block or one row would differ in the first digit)."""
+    from accelerate_tpu.serving.kv_blocks import page_lanes
+
     nn.manual_seed(0)
     model = make_model()
     dcfg = model._decoder_spec().cfg
     block, n_decode = 4, 7
     service = DecodeService(model, ServingConfig(max_slots=2, block_size=block, prompt_bucket=8))
     n_layers = next(iter(service._layers[0].values())).shape[0]
+    width = dcfg.n_kv_head * dcfg.head_dim
+    assert page_lanes(dcfg.n_kv_head, dcfg.head_dim) == 128 >= width  # gqa: 64 of 128
     assert service._k_pool.shape == service._v_pool.shape == (
-        n_layers, service.pool.num_blocks, block, dcfg.n_kv_head * dcfg.head_dim
+        n_layers, service.pool.num_blocks, block, 128
     )
     # a short request that leaves and a long one that stays, so the request
     # under test gets the first one's blocks, then blocks past the second's:
@@ -202,9 +207,12 @@ def test_pool_pages_hold_generates_contiguous_cache(make_model):
     np.testing.assert_array_equal(tokens, want_ids[len(prompt):])  # the replay IS generate()
     for pool, want in ((service._k_pool, k_want), (service._v_pool, v_want)):
         pool = np.asarray(pool)
+        assert not pool[..., width:].any()  # the pad lanes stay zero
         for page in range(-(-held // block)):
             lo, hi = page * block, min((page + 1) * block, held)
-            got = pool[:, row[page], : hi - lo].reshape(n_layers, hi - lo, dcfg.n_kv_head, dcfg.head_dim)
+            got = pool[:, row[page], : hi - lo, :width].reshape(
+                n_layers, hi - lo, dcfg.n_kv_head, dcfg.head_dim
+            )
             # to round-off: the replay runs eagerly, the service's programs fused
             np.testing.assert_allclose(
                 got.transpose(0, 2, 1, 3), want[:, :, lo:hi], rtol=1e-5, atol=1e-5,
@@ -216,6 +224,79 @@ def test_pool_pages_hold_generates_contiguous_cache(make_model):
         np.asarray(model.generate(prompt[None], max_new_tokens=n_decode + 5))[0],
     )
     service.pool.check_no_leaks()
+
+
+def test_decode_logits_agree_with_a_full_forward_to_float32_summation_order(tiny_model):
+    """What holds between the two engines now that decode attention sums in
+    chunks of pages: the same greedy tokens as ``generate()`` on this model,
+    and decode logits equal to a full forward's at the same positions to
+    float32 rounding — not bitwise.  Requests long enough to cross a chunk's
+    edge (128 tokens), batched with short ones."""
+    import dataclasses
+
+    import jax
+
+    from accelerate_tpu.nn import Tensor, no_grad
+
+    seen = []
+    service = DecodeService(tiny_model, ServingConfig(max_slots=3, block_size=16, prompt_bucket=16))
+    family = service.spec.family
+
+    def finalize(g, x, cfg):
+        logits = family.finalize(g, x, cfg)
+        jax.debug.callback(lambda a: seen.append(np.asarray(a)), logits, ordered=True)
+        return logits
+
+    service.spec = dataclasses.replace(
+        service.spec, family=dataclasses.replace(family, finalize=finalize)
+    )
+    prompts, new = _prompts([120, 7, 60], seed=11), 12
+    rids = [service.submit(p, max_new_tokens=new) for p in prompts]
+    service.step()  # admits all three (a prefill each), then decodes once
+    service.run()
+    decodes = [lg for lg in seen if lg.shape[0] == 3]  # (slots, V): the decode steps
+    assert len(decodes) == new - 1
+    slot_of = {rid: slot for slot, rid in enumerate(rids)}  # admitted in order into slots 0, 1, 2
+    for rid, prompt in zip(rids, prompts):
+        got_ids = service.results[rid].output_ids
+        np.testing.assert_array_equal(
+            got_ids, np.asarray(tiny_model.generate(prompt[None], max_new_tokens=new))[0]
+        )
+        with no_grad():
+            full = np.asarray(tiny_model(Tensor(got_ids[None]))["logits"].data)[0]
+        # decode step j fed the token at position len(prompt) + j
+        want = full[len(prompt): len(prompt) + new - 1]
+        got = np.stack([lg[slot_of[rid]] for lg in decodes])
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+def test_kv_pages_walked_over_tabled_reads_what_the_lengths_imply(tiny_model):
+    """``stats["kv_pages_walked"]`` counts the pages decode attention read —
+    each decoding slot's own length, a token at a time — and
+    ``["kv_pages_tabled"]`` the pages those slots' table rows span
+    (``blocks_per_slot`` each: what the gather path attended over): both
+    follow from the submitted lengths alone, and the ring's step span carries
+    each step's pair."""
+    from accelerate_tpu.telemetry import flightrec
+
+    block = 4
+    service = DecodeService(tiny_model, ServingConfig(max_slots=2, block_size=block, prompt_bucket=8))
+    lengths, budgets = [5, 11], [9, 3]
+    for p, b in zip(_prompts(lengths, seed=12), budgets):
+        service.submit(p, max_new_tokens=b)
+    service.run()
+    walked = tabled = 0
+    for n, b in zip(lengths, budgets):
+        # the prefill samples token 1; decode step j feeds the token at position n + j
+        fed = np.arange(n, n + b - 1)
+        walked += int((fed // block + 1).sum())
+        tabled += (b - 1) * service._tables.shape[1]
+    assert (service.stats["kv_pages_walked"], service.stats["kv_pages_tabled"]) == (walked, tabled)
+    assert 0 < walked < tabled
+    steps = [e for e in flightrec.recorder().snapshot() if e["kind"] == "atpu/serve/step"]
+    mine = steps[-service.stats["steps"]:]
+    assert sum(e.get("kv_pages_walked", 0) for e in mine) == walked
+    assert sum(e.get("kv_pages_tabled", 0) for e in mine) == tabled
 
 
 def test_zero_recompiles_in_steady_state(tiny_model):
@@ -580,6 +661,41 @@ def test_multi_token_overrun_keeps_pool_leak_free(tiny_model):
         np.testing.assert_array_equal(service.results[rid].output_ids, want)
     service.pool.check_no_leaks()
     assert service.pool.free_blocks == service.pool.usable_blocks
+
+
+def test_multi_token_overrun_at_capacity_feeds_a_position_past_the_table(tiny_model):
+    """prompt + max_new == the slot's capacity at n=8: 22 decode tokens run
+    as 3 blocks = 24 micro-steps, so the last one feeds position 64 — past
+    the whole 4-page table.  Its k/v write is dropped (no table column names
+    a block for it), and the attention kernel walks the slot's own 4 pages
+    and no table entry past its row (``paged_attention._kernel`` clamps the
+    position: on the chip that entry would be a DMA address; the
+    interpreter here would clamp the index and count the last page twice,
+    which the kernel test catches).  Both slots overrun, the last one
+    included; tokens equal ``generate()``'s, the pool drains leak-free."""
+    service = DecodeService(
+        tiny_model,
+        ServingConfig(max_slots=2, block_size=16, prompt_bucket=16,
+                      max_request_len=64, decode_steps=8),
+    )
+    assert service.capacity == 64
+    prompts = _prompts([41, 41], seed=11)
+    rids = [service.submit(p, max_new_tokens=23) for p in prompts]
+    fed = []
+    step = service._step
+
+    def watched(about):
+        fed.append(int(service._positions.max()))
+        return step(about)
+
+    service._step = watched
+    service.run()
+    assert max(fed) + 8 > service.capacity  # the last block starts at 57: 57 .. 64
+    for rid, p in zip(rids, prompts):
+        want = np.asarray(tiny_model.generate(p[None], max_new_tokens=23))[0]
+        np.testing.assert_array_equal(service.results[rid].output_ids, want)
+    service.pool.check_no_leaks()
+    assert service.recompile_events == 0
 
 
 def test_zero_recompiles_steady_state_multi_token(tiny_model):

@@ -327,7 +327,7 @@ def test_one_span_mechanism_remains():
 # scopes and the program registry
 # ---------------------------------------------------------------------------
 
-SERVE_SCOPES = ("embed", "qkv", "kv_write", "kv_gather", "attend", "out_mlp", "head")
+SERVE_SCOPES = ("embed", "qkv", "kv_write", "attend", "out_mlp", "head")
 
 
 def _paths(text):
@@ -356,8 +356,9 @@ def test_decode_program_text_carries_every_serve_scope(tiny_model, decode_steps)
     segments = {seg for m in re.finditer(r'loc\("([^"]+)"', lowered.as_text(debug_info=True))
                 for seg in m.group(1).split("/")}
     assert {f"atpu_serve_{s}" for s in SERVE_SCOPES} <= segments
+    assert "atpu_serve_kv_gather" not in segments  # it went with the gathered span it named
     # and the compiled program keeps them as instruction metadata (the CPU
-    # compiler fuses a tiny model's gather and embed into their neighbours)
+    # compiler fuses a tiny model's embed into its neighbour)
     scoped = set(profiler.scope_map_from_text(lowered.compile().as_text()).values())
     assert {"atpu_serve_qkv", "atpu_serve_kv_write", "atpu_serve_attend",
             "atpu_serve_out_mlp", "atpu_serve_head"} <= scoped
@@ -370,7 +371,7 @@ def test_prefill_program_carries_the_scopes_its_phases_have(tiny_model):
     scoped = set(profiler.scope_map("_prefill_jit").values())
     # (the CPU compiler fuses a tiny prefill's pool write into a neighbour)
     assert {"atpu_serve_qkv", "atpu_serve_out_mlp", "atpu_serve_head"} <= scoped
-    assert "atpu_serve_kv_gather" not in scoped  # a prefill attends to its own k/v
+    assert "atpu_serve_attend" in scoped  # cached_attention on the bucket's own k/v
 
 
 def test_train_step_text_carries_head_loss_scope_forward_and_backward():

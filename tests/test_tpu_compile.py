@@ -63,11 +63,14 @@ def dp_mesh(topo):
     return Mesh(np.array(topo.devices).reshape(4), ("dp",))
 
 
-@pytest.fixture(autouse=True)
-def mosaic(monkeypatch):
+@pytest.fixture(autouse=True, scope="module")
+def mosaic():
     """These compiles target a TPU while jax's default backend is the CPU:
-    steer the flash kernels' backend question to the chip's answer."""
-    monkeypatch.setattr(flash, "_interpret", lambda: False)
+    steer the kernels' backend question to the chip's answer (for the whole
+    module: the serving programs are compiled once, in module fixtures)."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(flash, "_interpret", lambda: False)
+        yield
 
 
 def sds(shape, dtype, sharding):
@@ -149,7 +152,7 @@ def test_flash_on_a_mesh_needs_shard_map_and_has_it(dp_mesh, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# the three KernelPolicy kernels, Mosaic mode
+# the KernelPolicy kernels and the decode program's attention, Mosaic mode
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize(
     "shape, axis",
@@ -202,26 +205,36 @@ def test_quantize_rs_on_a_sharded_array_is_refused(dp_mesh):
     assert "cannot be automatically partitioned" in TPU_REFUSED["quantized_rs"]
 
 
-def test_paged_attention_is_refused_by_the_tpu_compiler(one_chip):
-    """Past the block-table BlockSpec (now scalar-prefetched), the attend
-    math does not lower; the arming error quotes this refusal."""
-    from accelerate_tpu.models.gpt import _GPTDecodeCfg
-    from accelerate_tpu.native.kernels.paged_attention import paged_attention
+@pytest.mark.parametrize(
+    "slots, bps, heads, n_kv, d, layers, num_blocks",
+    [(16, 64, 25, 25, 64, 48, 513), (64, 48, 32, 2, 128, 3, 3073)],
+    ids=["gpt2-xl.serve-steady", "32-on-2-heads-of-128"],
+)
+def test_paged_attention_compiles_in_mosaic_for_v5e(one_chip, slots, bps, heads, n_kv, d, layers, num_blocks):
+    """A scanned plan's decode attention at the XL cell's shapes, 16 slots x
+    64 pages of 16 x 1600 (25 heads of 64; the page 1664 lanes wide, 513 blocks
+    a layer), and with grouped kv heads, 64 slots x 48 pages of 16 x 256 (32
+    query heads on 2 kv heads of 128: a Llama-geometry family's shape).  One
+    Mosaic kernel within the VMEM a kernel gets by default (the compiler
+    refuses one that is not), the pools left in HBM: beside it only the spread
+    query and the output before each head takes its own lanes."""
+    from accelerate_tpu.native.kernels import paged_attention as kernel
+    from accelerate_tpu.serving.kv_blocks import page_lanes
 
-    cfg = _GPTDecodeCfg(n_head=12, n_kv_head=12, head_dim=64, eps=1e-5)
-    slots, bps, block = 8, 64, 16
-    pool = sds((slots * bps + 1, 12, block, 64), BF16, one_chip)
-    with pytest.raises(Exception) as refused:
-        jax.jit(
-            lambda q, kp, vp, t, p: paged_attention(
-                q, kp, vp, t, p, cfg=cfg, interpret=False
-            )
-        ).lower(
-            sds((slots, 12, 1, 64), BF16, one_chip), pool, pool,
-            sds((slots, bps), jnp.int32, one_chip), sds((slots,), jnp.int32, one_chip),
-        ).compile()
-    assert "Up to 1 batch dim supported" in str(refused.value)
-    assert "Up to 1 batch dim supported" in refusal_words("paged_attention")
+    block, lanes = 16, page_lanes(n_kv, d)
+    rows = sds((layers * num_blocks, block, lanes), BF16, one_chip)
+    compiled = jax.jit(
+        lambda q, kp, vp, t, p, first: kernel.paged_attention(
+            q, kp, vp, t, p, first, None, n_kv=n_kv
+        )
+    ).lower(
+        sds((slots, heads, d), BF16, one_chip), rows, rows,
+        sds((slots, bps), jnp.int32, one_chip), sds((slots,), jnp.int32, one_chip),
+        sds((), jnp.int32, one_chip),
+    ).compile()
+    assert pallas_calls(compiled) == 1
+    assert "paged_attention" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes <= 4 * slots * 32 * lanes * 2
 
 
 def test_collective_matmul_ring_compiles_for_a_v5e_mesh(dp_mesh):
@@ -259,10 +272,10 @@ def test_collective_matmul_ring_compiles_for_a_v5e_mesh(dp_mesh):
 
 def test_collective_matmul_policy_arms_on_tpu_refused_kernels_do_not(monkeypatch):
     """Arming follows the compiler: the ring is allowed on a TPU backend,
-    the two refused kernels raise with its words — nothing interprets."""
+    the refused kernel raises with its words — nothing interprets."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert KernelPolicy(collective_matmul=True).interpret is False
-    for name in ("quantized_rs", "paged_attention"):
+    for name in TPU_REFUSED:
         with pytest.raises(NotImplementedError) as refused:
             KernelPolicy(**{name: True}).interpret
         assert refusal_words(name) in str(refused.value)
@@ -337,6 +350,17 @@ def test_serving_programs_move_no_pool_sized_buffer(xl_serving_programs, program
     header = text.split("\n", 1)[0]
     for i in (0, 1):
         assert re.search(rf"\{{{i}\}}: \({i}, \{{\}}, (may|must)-alias\)", header), header[:400]
+    if program == "decode":
+        # attention reads each slot's live pages where they lie: one Mosaic
+        # kernel in the scanned layer body, and nothing of the gathered span's
+        # size (16 slots x 1024 positions x a token's lanes; it was built twice a
+        # layer, and re-laid as (25, 1024, 64): 65 of a 72.7 ms step, PERF.md PR 32)
+        from accelerate_tpu.telemetry import profiler
+
+        assert pallas_calls(programs[program]) == 1
+        opcodes = {opcode for _, _, opcode in profiler._HLO_RESULT_RE.findall(text)}
+        span_sized = {dims for _, _, dims in instructions_of_size(text, opcodes, 16 * 1024 * 1600)}
+        assert span_sized <= {(2, 513, 16, 1664), (2 * 513, 16, 1664)}, span_sized  # the pools alone
 
 
 def test_a_plan_of_attention_layers_keeps_the_scan_and_takes_no_state(xl_serving_programs):
@@ -359,6 +383,85 @@ def test_a_plan_of_attention_layers_keeps_the_scan_and_takes_no_state(xl_serving
         text = programs[name].as_text()
         entry = text[text.index("ENTRY"):]
         assert len(re.findall(r"\bparameter\(\d+\)", entry)) == n_args, name
+
+
+# ---------------------------------------------------------------------------
+# a sharded model's decode program: the kernel per device, under shard_map
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def sharded_decode_inputs(topo):
+    """What ``DecodeService`` hands the decode programs for a prepared or
+    ``shard_for_inference`` model on a four-chip host: the weights sharded
+    over the mesh (here over their last dimension where 4 divides it, as a
+    ``tp`` plan would), the pools and the small inputs replicated on the same
+    mesh.  GPT-2-small width, 2 layers, 2048 rows of vocabulary."""
+    import accelerate_tpu.nn as nn
+    from accelerate_tpu.models import GPTConfig, GPTLMHeadModel
+    from accelerate_tpu.models.generation import stacked_params_for_mode
+    from accelerate_tpu.serving import make_pools
+
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("tp",))
+    n_layer, slots, block, bps, num_blocks = 2, 16, 16, 64, 513
+    nn.manual_seed(0)
+    model = GPTLMHeadModel(
+        GPTConfig(vocab_size=2048, n_positions=1024, n_embd=768, n_layer=n_layer, n_head=12)
+    ).eval()
+    for p in model.parameters():
+        p.data = p.data.astype(BF16)
+    spec = model._decoder_spec()
+    pools = jax.eval_shape(lambda: make_pools(
+        n_layer, num_blocks, spec.cfg.n_kv_head, block, spec.cfg.head_dim, BF16
+    ))
+
+    def whole(shape, dtype):
+        return sds(shape, dtype, NamedSharding(mesh, P()))
+
+    def weight(x):
+        over = x.ndim >= 2 and x.shape[-1] % 4 == 0
+        return sds(x.shape, x.dtype, NamedSharding(mesh, P(*[None] * (x.ndim - 1), "tp") if over else P()))
+
+    args = (
+        *(whole(p.shape, p.dtype) for p in pools),
+        *jax.tree_util.tree_map(weight, stacked_params_for_mode(model, 0, spec.stack)),
+        whole((slots, bps), jnp.int32), whole((slots,), jnp.int32), whole((slots,), jnp.int32),
+        whole((slots, 2), jnp.uint32),
+    )
+    statics = dict(family=spec.family, cfg=spec.cfg, qbits=0, temperature=0.0)
+    return mesh, args, statics, int(np.prod(pools[0].shape[1:]))
+
+
+@pytest.mark.parametrize("decode_steps", [1, 8])
+def test_decode_on_a_mesh_needs_shard_map_and_has_it(sharded_decode_inputs, decode_steps):
+    """The decode program's attention is a Mosaic kernel and its only path:
+    bare in a program over four devices the TPU lowering refuses it (the CPU
+    tests interpret the kernel and cannot see this).  Given the pools' mesh
+    (``engine._pool_mesh``, which ``run_decode`` and ``run_decode_n`` read off
+    the committed pool) the kernel runs per device under ``shard_map`` on
+    replicated operands: the program compiles, holds one kernel a scanned
+    layer body, and still carries the replicated pools in place."""
+    from accelerate_tpu.serving import engine
+    from accelerate_tpu.telemetry.profiler import instructions_of_size
+
+    mesh, args, statics, layer_pool_elements = sharded_decode_inputs
+    program = engine._decode_jit if decode_steps == 1 else engine._decode_n_jit
+    if decode_steps > 1:
+        statics = dict(statics, decode_steps=decode_steps)
+    with pytest.raises(NotImplementedError, match="cannot be automatically partitioned"):
+        program.lower(*args, **statics).compile()
+    assert engine._pool_mesh(args[0]) is mesh  # what the dispatch observes
+    compiled = program.lower(*args, **statics, mesh=mesh).compile()
+    text = compiled.as_text()
+    assert pallas_calls(compiled) == 1
+    moved = instructions_of_size(
+        text, ("copy", "dynamic-slice", "dynamic-update-slice", "all-gather", "all-reduce"),
+        layer_pool_elements,
+    )
+    assert moved == []
+    assert len(instructions_of_size(text, ("scatter",), layer_pool_elements)) == 2
+    header = text.split("\n", 1)[0]
+    for i in (0, 1):
+        assert re.search(rf"\{{{i}\}}: \({i}, \{{\}}, (may|must)-alias\)", header), header[:400]
 
 
 # ---------------------------------------------------------------------------
@@ -432,3 +535,51 @@ def test_hybrid_programs_move_no_cache_or_expert_sized_buffer(hybrid_serving_pro
     header = text.split("\n", 1)[0]
     assert len(re.findall(r"(?:may|must)-alias", header)) == 4, header[:600]
     assert 'custom_call_target="tpu_custom_call"' not in text
+
+
+_SERVE_A_MIXED_PLAN = """
+import sys
+
+import numpy as np
+
+import accelerate_tpu.serving.engine as engine
+from accelerate_tpu import DecodeService, ServingConfig
+from accelerate_tpu.models.nemotron_h import NemotronHConfig, NemotronHForCausalLM
+
+cfg = NemotronHConfig(
+    vocab_size=96, hidden_size=32, pattern="MEM*EM", mamba_num_heads=4, mamba_head_dim=8,
+    n_groups=2, ssm_state_size=8, chunk_size=8, num_attention_heads=4, num_key_value_heads=2,
+    head_dim=8, n_routed_experts=8, experts_held=4, num_experts_per_tok=3,
+    moe_intermediate_size=16, moe_shared_expert_intermediate_size=24, max_position_embeddings=128,
+)
+service = DecodeService(
+    NemotronHForCausalLM(cfg).eval(),
+    ServingConfig(max_slots=2, block_size=4, prompt_bucket=16, max_request_len=64),
+)
+for n in (5, 19):
+    service.submit(np.arange(n, dtype=np.int32) % 96, max_new_tokens=6)
+service.run()
+assert len(service.results) == 2 and engine._decode_jit._cache_size() == 1
+print("pallas" if "jax.experimental.pallas" in sys.modules else "no pallas")
+"""
+
+
+def test_a_process_that_serves_a_mixed_plan_never_imports_pallas():
+    """The decode kernel is the scanned plan's, imported in its branch of
+    ``decode_layer`` at trace time: a fresh interpreter that imports the
+    engine, builds a service over a mixed plan and serves two requests through
+    its prefill and decode programs has not imported ``jax.experimental.pallas``
+    (0.8-1.0 s at every start, and one kernel lowering an unrolled attention
+    layer: what ``setup_s`` refused in PR 32, PERF.md PR 33)."""
+    import os
+    import subprocess
+    import sys
+
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    env.pop("XLA_FLAGS", None)  # one device: the service's own default
+    proc = subprocess.run(
+        [sys.executable, "-c", _SERVE_A_MIXED_PLAN], env=env, capture_output=True, text=True,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))), timeout=300,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.strip().splitlines()[-1] == "no pallas"
