@@ -3,7 +3,7 @@
 # the TPU-native layout. All targets run on the virtual 8-device CPU mesh
 # (tests/conftest.py forces it) — no hardware needed.
 
-.PHONY: test test_core test_models test_parallel test_cli test_big_modeling test_checkpoint test_examples test_analysis test_slow lint lint-cold lint-sarif multichip telemetry-smoke resilience-smoke serve-smoke serve-chaos-smoke profile-smoke cache-smoke elastic-smoke autopilot-smoke kernel-smoke pipeline-smoke bench bench-gate
+.PHONY: test test_core test_models test_parallel test_cli test_big_modeling test_checkpoint test_examples test_analysis test_slow lint lint-cold lint-sarif multichip telemetry-smoke pipeline-smoke
 
 # graftlint: whole-program trace-safety & collective-correctness static
 # analysis (docs/graftlint.md). Runs before the suite. The on-disk cache
@@ -57,98 +57,23 @@ multichip:
 	  tests/test_serving_recovery.py tests/test_fleet.py \
 	  tests/test_kernels.py tests/test_parallel_plan.py -q
 
-# telemetry pipeline proof (docs/telemetry.md): tiny model, 3 steps + a
-# forced shape change with telemetry + trace export on, JSONL validated
-# through tools/telemetry_report.py (step phases present, recompile cause
-# attributed), flight-ring health + trace tracks checked; then the
-# injected-hang leg — a real 2-process gloo world where rank 1 hangs, the
-# watchdog dumps both ranks and tools/blackbox_report.py must name the
-# stalled rank and first divergent collective
+# the injected-hang proof (docs/telemetry.md), the one leg of the telemetry
+# pipeline that needs two real processes: a 2-process gloo world where rank
+# 1 hangs, the watchdog dumps both ranks and tools/blackbox_report.py must
+# name the stalled rank and first divergent collective.  What one process
+# can show is in tests/test_telemetry.py
 telemetry-smoke:
 	JAX_PLATFORMS=cpu python tools/telemetry_smoke.py
 
-# preemption-path proof (docs/resilience.md): tiny model, injected SIGTERM
-# at step 2, asserts the loop drains a COMPLETE checkpoint and a fresh
-# accelerator resumes bitwise-equal to the uninterrupted run
-resilience-smoke:
-	JAX_PLATFORMS=cpu python tools/resilience_smoke.py
-
-# serving-path proof (docs/serving.md): tiny GPT, 8 mixed-length staggered
-# requests through the continuous-batching service on CPU — asserts every
-# request's greedy tokens match a single-request generate(), zero recompile
-# events after warmup (CompileWatcher forensics), no leaked KV blocks, and
-# kind="serving" telemetry records present
-serve-smoke:
-	JAX_PLATFORMS=cpu python tools/serving_smoke.py
-
-# fault-tolerant serving proof (docs/serving.md §fault tolerance): tiny
-# GPT, staggered requests through a journaled replica with an injected
-# transient decode fault and a mid-flight SIGTERM — asserts the fault is
-# retried without a recompile, the drain leaves every open request in the
-# journal, a restarted replica completes all of them bitwise-equal to
-# generate() (zero lost), and the second pass against the same AOT store
-# recovers with ZERO compiles
-serve-chaos-smoke:
-	JAX_PLATFORMS=cpu python tools/serve_chaos_smoke.py
-
-# device-time proof (docs/telemetry.md): tiny GPT, 3 steps with every call
-# profiled (profile_every_n=1) — asserts a nonempty per-device busy/idle +
-# compute/collective split covering >= 80% of each replay's wall clock,
-# a valid Prometheus scrape from the live metrics endpoint, and zero
-# recompiles introduced by the profiling itself
-profile-smoke:
-	JAX_PLATFORMS=cpu python tools/profile_smoke.py
-
-# zero-cold-start proof (docs/aot_cache.md): tiny GPT trained 2 steps in a
-# fresh subprocess (miss → compile → store), then restarted in a SECOND
-# fresh subprocess against the same cache dir — asserts the first captured
-# call of the restart has zero trace/compile phase time (telemetry-
-# verified), >= 1 cache hit, and bitwise-equal losses to the cold run
-cache-smoke:
-	JAX_PLATFORMS=cpu python tools/cache_smoke.py
-
-# survive-and-resize proof (docs/elastic.md): tiny GPT on 4 virtual CPU
-# devices, injected host_lost at step 2 — asserts drain → COMPLETE
-# checkpoint → re-mesh dp=4→2 → reshard → loss-parity resume, run twice
-# against one AOT store so the warm pass's post-resize step deserializes
-# the prewarmed dp=2 program with zero trace/compile
-elastic-smoke:
-	JAX_PLATFORMS=cpu python tools/elastic_smoke.py
-
-# closed-loop proof (docs/elastic.md §autopilot): tiny GPT on 4 virtual CPU
-# devices, NO caller polling — injected host_lost → the autopilot shrinks
-# dp 4→2 → injected host_gained → it grows back 2→4, losses within parity
-# of an uninterrupted run, warm pass serves every post-resize build from
-# the AOT store (zero trace/compile), and an injected signal_storm is
-# suppressed by the debounce/hysteresis window (records, zero resizes)
-autopilot-smoke:
-	JAX_PLATFORMS=cpu python tools/autopilot_smoke.py
-
-# pallas-kernel proof (docs/kernels.md): tiny GPT on 4 virtual CPU
-# devices, every kernel armed under the interpreter — IR-inspection
-# assertions (no unfused all-gather-then-dot, no full page-span
-# materialization), loss-bitwise parity vs the reference paths, zero
-# recompiles, paged decode token parity
-kernel-smoke:
-	JAX_PLATFORMS=cpu python tools/kernel_smoke.py
-
-# parallel-plan proof (docs/parallel_plan.md): 2-stage × dp=2 interleaved
-# 1F1B (V=2) with ZeRO-1 + int8 compression + grad accumulation in ONE
-# captured step on 4 virtual CPU devices — asserts the resolved plan IS
-# the acceptance geometry, ≤1e-3 loss parity vs the dp-only run, zero
-# steady-state recompiles, interleaved-vs-fused trajectory parity, and
-# the strictly-smaller analytic bubble at V=2
+# per-stage captured programs across a restart (docs/parallel_plan.md): two
+# fresh one-device subprocesses against one AOT store, the warm one loading
+# all 2·S·V programs with zero compiles at a bitwise-equal loss.  A script
+# until the stagewise loader pins its devices (ROADMAP D9); the rest of the
+# plan's acceptance is in tests/test_parallel_plan.py and tests/test_1f1b.py
 pipeline-smoke:
 	JAX_PLATFORMS=cpu python tools/pipeline_smoke.py
 
-# bench regression gate (docs/performance.md): diff the newest
-# BENCH_r*.json primary step_ms against the previous round; exits nonzero
-# past $$BENCH_REGRESSION_PCT (default 10, same-platform rows only) — a
-# hot-path regression finally fails CI instead of riding the trajectory
-bench-gate:
-	python tools/bench_compare.py
-
-test: lint lint-sarif multichip telemetry-smoke resilience-smoke serve-smoke serve-chaos-smoke profile-smoke cache-smoke elastic-smoke autopilot-smoke kernel-smoke pipeline-smoke bench-gate
+test: lint lint-sarif multichip telemetry-smoke pipeline-smoke
 	python -m pytest tests/ -q
 
 test_core:
@@ -199,12 +124,9 @@ test_examples:
 	python -m pytest tests/test_examples.py tests/test_external_scripts.py -q
 
 test_analysis:
-	python -m pytest tests/test_graftlint.py tests/test_outage_summary.py -q
+	python -m pytest tests/test_graftlint.py -q
 
 # the slow split: subprocess launches + big compiles, partitioned out of
 # the default suite by the `slow` marker; CI runs both targets
 test_slow:
 	RUN_SLOW=1 python -m pytest tests/ -q -m slow
-
-bench:
-	python bench.py

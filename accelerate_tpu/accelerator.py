@@ -808,7 +808,7 @@ class Accelerator:
         """dp-axis collective-bytes attribution (telemetry
         ``kind="collectives"``): the analytic per-step wire bytes of the
         ZeRO-1 reduce-scatter/all-gather pair under the active compression
-        policy — the denominator bench.py's A/B compares across policies."""
+        policy — the denominator an A/B across policies compares."""
         if not self.telemetry.enabled:
             return
         for opt in self._optimizers:
@@ -819,7 +819,7 @@ class Accelerator:
     def _record_kernels(self) -> None:
         """One ``kind="kernel"`` record per armed Pallas kernel
         (docs/kernels.md): which hot path it replaces and how it lowers —
-        the attribution bench.py's kernel A/B and the per-phase device
+        the attribution a kernel on/off A/B and the per-phase device
         split join against."""
         if not self.telemetry.enabled or not self.kernels.enabled:
             return

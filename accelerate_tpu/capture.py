@@ -179,7 +179,7 @@ class CapturedStep:
         self._cache: dict = {}
         # host-side argument-assembly accounting (collect/flatten/key/split
         # before each dispatch): replay calls only — trace/compile calls are
-        # excluded so bench.py can report steady-state host overhead per step
+        # excluded, so that the figure is steady-state host overhead per step
         self.host_assembly_ms_total = 0.0
         self.host_assembly_calls = 0
         # None until the first trace reveals whether the body contains

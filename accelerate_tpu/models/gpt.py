@@ -343,7 +343,7 @@ def maybe_remat(fn):
     recomputes the layer forward instead of keeping its activations alive —
     ~33% more FLOPs for an O(layers) → O(1) activation footprint per layer,
     which buys a larger per-chip batch (usually a net MFU win on HBM-bound
-    workloads; sweep with bench.py).  Used by every pure-fn decoder family
+    workloads; on the chip not measured).  Used by every pure-fn decoder family
     (Llama/OPT/GPT-J/NeoX); numerics are exactly unchanged (tested).
 
     The knobs are read at TRACE time: captured steps bake the value at

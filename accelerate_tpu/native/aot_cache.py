@@ -861,7 +861,7 @@ class AOTServingPrograms:
     serializable), store, dispatch.  CompileWatcher bookkeeping is kept
     equivalent: cold builds count as compiles, disk/memory hits never do,
     and a build on an already-seen signature still raises the steady-state
-    recompile event the smoke/bench assertions read.
+    recompile event the tests' and ``chip_smoke.py``'s assertions read.
     """
 
     def __init__(self, cache: AOTCompilationCache, service_fingerprint: dict):

@@ -20,8 +20,7 @@ structural fact that IS the optimization:
   interpreter lowers to), not as a free-floating top-level op between HBM
   round-trips.
 
-Every check returns the dict of facts it asserted (the smoke target prints
-them); ``main()`` runs them on a small geometry.  (The decode program's
+Every check returns the dict of facts it asserted.  (The decode program's
 paged attention is held against the chip's compiler instead:
 tests/test_tpu_compile.py asserts one Mosaic kernel a layer body and no
 tensor of the gathered span's size in the compiled program.)
@@ -42,7 +41,6 @@ __all__ = [
     "check_collective_matmul",
     "check_quantize_rs",
     "check_pipeline_layout",
-    "run_all",
 ]
 
 _ALL_GATHER_RE = re.compile(r"all[_-]gather", re.IGNORECASE)
@@ -226,24 +224,3 @@ def check_pipeline_layout(mesh=None, *, num_stages: int = 2, virtual: int = 3,
         "— the inspection contrast lost its meaning; update the harness"
     )
     return facts
-
-
-def run_all(interpret: bool = True) -> dict:
-    """The checks on a small geometry (the kernel-smoke entry)."""
-    out = {"quantize_rs": check_quantize_rs(interpret=interpret)}
-    if len(jax.devices()) > 1:
-        out["collective_matmul"] = check_collective_matmul(interpret=interpret)
-    else:
-        out["collective_matmul"] = {"skipped": "single device: no dp ring"}
-    return out
-
-
-def main() -> int:  # pragma: no cover - exercised via tools/kernel_smoke.py
-    import json
-
-    print(json.dumps(run_all(), indent=1, default=str))
-    return 0
-
-
-if __name__ == "__main__":  # pragma: no cover
-    raise SystemExit(main())

@@ -26,7 +26,7 @@ shape.  This module is where both meet: a :class:`CompressionPolicy`
   eligibility gates and error-feedback state management are one code path;
 * **collective-bytes attribution** — :func:`collective_bytes` computes the
   analytic per-step dp-axis wire bytes for a policy, recorded through
-  telemetry (``kind="collectives"``) and A/B'd by ``bench.py``.
+  telemetry (``kind="collectives"``).
 
 Error-feedback semantics (docs/compression.md): in the GSPMD formulation
 the dp gradient *sum* happens inside the backward (XLA's psum), so the

@@ -83,8 +83,8 @@ class Resilience:
                 backoff_s=handler.retry_backoff_s,
                 rollback=handler.rollback,
             )
-        # an init that ran before this hub existed (PartialState hardening,
-        # bench.py's probe) still lands in the event stream; consumed on
+        # an init that ran before this hub existed (PartialState hardening)
+        # still lands in the event stream; consumed on
         # pickup so a later hub in the same process doesn't re-emit a stale
         # report as its own
         from . import backend as _backend

@@ -26,8 +26,8 @@ package turns it into a serving path:
   ``$ACCELERATE_SERVING_JOURNAL``.
 
 Steady state is **zero recompiles** — asserted through the telemetry
-recompile forensics (``CompileWatcher``), benched by bench.py's serving
-block, and smoke-tested by ``make serve-smoke``.
+recompile forensics (``CompileWatcher``), in ``tests/test_serving.py`` and,
+on the chip, by ``chip_smoke.py``.
 """
 
 from .kv_blocks import BlockPool, blocks_for_request, bucket_length, make_pools, make_state_pool

@@ -92,7 +92,7 @@ Zero-recompile forensics: the scheduler routes every call through
 First compiles of a not-yet-seen signature are warmup; any growth on a seen
 signature is an anomaly, counted and emitted as a ``kind="serving"``
 :class:`~..telemetry.RecompileEvent` through the telemetry hub — the
-regression guard the bench/smoke assertions read.
+regression guard the tests' and ``chip_smoke.py``'s assertions read.
 """
 
 from __future__ import annotations
@@ -514,7 +514,7 @@ class CompileWatcher:
     call is warmup, growth on a SEEN signature is a steady-state recompile —
     counted, and emitted as a ``kind="serving"`` RecompileEvent through the
     telemetry hub when one is attached.  ``recompile_events == 0`` after
-    warmup is the serving acceptance contract (ISSUE 7 / bench / smoke).
+    warmup is the serving acceptance contract (ISSUE 7; tests, ``chip_smoke.py``).
     """
 
     def __init__(self, hub=None):

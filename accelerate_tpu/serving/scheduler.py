@@ -1423,8 +1423,8 @@ class DecodeService:
     @property
     def host_syncs_per_token(self) -> float:
         """Blocking device→host syncs a sequence experiences per emitted
-        DECODE token — the dispatch-overhead gauge the bench A/B and
-        serve-smoke assert on: exactly 1.0 on the classic per-token path,
+        DECODE token — the dispatch-overhead gauge ``tests/test_serving.py``
+        asserts on: exactly 1.0 on the classic per-token path,
         ~1/n with an n-token device-resident block (slightly above 1/n
         when stops discard overrun tokens).  Each decode sync counts once
         per active slot, so the ratio is batch-size independent; prefill's

@@ -13,7 +13,7 @@ Two sinks:
 * :func:`write_jsonl` — the full retained history as one JSON object per
   line, ``kind``-tagged (``meta``/``step``/``recompile``/``program``/
   ``resources``/``summary``); the schema ``tools/telemetry_report.py``
-  renders and ``make telemetry-smoke`` validates.  Schema reference:
+  renders and ``tests/test_telemetry.py`` validates.  Schema reference:
   docs/telemetry.md.
 """
 

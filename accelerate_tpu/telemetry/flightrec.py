@@ -185,7 +185,7 @@ class FlightRecorder:
         self._lock = threading.Lock()
         # monotonic↔wall anchor: collective seqs align ranks *ordinally*;
         # the wall anchor lets tools place per-rank monotonic stamps on one
-        # absolute timeline (outage_summary --blackbox join), and is what
+        # absolute timeline, and is what
         # turns a monotonic stamp into the ring clock
         wall_ns, mono_ns, perf_ns = _clock_anchor()
         self._anchor_wall = wall_ns / 1e9

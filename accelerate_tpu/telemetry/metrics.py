@@ -45,8 +45,8 @@ CONTENT_TYPE = "text/plain; version=0.0.4; charset=utf-8"
 
 # the grammar of one exposition sample line this module emits: a bare
 # metric name, optionally the one label histograms require
-# (`_bucket{le="..."}`), then the value.  Exported so the smoke tool and
-# the endpoint tests validate the SAME grammar the renderer produces —
+# (`_bucket{le="..."}`), then the value.  Exported so the endpoint
+# tests validate the SAME grammar the renderer produces —
 # a format change here updates every validator with it.
 SAMPLE_LINE_RE = re.compile(
     r"^[a-zA-Z_][a-zA-Z0-9_]*(\{le=\"[^\"]+\"\})? [-+0-9eE.naif]+$"

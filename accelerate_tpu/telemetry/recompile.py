@@ -2,8 +2,8 @@
 
 ``CapturedStep`` keys its compiled variants on
 ``(args_treedef, per-leaf (shape, dtype), sync_gradients, training_modes)``
-and silently builds a new program whenever a component moves.  bench.py could
-previously only report *that* a recompile happened; this module says *what
+and silently builds a new program whenever a component moves.  A count
+says only *that* a recompile happened; this module says *what
 changed*: each new cache key is diffed against the previously used one and the
 differences become human-readable cause strings on a structured
 :class:`RecompileEvent`.

@@ -116,7 +116,7 @@ class CollectiveRecord:
     ``prepare()``.  Complements ``cost_analysis`` — the backend reports
     collective bytes only on some platforms (the keys ``program_stats``
     scrapes), while this figure exists on every backend, CPU mesh included,
-    so bench.py can A/B ``none`` vs ``int8`` vs ``fp8`` anywhere."""
+    so ``none`` vs ``int8`` vs ``fp8`` can be A/B'd anywhere."""
 
     policy: str
     stats: dict = field(default_factory=dict)
@@ -130,7 +130,7 @@ class KernelRecord:
     """One armed Pallas hot-path kernel (docs/kernels.md), recorded at
     ``prepare()`` like :class:`CollectiveRecord`: which reference path the
     kernel replaces and how it lowers (compiled Mosaic vs interpreter) —
-    the join key for bench.py's kernel A/B and the per-phase device-time
+    the join key for a kernel on/off A/B and the per-phase device-time
     split."""
 
     kernel: str

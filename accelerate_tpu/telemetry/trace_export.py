@@ -184,7 +184,7 @@ def export_chrome_trace(path: str, telemetry=None,
 
 def validate_trace(doc) -> list[str]:
     """Structural well-formedness of a Trace Event Format document; ``[]``
-    when valid.  The smoke (``tools/telemetry_smoke.py``) additionally
+    when valid.  ``tests/test_telemetry.py`` additionally
     asserts the three tracks carry events for the same steps."""
     errors: list[str] = []
     if not isinstance(doc, dict) or not isinstance(doc.get("traceEvents"), list):

@@ -56,3 +56,23 @@ def _reset_singletons():
     AcceleratorState._reset_state()
     GradientState._reset_state()
     PartialState._reset_state()
+
+
+@pytest.fixture
+def only_the_aot_store_skips_a_compile():
+    """For a test that stores an executable in the AOT store and loads it
+    again in the same process: the suite's persistent XLA cache (above) is
+    off while it runs.  An XLA:CPU executable that came out of that cache
+    serializes into an entry that loads and then dies at its first dispatch
+    ("Function iota_compare_fusion not found"), past verify-on-store; and
+    which programs are in it depends on whose compile once took over the 0.5 s
+    threshold (the tiny serving programs do, on a machine six workers load).
+    On a TPU the two layers compose; here a compile is a compile."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()

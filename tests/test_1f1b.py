@@ -283,6 +283,17 @@ def _ckpt_run(layout, mp="no", schedule="interleaved", virtual=2):
     return acc, model, opt, step, ids
 
 
+def test_committed_layout_lowers_without_the_layer_gather():
+    """The same fact where it is structural (docs/parallel_plan.md): the
+    committed layout's lowering holds no gather op and no layer-order index
+    vector, and the gather layout's — the contrast — holds both."""
+    from accelerate_tpu.native.kernels.inspect import check_pipeline_layout
+
+    facts = check_pipeline_layout()
+    assert facts["committed_gather_ops"] == 0 and facts["committed_order_vectors"] == 0
+    assert facts["gather_gather_ops"] > 0 and facts["gather_order_vectors"] > 0
+
+
 def _plain_opt_state(acc, model, opt):
     """Moments (+ masters when present) for STACKED params, viewed in plain
     layer order — the cross-layout bitwise-comparison unit.  Leaf→param

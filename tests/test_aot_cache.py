@@ -31,6 +31,8 @@ from accelerate_tpu.nn.tape import Tensor
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "tools"))
 
+pytestmark = pytest.mark.usefixtures("only_the_aot_store_skips_a_compile")
+
 
 @pytest.fixture(autouse=True)
 def _reset_active_cache():
@@ -400,7 +402,8 @@ def test_serving_warm_from_disk(tmp_path):
     JIT-compiled other programs; verify-on-store catches that and records
     store_failed) recompiles soundly — warmed + compiles covers both
     programs, zero steady-state recompile events, identical greedy tokens.
-    The cross-process zero-cold-start proof is `make cache-smoke`."""
+    The cross-process zero-cold-start proof is
+    ``test_scope_map_persists_across_processes``."""
     cache_dir = tmp_path / "cache"
     svc1, tokens1 = _serving_run(cache_dir)
     assert svc1.watcher.compiles_total == 2  # prefill bucket + decode
@@ -498,10 +501,10 @@ rng = np.random.default_rng(0)
 x = batch_to_global_array(
     np.asarray(rng.normal(size=(8, 16)), np.float32), mesh=acc.mesh
 )
-for _ in range(2):
-    float(step(x))
+losses = [repr(float(step(x))) for _ in range(2)]  # repr keeps the whole float
 first = acc.telemetry.timeline.records()[0]
 result = {
+    "losses": losses,
     "first_trace_ms": first.trace_ms,
     "first_compile_ms": first.compile_ms,
     "hits": acc.aot_cache.hits,
@@ -515,14 +518,15 @@ with open(out_path, "w") as f:
 '''
 
 
-@pytest.mark.slow
 def test_scope_map_persists_across_processes(tmp_path):
     """ROADMAP carried item: programs deserialized from the AOT store carry
     no HLO metadata, so a warm process used to sample EMPTY ``phases`` —
     the op→scope map is now persisted beside the executable and restored on
-    load.  Two real subprocesses (like ``make cache-smoke``): the cold one
+    load.  Two real subprocesses (nothing in memory survives: the shape of a
+    preempted-and-rescheduled job or an autoscaled replica): the cold one
     compiles/stores with every step profiled, the warm one deserializes
-    (zero trace/compile) and its samples must STILL split by atpu phase."""
+    (zero trace/compile, losses bitwise the cold run's) and its samples must
+    STILL split by atpu phase."""
     import subprocess
 
     child = tmp_path / "child.py"
@@ -558,6 +562,7 @@ def test_scope_map_persists_across_processes(tmp_path):
     assert warm["first_trace_ms"] == 0.0 and warm["first_compile_ms"] == 0.0, (
         "warm child recompiled — the store did not serve the program"
     )
+    assert warm["losses"] == cold["losses"]  # the same program, bit for bit
     # THE pin: a metadata-less deserialized program still splits by phase,
     # because the stored scope map was restored into the telemetry hub
     assert warm["phases_per_sample"], "warm run sampled nothing"

@@ -75,7 +75,7 @@ def test_conftest_bench_and_smoke_all_go_through_the_helper():
     """No second placement anywhere: the three entry points call the helper,
     and the only other ``jax_compilation_cache_dir`` update in the tree is
     the profiler-armed disarm (``None``)."""
-    for path in ("tests/conftest.py", "bench.py", "chip_smoke.py"):
+    for path in ("tests/conftest.py", "benchmark/harness.py", "chip_smoke.py"):
         with open(os.path.join(REPO, path), encoding="utf-8") as f:
             source = f.read()
         assert "enable_compilation_cache()" in source, path

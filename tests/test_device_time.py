@@ -408,8 +408,8 @@ def _EnabledKwargs():
 # metrics endpoint: valid Prometheus text, live serving gauges
 # ---------------------------------------------------------------------------
 
-# the renderer's own sample-line grammar (incl. histogram `le` labels) —
-# shared with tools/profile_smoke.py so every validator tracks the format
+# the renderer's own sample-line grammar (incl. histogram `le` labels): one
+# source of truth, so no validator tracks another format than the endpoint's
 from accelerate_tpu.telemetry.metrics import SAMPLE_LINE_RE as _SAMPLE_RE
 
 
@@ -422,8 +422,9 @@ def _scrape(url):
     return body
 
 
-def test_metrics_endpoint_scrapes_training_hub():
-    acc, step = _make_step()
+@pytest.mark.parametrize("profile_every_n", [0, 1], ids=["unprofiled", "every_step_profiled"])
+def test_metrics_endpoint_scrapes_training_hub(profile_every_n):
+    acc, step = _make_step(profile_every_n=profile_every_n)
     batch = _batch(acc)
     for _ in range(2):
         step(batch)
@@ -439,6 +440,8 @@ def test_metrics_endpoint_scrapes_training_hub():
         assert "# TYPE atpu_telemetry_step_latency_ms histogram" in body
         assert 'atpu_telemetry_step_latency_ms_bucket{le="+Inf"} 1' in body
         assert "atpu_telemetry_step_latency_ms_count 1" in body  # replay only
+        # the device split is scraped live once a step has been sampled
+        assert ("atpu_telemetry_device_busy_ms" in body) == bool(profile_every_n)
     finally:
         acc.telemetry.close_metrics()
     assert acc.telemetry.metrics_server is None

@@ -794,11 +794,14 @@ def test_evaluate_window_serving_signals():
 # autopilot: the driver (closed loop, storm, skew)
 # ---------------------------------------------------------------------------
 
-def test_autopilot_closed_loop_no_caller_polling(tmp_path):
+@pytest.mark.parametrize("aot_store", [False, True], ids=["compiled", "aot_store"])
+def test_autopilot_closed_loop_no_caller_polling(tmp_path, aot_store, only_the_aot_store_skips_a_compile):
     """ISSUE acceptance: under an injected host_lost then host_gained plan
     the autopilot ALONE drives dp down and back up — the loop below never
     reads should_resize or calls resize — with final losses within 1e-3 of
-    the uninterrupted run."""
+    the uninterrupted run.  Against one AOT store the scenario runs twice,
+    and the second pass builds every program (the start, after the shrink,
+    after the grow) from the store: zero trace/compile time on each."""
     if _num_devices() < 2:
         pytest.skip("needs >= 2 devices")
     steps = 6
@@ -808,23 +811,31 @@ def test_autopilot_closed_loop_no_caller_polling(tmp_path):
     raw = [np.asarray(b) for b in _batches(acc_ref, steps)]
     ref = [float(step_ref(b)) for b in _batches(acc_ref, steps)]
 
-    Accelerator._reset_state()
-    acc, _, _, step = _make_step(
-        [
+    def closed_loop(tag):
+        Accelerator._reset_state()
+        handlers = [
             FleetKwargs(
                 enabled=True, autopilot=True,
                 fault_plan="host_lost:step=1;host_gained:step=3",
-                checkpoint_dir=str(tmp_path / "drain"),
+                checkpoint_dir=str(tmp_path / f"drain-{tag}"),
             )
         ]
-    )
-    dp = dict(acc.mesh.shape)["dp"]
-    losses = [
-        float(step(batch_to_global_array(b, mesh=acc.mesh))) for b in raw
-    ]
-    assert acc.fleet.resizes_total == 1 and acc.fleet.grows_total == 1
-    assert dict(acc.mesh.shape)["dp"] == dp
-    np.testing.assert_allclose(losses, ref, rtol=1e-3)
+        if aot_store:
+            handlers += [
+                CompilationCacheKwargs(cache_dir=str(tmp_path / "aot")),
+                TelemetryKwargs(enabled=True),
+            ]
+        acc, _, _, step = _make_step(handlers)
+        dp = dict(acc.mesh.shape)["dp"]
+        losses = [
+            float(step(batch_to_global_array(b, mesh=acc.mesh))) for b in raw
+        ]
+        assert acc.fleet.resizes_total == 1 and acc.fleet.grows_total == 1
+        assert dict(acc.mesh.shape)["dp"] == dp
+        np.testing.assert_allclose(losses, ref, rtol=1e-3)
+        return acc
+
+    acc = closed_loop("cold")
     decisions = [e for e in acc.fleet.events if e.get("kind") == "autopilot"]
     fired = [(d["signal"], d["action"]) for d in decisions if d["fired"]]
     assert fired == [("host_lost", "shrink"), ("host_gained", "grow")]
@@ -834,6 +845,14 @@ def test_autopilot_closed_loop_no_caller_polling(tmp_path):
     for d in decisions:
         if d["fired"]:
             assert d["resize"]["direction"] in ("shrink", "grow")
+    if aot_store:
+        assert acc.aot_cache.stores >= 1
+        warm = closed_loop("warm")
+        built = [r for r in warm.telemetry.timeline.records() if r.built]
+        assert len(built) >= 3
+        assert [(r.trace_ms, r.compile_ms) for r in built] == [(0.0, 0.0)] * len(built)
+        hits = [e for e in warm.telemetry.aot_cache_events if e["event"] == "hit"]
+        assert len(hits) >= 3
 
 
 def test_autopilot_signal_storm_suppressed_zero_resizes():

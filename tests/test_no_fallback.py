@@ -1,10 +1,9 @@
 """A missing chip is an error, not a quieter path: the places that used to
-hide the device (bench.py's CPU row under a per-chip name, the O(S²)
+hide the device (a CPU row under a per-chip name, the O(S²)
 attention behind a failed import, interpreted kernels behind a swallowed
 exception, a reshaped mesh behind a failed topology mapping, skip decisions
 that opened a backend at import) fail where they used to fall back."""
 
-import importlib.util
 import json
 import os
 import subprocess
@@ -20,65 +19,85 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
-def bench():
-    spec = importlib.util.spec_from_file_location("bench", os.path.join(REPO, "bench.py"))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)  # defines only; main() is under __main__
-    return module
+def harness():
+    """The yardstick's shared module, read and never edited (``benchmark/``
+    puts the checkout's root on the path the way ``benchmark/run.py`` does)."""
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    from benchmark import harness
+
+    return harness
 
 
 # ---------------------------------------------------------------------------
-# bench.py: what it prints is true of the device it names
+# benchmark/: what it prints is true of the device it names
 # ---------------------------------------------------------------------------
-def test_unknown_device_kind_is_an_error_not_a_default_peak(bench):
-    assert bench.peak_flops("TPU v5 lite") == 197e12
-    assert "TPU v5e" in bench.PEAK_BF16_FLOPS["TPU v5 lite"][1]  # its source
-    for kind in ("cpu", "TPU v9", ""):
-        with pytest.raises(SystemExit, match="no published peak"):
-            bench.peak_flops(kind)
-    assert "BENCH_TPU_PEAK_FLOPS" not in open(os.path.join(REPO, "bench.py")).read()
+def test_unknown_device_kind_is_an_error_not_a_default_peak(harness):
+    flops = harness.flops
+    assert flops.load_peaks("TPU v5 lite")["bf16_flops_per_s"] == 197e12
+    with open(os.path.join(REPO, "benchmark", "peaks.json")) as f:
+        assert "TPU v5e" in json.load(f)["_source"]  # the table names where its peaks come from
+    for kind in ("cpu", "TPU v9", "", "_source"):
+        with pytest.raises(KeyError, match="not in benchmark/peaks.json"):
+            flops.load_peaks(kind)
 
 
-def test_bench_without_a_tpu_exits_nonzero_and_prints_no_row():
+def test_the_benchmark_without_a_tpu_exits_3_and_prints_no_result():
     proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")], cwd=REPO,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        [sys.executable, os.path.join(REPO, "benchmark", "run.py"),
+         "--workload", "gpt2-medium.train-1k", "--seed", "0", "--seconds", "1"],
+        cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
         capture_output=True, text=True, timeout=300,
     )
-    assert proc.returncode != 0
-    assert "per_chip" not in proc.stdout and "{" not in proc.stdout
-    assert "not on a TPU" in proc.stderr
+    assert proc.returncode == 3
+    assert proc.stdout.strip() == ""  # no result line, under any name
+    assert "the cell needs 1 tpu chip(s)" in proc.stderr
 
 
-def test_a_cpu_row_is_never_printed_under_a_per_chip_name(bench, capsys):
-    row = {
-        "metric": "gpt2_small_train_tokens_per_sec_per_chip", "value": 7000.0,
-        "vs_baseline": 0.05, "mfu_pct": None,
-        "bert_mrpc_samples_per_sec_per_chip": 3.0,
+class _Device:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
+@pytest.mark.parametrize("platform, kind, count, why", [
+    ("cpu", "cpu", 1, "needs 1 tpu chip"),  # another platform
+    ("tpu", "TPU v5 lite", 4, "needs 1 tpu chip"),  # another count than the cell names
+    ("tpu", "TPU v9", 1, "not in benchmark/peaks.json"),  # a chip without published peaks
+])
+def test_a_one_chip_cell_is_refused_on_any_other_machine(harness, monkeypatch, platform, kind, count, why):
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Device(platform, kind)] * count)
+    with pytest.raises(harness.NoChip, match=why):
+        harness.require_chips(1)
+    assert harness.open_cell("gpt2-medium.train-1k", "run.py") is None  # what run.py turns into exit 3
+
+
+def test_the_chip_the_cell_names_is_reported_as_jax_reports_it(harness, monkeypatch):
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Device("tpu", "TPU v5 lite")])
+    assert harness.require_chips(1) == {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+
+
+@pytest.mark.parametrize("numbers, correct", [
+    ({"served_logit_gap": 0.03, "unfinished_requests": 0.0}, True),
+    ({"served_logit_gap": 0.03, "unfinished_requests": 1.0}, False),  # one request never finished
+    ({"served_logit_gap": 0.151, "unfinished_requests": 0.0}, False),  # a number over its limit
+    ({"served_logit_gap": float("nan"), "unfinished_requests": 0.0}, False),
+    ({"served_logit_gap": 0.03}, False),  # a limit without its number
+    ({"served_logit_gap": 0.03, "unfinished_requests": 0.0, "unlisted": 0.0}, False),  # and the reverse
+])
+def test_a_failed_operation_or_a_number_over_its_limit_is_not_correct(harness, numbers, correct):
+    with open(os.path.join(REPO, "benchmark", "limits", "gpt2-xl.serve-steady.json")) as f:
+        limits = json.load(f)["limits"]
+    ok, compared = harness.decide(numbers, limits)
+    assert ok is correct
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    line = json.loads(harness.result_line(
+        correct=ok, attempted=112, failed=int(numbers.get("unfinished_requests", 0)), metrics={},
+        units={}, device=device, compared=compared,
+    ))
+    assert line["correct"] is correct and line["device"] == device
+    assert line["compared"]["served_logit_gap"] == {
+        "value": pytest.approx(numbers["served_logit_gap"], nan_ok=True), "limit": limits["served_logit_gap"],
     }
-    bench._EMITTED = False
-    assert bench._emit_result(dict(row), on_accel=False) == 0
-    printed = json.loads(capsys.readouterr().out)
-    assert not any("per_chip" in str(k) or "per_chip" in str(v) for k, v in printed.items())
-    assert printed["metric"] == "gpt2_tiny_train_tokens_per_sec_cpu_rehearsal"
-    assert "vs_baseline" not in printed and printed["mfu_pct"] is None
-    # on the chip the names stand
-    bench._EMITTED = False
-    assert bench._emit_result(dict(row), on_accel=True) == 0
-    assert json.loads(capsys.readouterr().out)["metric"].endswith("_per_chip")
-
-
-def test_a_failed_phase_makes_the_exit_code_nonzero(bench, capsys):
-    result = {"metric": "gpt2_small_train_tokens_per_sec_per_chip", "value": 1.0}
-    try:
-        raise RuntimeError("serving block broke")
-    except RuntimeError as exc:
-        bench._record_failure(result, "serving", exc)
-    bench._EMITTED = False
-    assert bench._emit_result(result, on_accel=True) == 1
-    out, err = capsys.readouterr()
-    assert json.loads(out)["failed_phases"] == ["serving_error"]
-    assert "RuntimeError: serving block broke" in err  # the traceback, not just a field
 
 
 # ---------------------------------------------------------------------------

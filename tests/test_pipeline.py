@@ -70,13 +70,13 @@ def test_gpipe_bad_microbatch():
 
 
 def test_bubble_profile_common_granularity():
-    """Pin the bench's A/B bubble accounting (bench.py _pipeline_block):
-    BOTH arms must be quoted in the SAME chunk unit (granularity=V), where
+    """Pin the A/B bubble accounting of a fused against an interleaved
+    schedule: BOTH arms must be quoted in the SAME chunk unit (granularity=V), where
     the fused profile is exactly V× the interleaved one.  At each
     schedule's OWN default granularity the two are numerically equal
     (2·(S−1) self-sized chunks each) — comparing defaults would silently
     erase the interleaving gain, which is the bug this test pins out."""
-    # the bench geometry: M=8, S=2, V=2 quoted in 1/2-stage chunks
+    # the A/B geometry: M=8, S=2, V=2 quoted in 1/2-stage chunks
     assert bubble_ticks(8, 2, 1, granularity=2) == 4
     assert bubble_ticks(8, 2, 2, granularity=2) == 2
     for S in (2, 4):

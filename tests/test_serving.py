@@ -465,9 +465,11 @@ def test_quantized_mode_composes(tiny_model):
     assert set(tiny_model._generation_param_cache[1]) >= {8}
 
 
-def test_serving_telemetry_records(tiny_model):
+@pytest.mark.parametrize("decode_steps", [1, 8])
+def test_serving_telemetry_records(tiny_model, decode_steps):
     """With a hub attached, every step emits a kind='serving' occupancy
-    record and every completion a TTFT/TPOT record; the JSONL dump carries
+    record and every completion a TTFT/TPOT record, on the per-token path
+    and on the device-resident block loop alike; the JSONL dump carries
     them (docs/telemetry.md schema)."""
     from accelerate_tpu.telemetry import Telemetry
     from accelerate_tpu.utils.dataclasses import TelemetryKwargs
@@ -475,7 +477,7 @@ def test_serving_telemetry_records(tiny_model):
     hub = Telemetry(TelemetryKwargs(enabled=True))
     service = DecodeService(
         tiny_model,
-        ServingConfig(max_slots=2, block_size=16, prompt_bucket=16),
+        ServingConfig(max_slots=2, block_size=16, prompt_bucket=16, decode_steps=decode_steps),
         telemetry=hub,
     )
     rids = [service.submit(p, max_new_tokens=3) for p in _prompts([4, 7, 9], seed=6)]

@@ -13,6 +13,7 @@ import json
 import os
 import signal
 
+import jax
 import numpy as np
 import pytest
 
@@ -300,40 +301,67 @@ def test_non_transient_fault_raises(tiny_model, monkeypatch):
 # preemption drain + resume
 # ---------------------------------------------------------------------------
 
-def test_sigterm_drains_and_fresh_replica_resumes(tiny_model, tmp_path,
-                                                  monkeypatch):
+@pytest.mark.parametrize("aot_store", [False, True], ids=["compiled", "aot_store"])
+def test_sigterm_drains_and_fresh_replica_resumes(tiny_model, tmp_path, monkeypatch, aot_store,
+                                                  only_the_aot_store_skips_a_compile):
     """Injected SIGTERM mid-decode: the guard's sticky flag drains the
     service (journal finalized, open rids reported); a fresh replica on the
-    same journal completes every request, bitwise equal, zero lost."""
+    same journal completes every request, bitwise equal, zero lost.  Against
+    one AOT store the scenario runs twice, and the second pass — the
+    recovery's re-prefills among it — compiles nothing the first one stored:
+    a replica's restart is disk reads."""
     prompts = _prompts(_LENGTHS)
     ref = DecodeService(tiny_model, _cfg())
     _run_all(ref, prompts, _BUDGETS)
     want = _outputs(ref)
 
-    jdir = str(tmp_path / "j")
-    monkeypatch.setenv("ACCELERATE_FAULT_PLAN", "serving_sigterm:step=2")
-    a = DecodeService(tiny_model, _cfg(journal_dir=jdir))
-    for p, b in zip(prompts, _BUDGETS):
-        a.submit(p, max_new_tokens=b)
-    a.run(max_steps=50)
-    assert a.draining
-    finished_on_a = _outputs(a)
-    open_rids = a.drain()  # idempotent; returns the still-open rids
-    assert open_rids and set(open_rids).isdisjoint(finished_on_a)
-    state = replay_journal(jdir)
-    assert state.drained
-    assert [e.rid for e in state.open_requests] == open_rids
+    def replica(jdir):
+        if not aot_store:
+            return DecodeService(tiny_model, _cfg(journal_dir=jdir))
+        from accelerate_tpu import CompilationCacheKwargs
+        from accelerate_tpu.native.aot_cache import AOTCompilationCache
 
-    monkeypatch.delenv("ACCELERATE_FAULT_PLAN")
-    b_svc = DecodeService(tiny_model, _cfg(journal_dir=jdir))
-    resumed = b_svc.resume_from_journal()
-    assert resumed == open_rids
-    _run_all(b_svc)
-    got = _outputs(b_svc)
-    # zero lost: A's completions + B's recoveries cover every submission
-    assert sorted(list(finished_on_a) + list(got)) == sorted(want)
-    for rid in got:
-        np.testing.assert_array_equal(got[rid], want[rid])
+        cache = AOTCompilationCache(CompilationCacheKwargs(cache_dir=str(tmp_path / "aot")))
+        # the unprepared model lives on one device; left unpinned, a stored
+        # program loads onto every device of the backend (8 here)
+        cache.set_context(mesh=jax.sharding.Mesh(np.array(jax.devices()[:1]), ("dp",)))
+        return DecodeService(tiny_model, _cfg(journal_dir=jdir), aot_cache=cache)
+
+    def preempt_and_resume(jdir):
+        monkeypatch.setenv("ACCELERATE_FAULT_PLAN", "serving_sigterm:step=2")
+        a = replica(jdir)
+        for p, b in zip(prompts, _BUDGETS):
+            a.submit(p, max_new_tokens=b)
+        a.run(max_steps=50)
+        assert a.draining
+        finished_on_a = _outputs(a)
+        open_rids = a.drain()  # idempotent; returns the still-open rids
+        assert open_rids and set(open_rids).isdisjoint(finished_on_a)
+        state = replay_journal(jdir)
+        assert state.drained
+        assert [e.rid for e in state.open_requests] == open_rids
+
+        monkeypatch.delenv("ACCELERATE_FAULT_PLAN")
+        b_svc = replica(jdir)
+        resumed = b_svc.resume_from_journal()
+        assert resumed == open_rids
+        _run_all(b_svc)
+        got = _outputs(b_svc)
+        # zero lost: A's completions + B's recoveries cover every submission
+        assert sorted(list(finished_on_a) + list(got)) == sorted(want)
+        for rid in got:
+            np.testing.assert_array_equal(got[rid], want[rid])
+        return a, b_svc
+
+    a, b_svc = preempt_and_resume(str(tmp_path / "j"))
+    if aot_store:
+        built = a.watcher.compiles_total + b_svc.watcher.compiles_total
+        stored = len(list((tmp_path / "aot").glob("*-*.pkl")))
+        assert 1 <= stored <= built  # under `built` only where XLA:CPU refused to serialize one
+        a2, b2 = preempt_and_resume(str(tmp_path / "j2"))
+        assert a2._aot.warmed == stored
+        assert a2.watcher.compiles_total + b2.watcher.compiles_total <= built - stored
+        assert a2.recompile_events == 0 and b2.recompile_events == 0
 
 
 def test_drain_stops_admission(tiny_model):

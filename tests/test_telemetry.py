@@ -534,6 +534,45 @@ def test_flightrec_collective_seq_and_dump_roundtrip(tmp_path):
     assert json.load(open(explicit))["events_total"] == rec.events_total
 
 
+def test_blackbox_report_names_the_stalled_rank_and_first_divergent_collective(tmp_path):
+    """``tools/blackbox_report.py`` on two ranks' dumps: rank 0's watchdog
+    fired while it was blocked inside its third gather, rank 1 went silent
+    (an injected hang) after its second and dumped on the signal that ended
+    it.  The lowest counter names the stalled rank, the next collective the
+    one that diverged.  (The same story in a real two-process world, where
+    the collective really blocks, is ``make telemetry-smoke``.)"""
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    try:
+        from blackbox_report import find_dumps, load_dump, merge, render
+    finally:
+        sys.path.pop(0)
+
+    blocked, silent = FlightRecorder(capacity=64), FlightRecorder(capacity=64)
+    for _ in range(3):
+        blocked.note_collective("gather_object", world=2)
+    for _ in range(2):
+        silent.note_collective("gather_object", world=2)
+    silent.record("hang_injected", step=2, seconds=600)
+    blocked.dump(str(tmp_path), reason="watchdog_stall",
+                 extra={"rank": 0, "stalled_label": "collective:gather_object #3"})
+    silent.dump(str(tmp_path), reason="signal", extra={"rank": 1})
+    (tmp_path / "blackbox_rank2.json").write_text("{not a dump")
+
+    paths = find_dumps([str(tmp_path)])
+    assert [os.path.basename(p) for p in paths] == [f"blackbox_rank{i}.json" for i in range(3)]
+    dumps = [d for d in map(load_dump, paths) if d is not None]
+    assert len(dumps) == 2  # the torn file is left out, not fatal
+    report = merge(dumps)
+    assert report["stalled_ranks"] == [1] and not report["aligned"]
+    assert report["first_divergent_seq"] == 3 and report["first_divergent_op"] == "gather_object"
+    ranks = {r["rank"]: r for r in report["ranks"]}
+    assert ranks[0]["reason"] == "watchdog_stall" and ranks[0]["blocked_in"] == {"op": "gather_object", "seq": 3}
+    assert ranks[1]["reason"] == "signal" and ranks[1]["hang_injected"]["step"] == 2
+    assert "STALLED rank(s): 1" in render(report)
+    # ranks at one counter: nothing diverged
+    assert merge([dumps[0], dict(dumps[0], rank=1)])["stalled_ranks"] == []
+
+
 def test_flightrec_disabled_is_noop():
     rec = FlightRecorder(capacity=32, enabled=False)
     rec.record("tick")

@@ -27,7 +27,7 @@ def _fresh():
 
 
 # single source of truth for per-replica residency accounting (also used by
-# tests/test_zero1.py and bench.py)
+# tests/test_zero1.py)
 from accelerate_tpu.utils.memory import opt_state_bytes_per_replica as _per_device_opt_bytes  # noqa: E402
 
 

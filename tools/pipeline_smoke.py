@@ -1,27 +1,17 @@
 #!/usr/bin/env python
-"""pipeline_smoke — `make pipeline-smoke`: prove the resolved ParallelPlan
-and the interleaved 1F1B pipeline end-to-end on CPU in seconds
-(docs/parallel_plan.md, ISSUE 15 acceptance).
+"""pipeline_smoke — `make pipeline-smoke`: the per-stage captured programs
+round-trip the AOT store across two FRESH subprocesses (docs/parallel_plan.md,
+docs/aot_cache.md): the cold leg compiles and stores every ``(stage, chunk,
+role)`` program of a 2-stage, V=2 interleaved 1F1B step, the warm leg loads
+every one off disk with ZERO compiles at a bitwise-equal loss.
 
-2-stage × dp=2 on the virtual 4-device mesh, interleaved schedule (V=2),
-ZeRO-1 + int8 compression + gradient accumulation in ONE captured step.
-Exit 0 requires:
-
-* the plan resolves the acceptance geometry (pp=2, dp=2, zero1 armed,
-  int8 compression, schedule=interleaved, V=2) and IS what consumers see
-  (``current_plan()``);
-* the composed run trains within 1e-3 loss parity of the dp-only run on
-  the same data/seed, and both replay with zero steady-state recompiles
-  (no builds after the two accumulation variants);
-* the interleaved schedule's analytic bubble profile is strictly better
-  than the fused one (bubble_ticks and bubble_fraction at V=2);
-* interleaved-vs-fused training parity holds (same trajectory);
-* (ISSUE 17) the committed layout's lowering contains NO stacked-layer
-  gather while the legacy gather layout's does
-  (``native.kernels.inspect.check_pipeline_layout``), and the per-stage
-  captured programs round-trip the AOT store across two FRESH
-  subprocesses: the warm leg loads every ``(stage, chunk, role)``
-  program off disk with ZERO trace/compile at a bitwise-equal loss.
+The legs run on one device each: ``StagewisePrograms.program`` loads a stored
+executable onto every device of the backend, so in a process with a virtual
+mesh (the test suite's 8 devices) the load of a one-device program fails its
+first dispatch, and no tier-1 test can hold this yet (ROADMAP D9).  What the
+rest of the plan proves — the acceptance geometry, loss parity, bubbles, the
+committed layout's lowering — is in tests/test_parallel_plan.py,
+tests/test_1f1b.py and tests/test_pipeline.py.
 """
 
 import json
@@ -31,67 +21,8 @@ import sys
 import tempfile
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-
-
-def _train(pp: int, schedule: str = "interleaved", micro_steps: int = 6):
-    import dataclasses
-
-    import jax.numpy as jnp
-    import numpy as np
-
-    import accelerate_tpu.nn as nn
-    import accelerate_tpu.optim as optim
-    from accelerate_tpu import Accelerator, CompressionKwargs, ParallelismConfig, TelemetryKwargs
-    from accelerate_tpu.data_loader import batch_to_global_array
-    from accelerate_tpu.models import GPTConfig, PipelinedGPTLMHeadModel
-    from accelerate_tpu.utils.dataclasses import PipelineParallelPlugin
-
-    Accelerator._reset_state()
-    nn.manual_seed(0)
-    kwargs = dict(
-        mixed_precision="no",
-        gradient_accumulation_steps=2,
-        kwargs_handlers=[
-            TelemetryKwargs(enabled=True),
-            CompressionKwargs(policy="int8"),
-        ],
-    )
-    if pp > 1:
-        acc = Accelerator(
-            parallelism_config=ParallelismConfig(pp_size=pp),
-            pp_plugin=PipelineParallelPlugin(
-                pp_size=pp, num_microbatches=8, schedule=schedule
-            ),
-            **kwargs,
-        )
-    else:
-        acc = Accelerator(**kwargs)
-    cfg = dataclasses.replace(GPTConfig.tiny(), n_layer=4)
-    model = PipelinedGPTLMHeadModel(cfg, num_microbatches=8)
-    opt = optim.SGD(model.parameters(), lr=0.1)
-    model, opt = acc.prepare(model, opt)
-
-    def step_fn(ids):
-        with acc.accumulate(model):
-            opt.zero_grad()
-            out = model(ids, labels=ids)
-            acc.backward(out["loss"])
-            opt.step()
-        return out["loss"]
-
-    step = acc.compile_step(step_fn)
-    rng = np.random.default_rng(0)
-    losses = []
-    for _ in range(micro_steps):
-        ids = batch_to_global_array(
-            jnp.asarray(rng.integers(0, 1024, (64, 32)), jnp.int32),
-            mesh=acc.mesh,
-        )
-        losses.append(float(step(ids)))
-    return acc, step, losses
 
 
 def _stagewise_leg(cache_dir: str, out_path: str) -> None:
@@ -175,65 +106,7 @@ def _run_stagewise_leg(cache_dir: str, label: str) -> dict:
 
 
 def main() -> int:
-    from accelerate_tpu.parallel.pipeline import bubble_fraction, bubble_ticks
-    from accelerate_tpu.parallel.plan import current_plan
-
     failures = []
-
-    acc_pp, step_pp, losses_pp = _train(pp=2)
-    plan = acc_pp.plan
-    if plan is not current_plan():
-        failures.append("accelerator.plan is not the published current_plan()")
-    geometry = (plan.pp, plan.dp, plan.zero1, plan.compression,
-                plan.stage.schedule, plan.stage.virtual)
-    expected = (2, 2, True, "int8", "interleaved", 2)
-    if geometry != expected:
-        failures.append(f"plan resolved {geometry}, expected {expected}")
-
-    acc_dp, step_dp, losses_dp = _train(pp=1)
-    diffs = [abs(a - b) for a, b in zip(losses_pp, losses_dp)]
-    if max(diffs) > 1e-3:
-        failures.append(f"loss parity vs dp-only broken: {diffs}")
-
-    for name, acc, step in (("pp2", acc_pp, step_pp), ("dp", acc_dp, step_dp)):
-        records = acc.telemetry.timeline.records()
-        late_builds = [r.step for r in records[2:] if r.built]
-        if late_builds:
-            failures.append(f"{name}: steady-state recompiles at {late_builds}")
-        if len(step._cache) != 2:
-            failures.append(
-                f"{name}: {len(step._cache)} compiled variants (want the 2 "
-                "accumulation variants)"
-            )
-
-    fused_b = bubble_ticks(8, 2, 1, granularity=2)
-    inter_b = bubble_ticks(8, 2, 2, granularity=2)
-    if not inter_b < fused_b:
-        failures.append(f"bubble ticks not reduced: {inter_b} vs {fused_b}")
-    if not bubble_fraction(8, 2, 2) < bubble_fraction(8, 2, 1):
-        failures.append("bubble fraction not reduced at V=2")
-
-    _, _, losses_f = _train(pp=2, schedule="1f1b")
-    fdiffs = [abs(a - b) for a, b in zip(losses_pp, losses_f)]
-    if max(fdiffs) > 1e-4:
-        failures.append(f"interleaved vs fused trajectory diverged: {fdiffs}")
-
-    # the committed layout resolved as the layout of record (default V>1)
-    if plan.layer_layout != "committed":
-        failures.append(
-            f"interleaved plan resolved layer_layout={plan.layer_layout!r}, "
-            "expected the committed default"
-        )
-
-    # zero permutation bytes, proven structurally: no gather op / no layer-
-    # order index vector in the committed lowering, both in the gather arm's
-    ir_facts = {}
-    try:
-        from accelerate_tpu.native.kernels.inspect import check_pipeline_layout
-
-        ir_facts = check_pipeline_layout()
-    except AssertionError as exc:
-        failures.append(f"layout IR inspection: {exc}")
 
     # per-stage captured programs round-trip the AOT store across fresh
     # processes: cold compiles+stores all 2·S·V programs, warm loads every
@@ -260,13 +133,8 @@ def main() -> int:
         )
 
     print(
-        f"pipeline_smoke: plan {plan.describe()} | losses pp2={losses_pp[-1]:.4f} "
-        f"dp={losses_dp[-1]:.4f} (max diff {max(diffs):.2e}) | bubble "
-        f"{fused_b}->{inter_b} ticks | layout IR gather ops "
-        f"{ir_facts.get('gather_gather_ops')}->"
-        f"{ir_facts.get('committed_gather_ops')} | stagewise warm "
-        f"{warm['loaded']}/{warm['programs']} programs from store, "
-        f"{warm['compiled']} compiles"
+        f"pipeline_smoke: stagewise warm {warm['loaded']}/{warm['programs']} "
+        f"programs from store, {warm['compiled']} compiles"
     )
     for failure in failures:
         print(f"pipeline_smoke: FAIL: {failure}", file=sys.stderr)

@@ -1,30 +1,22 @@
 #!/usr/bin/env python
-"""telemetry_smoke — `make telemetry-smoke`: prove the telemetry pipeline
-end-to-end on CPU in seconds.
+"""telemetry_smoke — `make telemetry-smoke`: the one leg of the telemetry
+pipeline that needs two real processes.
 
-Two legs:
+A REAL 2-rank ``jax.distributed`` gloo/CPU world where rank 1's fault
+injector sleeps (``hang:step=2``) before its third ``gather_object``: rank 0
+blocks inside the collective, its hang watchdog fires on the stall deadline
+and writes ``blackbox_rank0.json``; a SIGTERM to the sleeping rank 1
+exercises the watchdog's fatal-signal dump path; then
+tools/blackbox_report.py must merge the dumps and name the stalled rank (1)
+and the first divergent collective (#3, gather_object).
 
-1. **Single-process pipeline** — tiny model, 4 captured steps with
-   telemetry + per-step profiling + Chrome trace export on, full JSONL
-   export, then schema validation through tools/telemetry_report.py and
-   structural validation of the exported trace
-   (``telemetry.trace_export.validate_trace``): the host-phase, device-op
-   and flight-event tracks must all carry events for the same steps, and
-   the always-on flight recorder must have recorded every step.
+Everything one process can show is in tests/test_telemetry.py: the watchdog
+on a stalled section, the recorder's ring and dumps, the report's merge on
+two ranks' dumps, the JSONL schema and the exported trace's three tracks.
 
-2. **Two-process injected hang** — a REAL 2-rank ``jax.distributed``
-   gloo/CPU world where rank 1's fault injector sleeps
-   (``hang:step=2``) before its third ``gather_object``: rank 0 blocks
-   inside the collective, its hang watchdog fires on the stall deadline
-   and writes ``blackbox_rank0.json``; a SIGTERM to the sleeping rank 1
-   exercises the watchdog's fatal-signal dump path; then
-   tools/blackbox_report.py must merge the dumps and name the stalled
-   rank (1) and the first divergent collective (#3, gather_object).
-
-Exit 0 = both legs pass.
+Exit 0 = the leg passes.
 """
 
-import json
 import os
 import signal
 import socket
@@ -38,113 +30,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-
-
-def _pipeline_leg() -> list[str]:
-    """Leg 1: the single-process telemetry pipeline + trace export."""
-    import numpy as np
-    import jax.numpy as jnp
-
-    import accelerate_tpu.nn as nn
-    import accelerate_tpu.optim as optim
-    from accelerate_tpu import Accelerator, TelemetryKwargs
-    from accelerate_tpu.data_loader import batch_to_global_array
-    from accelerate_tpu.models import GPTConfig, GPTLMHeadModel
-    from accelerate_tpu.telemetry.trace_export import validate_trace
-
-    from telemetry_report import load_records, validate
-
-    tmp = tempfile.mkdtemp(prefix="atpu_telemetry_")
-    path = os.path.join(tmp, "run.jsonl")
-    trace_path = os.path.join(tmp, "trace.json")
-    nn.manual_seed(0)
-    acc = Accelerator(
-        kwargs_handlers=[
-            TelemetryKwargs(
-                enabled=True, jsonl_path=path,
-                profile_every_n=1,  # every step sampled → device-op track
-                trace_export_path=trace_path,
-            )
-        ]
-    )
-    model = GPTLMHeadModel(
-        GPTConfig(vocab_size=256, n_positions=64, n_embd=32, n_layer=1, n_head=2)
-    )
-    opt = optim.AdamW(model.parameters(), lr=1e-3)
-    model, opt = acc.prepare(model, opt)
-
-    def step_fn(ids):
-        opt.zero_grad()
-        out = model(ids, labels=ids)
-        acc.backward(out["loss"])
-        opt.step()
-        return out["loss"]
-
-    step = acc.compile_step(step_fn)
-    rng = np.random.default_rng(0)
-
-    def batch(seq):
-        ids = rng.integers(0, 256, (4, seq), dtype=np.int32)
-        return batch_to_global_array(jnp.asarray(ids), mesh=acc.mesh)
-
-    for _ in range(3):
-        loss = step(batch(32))
-    float(loss)
-    step(batch(48))  # forced shape change → recompile event with a cause
-    health = acc.telemetry.flightrec.health()
-    acc.end_training()  # writes the JSONL dump + the Chrome trace
-
-    records = load_records(path)
-    errors = validate(records, min_steps=4)
-    builds = [r for r in records if r.get("kind") == "step" and r.get("built")]
-    if not any(r["trace_ms"] > 0 and r["compile_ms"] > 0 for r in builds):
-        errors.append("no build step with nonzero trace/compile time")
-    recompiles = [r for r in records if r.get("kind") == "recompile"]
-    if not any("arg[0] shape changed" in (r.get("cause") or "") for r in recompiles):
-        errors.append(f"shape-change recompile cause missing: {recompiles}")
-
-    # the always-on flight recorder saw every captured step and is healthy
-    if health["events_total"] < 8:  # >= 4 step_begin/step_end pairs
-        errors.append(f"flight recorder too quiet: {health}")
-    if health["dropped_total"] != 0:
-        errors.append(f"flight recorder dropped events: {health}")
-
-    # the exported Chrome trace is well-formed and carries host-phase,
-    # device-op and flight-event tracks for the SAME steps
-    try:
-        with open(trace_path, encoding="utf-8") as f:
-            doc = json.load(f)
-    except (OSError, ValueError) as e:
-        doc = None
-        errors.append(f"trace export unreadable: {e}")
-    if doc is not None:
-        errors.extend(validate_trace(doc))
-        host_steps, device_steps, flight_steps = set(), set(), set()
-        for ev in doc.get("traceEvents", []):
-            step_arg = (ev.get("args") or {}).get("step")
-            if step_arg is None:
-                continue
-            if ev.get("tid") == 1 and ev.get("ph") == "X":
-                host_steps.add(step_arg)
-            elif ev.get("tid") == 2 and ev.get("ph") == "X":
-                device_steps.add(step_arg)
-            elif ev.get("tid") == 3:
-                flight_steps.add(step_arg)
-        common = host_steps & device_steps & flight_steps
-        if len(common) < 4:
-            errors.append(
-                "trace tracks do not share steps: host="
-                f"{sorted(host_steps)} device={sorted(device_steps)} "
-                f"flight={sorted(flight_steps)}"
-            )
-    if not errors:
-        steps = [r for r in records if r.get("kind") == "step"]
-        print(
-            f"telemetry-smoke: pipeline ok — {len(steps)} steps, "
-            f"{len(builds)} builds, {len(recompiles)} recompile event(s), "
-            f"{health['events_total']} flight events, trace at {trace_path}"
-        )
-    return errors
 
 
 _HANG_WORKER = textwrap.dedent(
@@ -211,8 +96,8 @@ def _wait_for(path: str, timeout_s: float) -> bool:
 
 
 def _hang_leg() -> list[str]:
-    """Leg 2: injected hang in a real 2-process world → watchdog dumps →
-    merged blackbox report names the stalled rank and collective."""
+    """Injected hang in a real 2-process world → watchdog dumps → merged
+    blackbox report names the stalled rank and collective."""
     from blackbox_report import load_dump, merge
 
     errors: list[str] = []
@@ -287,14 +172,10 @@ def _hang_leg() -> list[str]:
 
 
 def main() -> int:
-    errors = _pipeline_leg()
-    errors += _hang_leg()
+    errors = _hang_leg()
     for error in errors:
         print(f"telemetry-smoke: FAIL: {error}", file=sys.stderr)
-    if errors:
-        return 1
-    print("telemetry-smoke: ok — pipeline + injected-hang legs passed")
-    return 0
+    return 1 if errors else 0
 
 
 if __name__ == "__main__":
