@@ -405,12 +405,45 @@ class _GPTDecodeCfg:
     eps: float
 
 
+# The decode family reads every weight once, by the product that uses it, in
+# the layout in which it lies on the device (docs/serving.md §weights are read
+# where they lie).  A TPU lays an array out compactly: a matrix whose last
+# dimension is off the 128 lanes (GPT-2-XL's 1600) lies with its OTHER
+# dimension on the lanes — the layout ``h @ W.T`` wants.  Two things make the
+# compiler re-lay a weight every step all the same, and both are stated
+# otherwise here (held by tests/test_tpu_compile.py).
+
 def _dec_embed(g, ids, positions, cfg):
-    return g["wte"][ids] + g["wpe"][positions][None]
+    wte = g["wte"]
+    if wte.shape[1] % 128 == 0:
+        tok = wte[ids]  # rows lie contiguous: a gather takes them as they are
+    else:
+        # the table lies with the vocabulary on the lanes, as the tied head's
+        # product reads it, and a gather of rows makes the compiler copy the
+        # whole table to row-major first, every step (161 MB at GPT-2-XL).  A
+        # one-hot product reads it where it lies, and is exact: one term of
+        # each sum is not zero (HIGHEST keeps a float32 table's rows whole)
+        hot = jax.nn.one_hot(ids, wte.shape[0], dtype=wte.dtype)
+        tok = jnp.einsum("bsv,vc->bsc", hot, wte, precision=jax.lax.Precision.HIGHEST)
+    return tok + g["wpe"][positions][None]
 
 
 def _dec_attn_in(l, x, positions, cfg):
-    return gpt_attn_in(l, x, n_head=cfg.n_head, eps=cfg.eps)
+    """``gpt_attn_in``'s mathematics, the fused product's ``(b, s, 3c)``
+    RESULT held two-dimensional behind a barrier before it is split into
+    heads.  Without it the compiler folds the split into the product, which
+    then wants the weight as ``(3, h, d, c)`` with ``c`` on the lanes: a slice
+    and a copy of the layer's whole qkv weight, every layer, every step (3.1 of
+    an 8.5 ms GPT-2-XL step); the split of a 16 x 4800 activation is free.
+    Cutting q, k and v out of the result with no barrier cures one chip's
+    program too, but on a mesh GSPMD then shards the k/v rows and all-gathers
+    the replicated pools after every write (tests/test_tpu_compile.py holds
+    both)."""
+    b, s, c = x.shape
+    h = _pure_layernorm(x, l["ln1_w"], l["ln1_b"], cfg.eps)
+    qkv = jax.lax.optimization_barrier(h @ l["qkv_w"].T + l["qkv_b"])
+    qkv = qkv.reshape(b, s, 3, cfg.n_head, cfg.head_dim).transpose(2, 0, 3, 1, 4)
+    return qkv[0], qkv[1], qkv[2]
 
 
 def _dec_attn_out(l, x, att, cfg):

@@ -87,6 +87,19 @@ writers pad a token's row to the page's lanes only where the page has pad
 lanes (``_pad_lanes``), so a family whose ``n_kv·d`` is a multiple of 128
 traces the same writes either way.
 
+**Weights are read where they lie** (docs/serving.md has the section).  The
+layer scan hands a family's ``attn_in`` / ``attn_out`` layer ``l``'s slice of
+each stack, and the programs are meant to read it once, by the product that
+uses it, in the layout in which it lies on the device: compact, which at a
+width off the 128 lanes (GPT-2-XL's 1600) puts a weight's OTHER dimension on
+the lanes, as ``h @ W.T`` wants it.  What breaks that is in a family's own
+functions, not here: a head split folded into a fused product re-lays the
+layer's whole weight (3.1 of an 8.5 ms GPT-2-XL step), a gather of rows from a
+table that lies across the lanes copies the table (0.5 ms).  GPT-2's decode
+family states both otherwise (``models/gpt.py``); tests/test_tpu_compile.py
+holds that no ``copy`` or ``transpose`` of a weight's size is left in either
+program, and that every weight-sized slice lies as its stack does.
+
 Zero-recompile forensics: the scheduler routes every call through
 :class:`CompileWatcher`, which diffs the jit cache size around the call.
 First compiles of a not-yet-seen signature are warmup; any growth on a seen

@@ -226,30 +226,37 @@ def test_pool_pages_hold_generates_contiguous_cache(make_model):
     service.pool.check_no_leaks()
 
 
+def _tap_logits(service) -> list:
+    """Every ``(rows, V)`` logits array the service's programs compute from
+    here on, float32, in order: its family's ``finalize`` with a host callback
+    behind it."""
+    import dataclasses
+
+    import jax
+
+    seen, family = [], service.spec.family
+
+    def finalize(g, x, cfg):
+        logits = family.finalize(g, x, cfg)
+        jax.debug.callback(lambda a: seen.append(np.asarray(a, np.float32)), logits, ordered=True)
+        return logits
+
+    service.spec = dataclasses.replace(
+        service.spec, family=dataclasses.replace(family, finalize=finalize)
+    )
+    return seen
+
+
 def test_decode_logits_agree_with_a_full_forward_to_float32_summation_order(tiny_model):
     """What holds between the two engines now that decode attention sums in
     chunks of pages: the same greedy tokens as ``generate()`` on this model,
     and decode logits equal to a full forward's at the same positions to
     float32 rounding — not bitwise.  Requests long enough to cross a chunk's
     edge (128 tokens), batched with short ones."""
-    import dataclasses
-
-    import jax
-
     from accelerate_tpu.nn import Tensor, no_grad
 
-    seen = []
     service = DecodeService(tiny_model, ServingConfig(max_slots=3, block_size=16, prompt_bucket=16))
-    family = service.spec.family
-
-    def finalize(g, x, cfg):
-        logits = family.finalize(g, x, cfg)
-        jax.debug.callback(lambda a: seen.append(np.asarray(a)), logits, ordered=True)
-        return logits
-
-    service.spec = dataclasses.replace(
-        service.spec, family=dataclasses.replace(family, finalize=finalize)
-    )
+    seen = _tap_logits(service)
     prompts, new = _prompts([120, 7, 60], seed=11), 12
     rids = [service.submit(p, max_new_tokens=new) for p in prompts]
     service.step()  # admits all three (a prefill each), then decodes once
@@ -268,6 +275,54 @@ def test_decode_logits_agree_with_a_full_forward_to_float32_summation_order(tiny
         want = full[len(prompt): len(prompt) + new - 1]
         got = np.stack([lg[slot_of[rid]] for lg in decodes])
         np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("decode_steps", [1, 8])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_width_off_the_lanes_serves_what_generate_does(dtype, decode_steps):
+    """GPT-2 at a width that is not a multiple of 128, with heads of 64 (320 =
+    5 x 64, as GPT-2-XL's 1600 = 25 x 64): the decode family's embed is the
+    one-hot product over the table as it lies and its qkv product stays
+    two-dimensional until its result is cut (docs/serving.md §weights are read
+    where they lie).  Served greedy tokens equal ``generate()``'s, the decode
+    logits a full forward's to float32 summation order (to bfloat16's rounding
+    in bfloat16), 0 recompiles."""
+    import jax.numpy as jnp
+
+    from accelerate_tpu.nn import Tensor, no_grad
+
+    # seed 2: in bfloat16 the two engines' logits differ by one rounding step
+    # (0.016) and this model's narrowest top-2 gap is 0.10; of 14 seeds tried
+    # one holds an exact tie, which either engine may break its own way
+    nn.manual_seed(2)
+    model = GPTLMHeadModel(
+        GPTConfig(vocab_size=512, n_positions=256, n_embd=320, n_layer=2, n_head=5)
+    ).eval()
+    for p in model.parameters():
+        p.data = p.data.astype(jnp.dtype(dtype))
+    service = DecodeService(model, ServingConfig(
+        max_slots=3, block_size=16, prompt_bucket=16, decode_steps=decode_steps))
+    assert service.spec.cfg.head_dim == 64
+    seen = _tap_logits(service)
+    prompts, new = _prompts([120, 7, 60], vocab=512, seed=5), 12
+    rids = [service.submit(p, max_new_tokens=new) for p in prompts]
+    service.step()  # admits all three (a prefill each), then decodes
+    service.run()
+    assert service.recompile_events == 0
+    service.pool.check_no_leaks()
+    decodes = [lg for lg in seen if lg.shape[0] == 3][: new - 1]  # micro-steps past the budget aside
+    assert len(decodes) == new - 1
+    tol = 2e-5 if dtype == "float32" else 6e-2
+    for slot, (rid, prompt) in enumerate(zip(rids, prompts)):  # admitted in order into slots 0, 1, 2
+        got_ids = service.results[rid].output_ids
+        np.testing.assert_array_equal(
+            got_ids, np.asarray(model.generate(prompt[None], max_new_tokens=new))[0]
+        )
+        with no_grad():
+            full = np.asarray(model(Tensor(got_ids[None]))["logits"].data, np.float32)[0]
+        # decode step j fed the token at position len(prompt) + j
+        want = full[len(prompt): len(prompt) + new - 1]
+        np.testing.assert_allclose(np.stack([lg[slot] for lg in decodes]), want, rtol=tol, atol=tol)
 
 
 def test_kv_pages_walked_over_tabled_reads_what_the_lengths_imply(tiny_model):

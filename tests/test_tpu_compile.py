@@ -363,6 +363,51 @@ def test_serving_programs_move_no_pool_sized_buffer(xl_serving_programs, program
         assert span_sized <= {(2, 513, 16, 1664), (2 * 513, 16, 1664)}, span_sized  # the pools alone
 
 
+def _as_it_lies(dims, minor_to_major, drop=()):
+    """``(sizes, order)`` of a shape without its dimensions of size 1 (and
+    those in ``drop``): the sizes that are left, and the order in which they
+    lie, minor first.  A layer's ``[1, 4800, 1600]{1,2,0}`` slice and the
+    ``[4800, 1600]{0,1}`` bitcast of it read ``((4800, 1600), (0, 1))`` both."""
+    keep = [i for i, d in enumerate(dims) if d != 1 and i not in drop]
+    return tuple(dims[i] for i in keep), tuple(keep.index(i) for i in minor_to_major if i in keep)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_serving_programs_read_every_weight_where_it_lies(xl_serving_programs, program):
+    """At a width off the 128 lanes (1600) the device's compact layout puts a
+    weight's OTHER dimension on the lanes, which is what ``h @ W.T`` wants: in
+    a scanned plan's programs every weight is read once, by the product that
+    uses it, as it lies.  No ``copy`` or ``transpose`` of 1600 x 1600 elements
+    or more is left, and every ``dynamic-slice`` or fusion result of that size
+    is a pool, or a layer's weight (or the token table) in the order its stack
+    lies in.  (The head split folded into the fused qkv product re-laid 15 MB a
+    layer, ``copy.23`` / ``copy.27``, and the embed's gather the whole table,
+    ``copy.14`` / ``copy.17``: 3.6 of GPT-2-XL's 8.5 ms decode step, PERF.md
+    PR 35.)"""
+    from accelerate_tpu.telemetry.profiler import instructions_of_size
+
+    programs, _ = xl_serving_programs
+    text = programs[program].as_text()
+    weight = 1600 * 1600
+    assert instructions_of_size(text, ("copy", "transpose"), weight) == []
+
+    header = text.split("\n", 1)[0]
+    takes = header[header.index("entry_computation_layout"):].split("->")[0]
+    lies = {}  # a weight's sizes -> the order they lie in, the layer dimension aside
+    for dims, order in re.findall(r"bf16\[([\d,]+)\]\{([\d,]+)", takes):
+        dims, order = [int(d) for d in dims.split(",")], [int(i) for i in order.split(",")]
+        if len(dims) in (2, 3) and np.prod(dims[-2:]) >= weight:
+            sizes, lie = _as_it_lies(dims, order, drop=(0,) if len(dims) == 3 else ())
+            lies[sizes] = lie
+    assert set(lies) == {(2048, 1600), (4800, 1600), (1600, 1600), (6400, 1600), (1600, 6400)}
+    for name, _, dims in instructions_of_size(text, ("dynamic-slice", "fusion"), weight):
+        if dims[-2:] == (16, 1664):
+            continue  # a pool's page rows: test_serving_programs_move_no_pool_sized_buffer
+        order = re.search(rf"%?{re.escape(name)} = \w+\[[\d,]*\]\{{([\d,]+)", text).group(1)
+        sizes, lie = _as_it_lies(dims, [int(i) for i in order.split(",")])
+        assert lies.get(sizes) == lie, (name, dims, order)
+
+
 def test_a_plan_of_attention_layers_keeps_the_scan_and_takes_no_state(xl_serving_programs):
     """GPT-2's family is the plan "attention x L" (``layer_plan`` gives
     ``None``): its programs scan the one stack of layers and take no
