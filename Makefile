@@ -96,7 +96,7 @@ test_models:
 	  tests/test_llama_rope_scaling.py tests/test_chunked_ce.py \
 	  tests/test_opt.py tests/test_gptj_neox.py tests/test_t5.py \
 	  tests/test_generation.py tests/test_quantized_decode.py \
-	  tests/test_moe.py tests/test_nemotron_h.py \
+	  tests/test_moe.py tests/test_nemotron_h.py tests/test_olmo_hybrid.py \
 	  tests/test_torch_bridge.py tests/test_nn.py -q
 
 test_parallel:

@@ -39,7 +39,7 @@ logger = get_logger(__name__)
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
-ATTENTION, MAMBA2, EXPERTS = "attention", "mamba2", "experts"
+ATTENTION, RECURRENT, EXPERTS = "attention", "recurrent", "experts"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,25 +60,35 @@ class DecoderFamily:
       position of ``x: (b, s, c)``
 
     **The layer plan.**  ``plan(cfg)`` gives the kind of every layer in
-    order (``ATTENTION``, ``MAMBA2``, ``EXPERTS``); ``None`` is the plan
+    order (``ATTENTION``, ``RECURRENT``, ``EXPERTS``); ``None`` is the plan
     "attention × L", which is every family that has only the four hooks
     above.  A layer of kind ``ATTENTION`` is ``attn_in`` / cached attention /
     ``attn_out``; the other kinds bring their own hooks, each a whole layer
-    (pre-norm and residual included):
+    (norms, residuals and any MLP of its own included):
 
-    - ``mamba_prefill(l, x, true_len, cfg) -> (x, state, tail)`` — one
-      bucket-padded sequence ``x: (1, s, c)`` from a zero state; positions at
-      or past ``true_len`` must not move the state, and ``tail`` is what the
-      next token's convolution reads (the last true rows)
-    - ``mamba_step(l, x, state, tail, cfg) -> (x, state, tail)`` — one token
-      for every slot, ``x: (slots, 1, c)``; slots never mix
+    - ``recurrent_prefill(l, x, true_len, cfg) -> (x, state, tail)`` — a layer
+      with a per-slot state and a convolution tail (Mamba-2, the gated delta
+      rule): one bucket-padded sequence ``x: (1, s, c)`` from a zero state;
+      positions at or past ``true_len`` must not move the state, and ``tail``
+      is what the next token's convolution reads (the last true rows).  The
+      state's shape is the family's own: the service sizes its pool from what
+      this returns
+    - ``recurrent_step(l, x, state, tail, cfg) -> (x, state, tail)`` — one
+      token for every slot, ``x: (slots, 1, c)``; slots never mix
+    - ``recurrent_scopes`` — the two ``jax.named_scope`` names under which the
+      engine writes a layer's rows of the state pool, in prefill and in decode:
+      the family's own scan and step scopes, so that a trace counts the write
+      with the recurrence it belongs to
     - ``ffn(l, x, valid, cfg) -> (x, load)`` — a token-wise layer (sparse
       experts); ``valid: (b, s)`` marks the tokens that count, ``load`` is a
       small int vector the engine sums over the layers and hands the host
 
-    With a mixed plan the layers are a tuple of per-layer dicts in plan
-    order, each layer's weights arrays of their own: the plan is unrolled, and
-    a static slice of a stack that feeds a kernel would be a copy.
+    With a mixed plan the layers are a tuple of dicts.  One dict a layer in
+    plan order, each layer's weights arrays of their own: the plan is
+    unrolled (a static slice of a stack that feeds a kernel would be a copy).
+    Or, where the plan is a whole number of repeats of one period
+    (``plan_period``), one dict a POSITION IN THE PERIOD, every leaf a stack
+    over the repeats: the engine scans the repeats (serving/engine.py).
 
     Declared frozen so the whole family object is a stable static argument
     to ``jax.jit`` (module-level singletons hash by function identity).
@@ -89,8 +99,9 @@ class DecoderFamily:
     attn_out: Callable
     finalize: Callable
     plan: Optional[Callable] = None
-    mamba_prefill: Optional[Callable] = None
-    mamba_step: Optional[Callable] = None
+    recurrent_prefill: Optional[Callable] = None
+    recurrent_step: Optional[Callable] = None
+    recurrent_scopes: tuple = ()
     ffn: Optional[Callable] = None
 
 
@@ -102,6 +113,21 @@ def layer_plan(family: DecoderFamily, cfg) -> Optional[tuple]:
     if kinds is None or set(kinds) == {ATTENTION}:
         return None
     return kinds
+
+
+def plan_period(kinds: Optional[tuple], n_held: int) -> Optional[int]:
+    """How a mixed plan's layers are walked, from the plan and from how its
+    weights are held: ``p`` where the family holds ``p < L`` stacks and the
+    plan is ``L / p`` repeats of its first ``p`` kinds (scanned by the
+    period), ``None`` where it holds a dict a layer (unrolled)."""
+    if kinds is None or n_held == len(kinds):
+        return None
+    if len(kinds) % n_held or kinds != kinds[:n_held] * (len(kinds) // n_held):
+        raise ValueError(
+            f"a plan of {len(kinds)} layers held as {n_held} stacks must be "
+            f"repeats of its first {n_held} kinds; got {kinds}"
+        )
+    return n_held
 
 
 @dataclasses.dataclass

@@ -35,9 +35,9 @@ import jax.numpy as jnp
 from .. import nn
 from ..nn.moe import held_experts_apply, route_sigmoid_topk, shared_expert_ffn
 from ..ops import ssm
-from .generation import ATTENTION, EXPERTS, MAMBA2
+from .generation import ATTENTION, EXPERTS, RECURRENT
 
-KINDS = {"M": MAMBA2, "*": ATTENTION, "E": EXPERTS}
+KINDS = {"M": RECURRENT, "*": ATTENTION, "E": EXPERTS}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -125,7 +125,7 @@ def layer_shapes(cfg: NemotronHConfig, kind: str) -> dict:
     qd, kvd = cfg.n_head * cfg.head_dim, cfg.n_kv_head * cfg.head_dim
     return {
         "globals": {"embed": (cfg.vocab_size, d), "norm_f": (d,), "head": (cfg.vocab_size, d)},
-        MAMBA2: {
+        RECURRENT: {
             "norm": (d,), "in_w": (d, di + cw + h), "conv_w": (cw, cfg.conv_kernel), "conv_b": (cw,),
             "dt_bias": (h,), "a_log": (h,), "d": (h,), "gate_norm": (di,), "out_w": (di, d),
         },
@@ -336,7 +336,8 @@ def _make_decoder():
     return DecoderFamily(
         embed=_embed, attn_in=_attn_in, attn_out=_attn_out, finalize=_finalize,
         plan=lambda cfg: cfg.kinds,
-        mamba_prefill=mamba_prefill, mamba_step=mamba_step, ffn=experts_ffn,
+        recurrent_prefill=mamba_prefill, recurrent_step=mamba_step,
+        recurrent_scopes=("atpu_serve_ssm_scan", "atpu_serve_ssm_step"), ffn=experts_ffn,
     )
 
 

@@ -1,9 +1,10 @@
 """Paged-attention decode: one slot's live pages, as they lie (docs/kernels.md
 §paged-attention; docs/serving.md §decode attention).
 
-How the decode program of an all-attention layer plan attends
-(``serving/engine.py::_decode_body``, under its layer scan; a mixed plan keeps
-gather + ``cached_attention`` and never imports this module).  The KV pools stay in HBM as the page rows the layer loop carries,
+How the decode program of a scanned layer plan attends
+(``serving/engine.py::_decode_body``: under the layer scan of an all-attention
+plan, or under the scan over the repeats of a mixed plan's period; an unrolled
+mixed plan keeps gather + ``cached_attention`` and never imports this module).  The KV pools stay in HBM as the page rows the layer loop carries,
 ``(L·NB, bs, lanes)``: a page is one lane-dense ``[bs, lanes]`` slab — a
 token's ``n_kv·d`` first, zeros up to whole 128-lane tiles
 (``kv_blocks.page_lanes``) — and layer ``l``'s block ``b`` is row

@@ -25,14 +25,14 @@ import jax.numpy as jnp
 _HI = jax.lax.Precision.HIGHEST
 
 
-def causal_conv(xbc, w, b):
+def causal_conv(xbc, w, b=None):
     """Depthwise causal convolution over time with zero history.
     ``xbc: (T, C)``, ``w: (C, K)`` (tap ``K-1`` meets the current row),
-    ``b: (C,)``; float32 out."""
+    ``b: (C,)`` or ``None`` (no bias); float32 out."""
     t, k = xbc.shape[0], w.shape[1]
     x = jnp.pad(xbc.astype(jnp.float32), ((k - 1, 0), (0, 0)))
     w = w.astype(jnp.float32)
-    out = b.astype(jnp.float32)[None]
+    out = 0.0 if b is None else b.astype(jnp.float32)[None]
     for j in range(k):
         out = out + x[j:j + t] * w[:, j][None]
     return out
@@ -46,13 +46,14 @@ def conv_tail(xbc, true_len, k: int):
     return jax.lax.dynamic_slice_in_dim(x, true_len, k - 1, axis=0)
 
 
-def conv_step(tail, row, w, b):
+def conv_step(tail, row, w, b=None):
     """One token for every slot.  ``tail: (S, K-1, C)`` earlier rows,
-    ``row: (S, C)`` the current one.  Returns (float32 output ``(S, C)``, new
-    tail in ``tail``'s dtype)."""
+    ``row: (S, C)`` the current one, ``b: (C,)`` or ``None``.  Returns (float32
+    output ``(S, C)``, new tail in ``tail``'s dtype)."""
     window = jnp.concatenate([tail, row[:, None].astype(tail.dtype)], axis=1)
-    out = jnp.einsum("skc,ck->sc", window.astype(jnp.float32), w.astype(jnp.float32),
-                     precision=_HI) + b.astype(jnp.float32)[None]
+    out = jnp.einsum("skc,ck->sc", window.astype(jnp.float32), w.astype(jnp.float32), precision=_HI)
+    if b is not None:
+        out = out + b.astype(jnp.float32)[None]
     return out, window[:, 1:]
 
 

@@ -67,22 +67,30 @@ per-step mirror uploads (see ``_decode_jit``).
 
 Both programs walk the family's **layer plan** (``models.generation.layer_plan``;
 docs/serving.md §layer plan).  A plan of attention layers is the scan above
-and nothing else.  A mixed plan (state-space and expert layers among them) is
-unrolled by ``_walk_plan`` over per-layer weights, takes a second donated
-cache — the per-slot state pool of ``kv_blocks.make_state_pool``, updated at
-each layer's rows in place like the KV pool — and returns it with the expert
-layers' summed load beside the tokens.
+and nothing else.  A mixed plan (recurrent and expert layers among them) takes
+a second donated cache — the per-slot state pool of
+``kv_blocks.make_state_pool``, updated at each layer's rows in place like the
+KV pool — and returns it with the expert layers' summed load beside the tokens.
+How it is walked follows from the plan and from how the family holds its
+weights (``models.generation.plan_period``): a dict a layer is unrolled by
+``_walk_plan``; a plan that is a whole number of repeats of one period, held as
+one stack per position in the period, is scanned by ``_scan_periods`` — one
+``lax.scan`` over the repeats whose body walks one period, both pools in the
+carry, each program compiling one period.
 
 **The plan also decides how a decode layer attends** (``decode_layer``), and
-nothing else does — no argument, no policy, no model's name.  Under the scan
-it is the kernel above.  Under ``_walk_plan`` it is what it was: gather the
-slot's whole table row, re-lay it as ``(Hkv, S, d)``, ``cached_attention``
-over all of it.  Few of a mixed plan's layers attend, over few kv heads (3 of
-Nemotron's 26, 2 heads: 0.94 of a 20.7 ms step), and unrolled they would pay
-one kernel lowering each and the Pallas import at every start; so the kernel
-module is imported in the scan's branch at trace time, a process that serves a
-mixed plan never imports ``jax.experimental.pallas``, and its programs lower
-to the text they lowered to before the kernel came (PERF.md, PR 33).  The
+nothing else does — no argument, no policy, no model's name: what is scanned
+takes the kernel above, what is unrolled takes the gather.  Unrolled it is what
+it was: gather the slot's whole table row, re-lay it as ``(Hkv, S, d)``,
+``cached_attention`` over all of it.  Few of Nemotron-H's layers attend, over
+few kv heads (3 of 26, 2 heads: 0.94 of a 20.7 ms step), and unrolled they
+would pay one kernel lowering each and the Pallas import at every start; so the
+kernel module is imported in the scan's branch at trace time, a process that
+serves an unrolled plan never imports ``jax.experimental.pallas``, and its
+programs lower to the text they lowered to before the kernel came (PERF.md, PR
+33).  A period's attention layers sit in a scan's body: one lowering a program,
+and a family with 30 kv heads of 128 could not be served by the gather at all
+(4.5 GB gathered and re-laid a step: PERF.md, PR 36).  The
 writers pad a token's row to the page's lanes only where the page has pad
 lanes (``_pad_lanes``), so a family whose ``n_kv·d`` is a multiple of 128
 traces the same writes either way.
@@ -119,11 +127,12 @@ import jax.numpy as jnp
 
 from ..models.generation import (
     ATTENTION,
-    MAMBA2,
+    RECURRENT,
     DecoderFamily,
     _dequant_layer,
     cached_attention,
     layer_plan,
+    plan_period,
 )
 
 
@@ -147,26 +156,63 @@ def _pad_lanes(rows, pool):
     return jnp.pad(rows, ((0, 0),) * (rows.ndim - 1) + ((0, pad),))
 
 
-def _walk_plan(kinds, layers, x, kp, vp, state, attention, mamba, ffn):
-    """A MIXED layer plan, unrolled: ``layers[j]`` is layer ``j``'s own weights,
-    and the layer does what its kind does to the activations and to its own
-    cache — ``attention(l, x, kp, vp, i)`` the paged pool, ``mamba(l, x, state,
-    i)`` the state pool, ``i`` its rank among its kind; ``ffn(l, x)`` neither.
-    Unrolled and not scanned by runs: the runs of this repo's one mixed family
-    are one or two layers long, 26 layers compile in seconds, and a static
-    rank lets every layer touch its rows of both pools in place.  Returns
-    ``(x, kp, vp, state, load)``, ``load`` the ffn layers' loads summed."""
+def _walk_plan(kinds, layers, x, kp, vp, state, attention, recurrent, ffn, first=None):
+    """The layers of a MIXED plan, one after the other: ``layers[j]`` is layer
+    ``j``'s own weights, and the layer does what its kind does to the
+    activations and to its own cache — ``attention(l, x, kp, vp, i)`` the paged
+    pool, ``recurrent(l, x, state, i)`` the state pool, ``i`` its rank among
+    its kind; ``ffn(l, x)`` neither.  A plan held a dict a layer is walked
+    whole by one call: unrolled, because its runs of one kind are one or two
+    layers long, 26 layers compile in seconds, and a static rank lets every
+    layer touch its rows of both pools in place.  A scanned period
+    (``_scan_periods``) is one call a repeat, ``first[kind]`` the traced rank
+    of the repeat's first layer of that kind.  Returns ``(x, kp, vp, state,
+    load)``, ``load`` the ffn layers' loads summed."""
     seen, load = {}, None
     for kind, l in zip(kinds, layers, strict=True):
         i = seen[kind] = seen.get(kind, -1) + 1
+        if first is not None:
+            i = first[kind] + i
         if kind == ATTENTION:
             x, kp, vp = attention(l, x, kp, vp, i)
-        elif kind == MAMBA2:
-            x, state = mamba(l, x, state, i)
+        elif kind == RECURRENT:
+            x, state = recurrent(l, x, state, i)
         else:
             x, got = ffn(l, x)
             load = got if load is None else load + got
     return x, kp, vp, state, load
+
+
+def _scan_periods(kinds, stacks, x, kp, vp, state, attention, recurrent, ffn):
+    """A mixed plan that is ``L / period`` repeats of its first ``period``
+    kinds, its weights held as one stack per position in the period: ONE
+    ``lax.scan`` over the repeats whose body is ``_walk_plan`` over one period.
+    Both pools are carried whole, as the scan of an all-attention plan carries
+    the KV pool, and each layer touches its own rows of them in place, its rank
+    among its kind counted from the repeat's number.  A program compiles one
+    period, and its attention layers are in a scan: they take the kernel
+    (``decode_layer``)."""
+    per = kinds[: len(stacks)]
+
+    def one_period(carry, layers):
+        x, kp, vp, state, n = carry
+        first = {kind: n * per.count(kind) for kind in set(per)}
+        x, kp, vp, state, load = _walk_plan(
+            per, layers, x, kp, vp, state, attention, recurrent, ffn, first
+        )
+        return (x, kp, vp, state, n + 1), load
+
+    (x, kp, vp, state, _), loads = jax.lax.scan(
+        one_period, (x, kp, vp, state, jnp.int32(0)), stacks
+    )
+    return x, kp, vp, state, None if loads is None else loads.sum(axis=0)
+
+
+def _run_plan(kinds, layers, *rest):
+    """A mixed plan by how its weights are held (``plan_period``): a dict a
+    layer is unrolled, a stack per position in its period is scanned."""
+    scanned = plan_period(kinds, len(layers)) is not None
+    return (_scan_periods if scanned else _walk_plan)(kinds, layers, *rest)
 
 
 @partial(
@@ -234,19 +280,19 @@ def _prefill_jit(
             (x, kp, vp, _), _ = prefill_layer((x, kp, vp, i), (l, {}, {}))
             return x, kp, vp
 
-        def mamba(l, x, state, i):
+        def recurrent(l, x, state, i):
             # from a zero state, whatever the slot held: this write IS the
             # slot's reset.  Padding moves neither the state nor the tail
-            x, ssm, tail = family.mamba_prefill(l, x, prompt_len, cfg)
-            with jax.named_scope("atpu_serve_ssm_scan"):
+            x, new, tail = family.recurrent_prefill(l, x, prompt_len, cfg)
+            with jax.named_scope(family.recurrent_scopes[0]):
                 return x, {
-                    "ssm": state["ssm"].at[i, slot].set(ssm),
+                    "ssm": state["ssm"].at[i, slot].set(new),
                     "conv": state["conv"].at[i, slot].set(tail.astype(state["conv"].dtype)),
                 }
 
         valid = (positions < prompt_len)[None]
-        x, kp, vp, state, load = _walk_plan(
-            kinds, plain_layers, x, kp, vp, state, attention, mamba,
+        x, kp, vp, state, load = _run_plan(
+            kinds, plain_layers, x, kp, vp, state, attention, recurrent,
             lambda l, x: family.ffn(l, x, valid, cfg),
         )
     with jax.named_scope("atpu_serve_head"):
@@ -291,9 +337,10 @@ def _decode_body(
     num_blocks, block_size = pool_shape[1], pool_shape[2]
     plain_layers, q_layers, s_layers = layers
     kp, vp = _page_rows(k_pool), _page_rows(v_pool)
-    # the plan decides how a layer attends (decode_layer): None where every
-    # layer is attention and the layers are scanned
+    # the plan decides how a layer attends (decode_layer): under a scan (every
+    # layer attention, or a mixed plan scanned by its period) the kernel
     kinds = layer_plan(family, cfg)
+    scanned = kinds is None or plan_period(kinds, len(plain_layers)) is not None
 
     # the atpu_serve_* scopes are HLO metadata only (numerics untouched): a
     # device trace is split by them (docs/telemetry.md §spans and scopes)
@@ -329,12 +376,12 @@ def _decode_body(
             v_row = _pad_lanes(v[:, :, 0, :].reshape(-1, n_kv * d), vp)
             vp = vp.at[blk, off].set(v_row.astype(vp.dtype))
 
-        if kinds is None:
-            # the scanned plan: each slot's live pages, read where they lie,
+        if scanned:
+            # a scanned plan: each slot's live pages, read where they lie,
             # under a running softmax — one Mosaic kernel a layer, no gathered
             # span (docs/serving.md §decode attention).  Imported here, at
-            # trace time: a process that serves a mixed plan never pays for
-            # jax.experimental.pallas
+            # trace time: a process that serves an unrolled plan never pays
+            # for jax.experimental.pallas
             from ..native.kernels.paged_attention import paged_attention
 
             with jax.named_scope("atpu_serve_attend"):
@@ -343,10 +390,10 @@ def _decode_body(
                     n_kv=n_kv, mesh=mesh,
                 )[:, :, None, :]  # (slots, H, 1, d)
         else:
-            # a mixed plan's attention layers: gather the slot's whole table
-            # row and attend over it.  Few of its layers attend, over few kv
-            # heads: the kernel would buy 0.75 ms of a 20.7 ms step and cost
-            # a lowering a layer at every start (PERF.md, PR 33).
+            # an unrolled plan's attention layers: gather the slot's whole
+            # table row and attend over it.  Few of its layers attend, over
+            # few kv heads: the kernel would buy 0.75 ms of a 20.7 ms step and
+            # cost a lowering a layer at every start (PERF.md, PR 33).
             # Two vmaps where one would do, so that each phase's scope sits
             # OUTSIDE its vmap: a scope entered inside reads ``vmap(<scope>)``
             # in the op's path and is lost to the map.  Same batched
@@ -393,16 +440,16 @@ def _decode_body(
             (x, kp, vp, _), _ = decode_layer((x, kp, vp, i), (l, {}, {}))
             return x, kp, vp
 
-        def mamba(l, x, state, i):
+        def recurrent(l, x, state, i):
             # every slot's state, read and written at this layer's rows of the
             # carried pool.  A dead slot computes on what it holds: states
             # never mix across slots, and its next prefill writes it whole
-            x, ssm, tail = family.mamba_step(l, x, state["ssm"][i], state["conv"][i], cfg)
-            with jax.named_scope("atpu_serve_ssm_step"):
-                return x, {"ssm": state["ssm"].at[i].set(ssm), "conv": state["conv"].at[i].set(tail)}
+            x, new, tail = family.recurrent_step(l, x, state["ssm"][i], state["conv"][i], cfg)
+            with jax.named_scope(family.recurrent_scopes[1]):
+                return x, {"ssm": state["ssm"].at[i].set(new), "conv": state["conv"].at[i].set(tail)}
 
-        x, kp, vp, state, load = _walk_plan(
-            kinds, plain_layers, x, kp, vp, state, attention, mamba,
+        x, kp, vp, state, load = _run_plan(
+            kinds, plain_layers, x, kp, vp, state, attention, recurrent,
             lambda l, x: family.ffn(l, x, live, cfg),
         )
     with jax.named_scope("atpu_serve_head"):

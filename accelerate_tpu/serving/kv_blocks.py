@@ -202,8 +202,9 @@ def make_pools(n_layers: int, num_blocks: int, n_kv_head: int,
 
 def make_state_pool(n_layers: int, slots: int, state_shape, tail_shape, tail_dtype):
     """The recurrent layers' cache: ``{"ssm": (L, slots, *state_shape) float32,
-    "conv": (L, slots, *tail_shape)}`` — per layer and slot the state-space
-    state (Mamba-2: heads x head size x state size) and the rows the next
+    "conv": (L, slots, *tail_shape)}`` — per recurrent layer and slot the
+    state (Mamba-2: heads x head size x state size; the delta rule: heads x
+    packed k rows x lanes, ``ops/delta_rule.pack_state``) and the rows the next
     token's convolution reads (kernel - 1 rows of the convolved channels).
     Not paged: its size does not grow with the sequence.  Zeros, so that a
     slot nobody has claimed yet computes on finite numbers."""

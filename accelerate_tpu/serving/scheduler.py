@@ -193,7 +193,7 @@ class DecodeService:
 
     def __init__(self, model, config: Optional[ServingConfig] = None, telemetry=None,
                  aot_cache=None, preemption_guard=None):
-        from ..models.generation import ATTENTION, MAMBA2, layer_plan, stacked_params_for_mode
+        from ..models.generation import ATTENTION, RECURRENT, layer_plan, stacked_params_for_mode
 
         self.config = cfg = config or ServingConfig()
         if cfg.block_size < 1 or cfg.max_slots < 1:
@@ -234,7 +234,7 @@ class DecodeService:
             )
         blocks_per_slot = self.capacity // cfg.block_size
         num_blocks = cfg.num_blocks or (cfg.max_slots * blocks_per_slot + 1)
-        n_state_layers = kinds.count(MAMBA2) if kinds is not None else 0
+        n_state_layers = kinds.count(RECURRENT) if kinds is not None else 0
         self.pool = BlockPool(
             num_blocks, cfg.block_size, cfg.max_slots, blocks_per_slot,
             has_state=n_state_layers > 0,
@@ -293,14 +293,19 @@ class DecodeService:
         def _rebuild_state():
             if not n_state_layers:
                 return None
-            first = self._layers[0][kinds.index(MAMBA2)]
-            _, ssm, tail = jax.eval_shape(
-                lambda l: spec.family.mamba_prefill(
+            # the first recurrent layer's weights: a dict of its own, or one row
+            # of its position's stack where the plan is held by its period
+            held = self._layers[0]
+            first = held[kinds.index(RECURRENT)]
+            if len(held) < len(kinds):
+                first = jax.eval_shape(lambda l: jax.tree_util.tree_map(lambda a: a[0], l), first)
+            _, state, tail = jax.eval_shape(
+                lambda l: spec.family.recurrent_prefill(
                     l, jnp.zeros((1, cfg.prompt_bucket, probe.shape[-1]), act_dtype),
                     jnp.int32(1), dcfg,
                 ), first,
             )
-            pool = make_state_pool(n_state_layers, cfg.max_slots, ssm.shape, tail.shape, act_dtype)
+            pool = make_state_pool(n_state_layers, cfg.max_slots, state.shape, tail.shape, act_dtype)
             if self._pool_sharding is not None:
                 pool = jax.device_put(pool, self._pool_sharding)
             return pool

@@ -628,3 +628,121 @@ def test_a_process_that_serves_a_mixed_plan_never_imports_pallas():
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert proc.stdout.strip().splitlines()[-1] == "no pallas"
+
+
+# ---------------------------------------------------------------------------
+# a mixed plan scanned by its period: the kernel, and both caches in place
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def periodic_serving_programs(one_chip):
+    """``_decode_jit`` and the 256-token ``_prefill_jit`` of Olmo-Hybrid compiled
+    for the described v5e at the published widths and the serve-longout cell's
+    pools (48 slots, 3073 blocks of 16, 96 blocks a slot), two periods deep
+    (``[linear, linear, linear, full] x 2``: the scan's body is the same at 4)
+    and 2048 rows of vocabulary."""
+    from accelerate_tpu.models.olmo_hybrid import (
+        _PERIOD,
+        OLMO_HYBRID_DECODER,
+        OlmoHybridConfig,
+        layer_shapes,
+    )
+    from accelerate_tpu.ops import delta_rule
+    from accelerate_tpu.serving import engine, make_pools, make_state_pool
+
+    cfg = OlmoHybridConfig(vocab_size=2048, layer_types=_PERIOD * 2)
+    slots, block, bps, num_blocks, repeats = 48, 16, 96, 3073, 2
+
+    def shapes(tree):
+        return jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype, one_chip), tree)
+
+    def weights(kind, lead=()):
+        return {k: sds((*lead, *s), BF16, one_chip) for k, s in layer_shapes(cfg, kind).items()}
+
+    pools = shapes(jax.eval_shape(lambda: make_pools(2, num_blocks, cfg.n_kv_head, block, cfg.head_dim, BF16)))
+    heads, d_k, d_v = cfg.linear_num_value_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    packed = jax.eval_shape(delta_rule.pack_state, sds((heads, d_k, d_v), jnp.float32, one_chip)).shape
+    state = shapes(jax.eval_shape(lambda: make_state_pool(
+        6, slots, packed, (cfg.linear_conv_kernel_dim - 1, cfg.conv_width), BF16,
+    )))
+    layers = (tuple(weights(kind, (repeats,)) for kind in cfg.kinds[:4]), {}, {})
+
+    def ints(*shape):
+        return sds(shape, jnp.int32, one_chip)
+
+    statics = dict(family=OLMO_HYBRID_DECODER, cfg=cfg, qbits=0, temperature=0.0)
+    decode = engine._decode_jit.lower(
+        *pools, weights("globals"), layers, ints(slots, bps), ints(slots), ints(slots),
+        sds((slots, 2), jnp.uint32, one_chip), state, **statics,
+    ).compile()
+    prefill = engine._prefill_jit.lower(
+        *pools, weights("globals"), layers, ints(1, 256), ints(bps), ints(),
+        sds((2,), jnp.uint32, one_chip), ints(), state, **statics,
+    ).compile()
+    sizes = {
+        "span": slots * bps * block * cfg.n_kv_head * cfg.head_dim,  # what the gather path would build a pool and layer
+        "state": slots * heads * d_k * d_v, "kv": int(np.prod(pools[0].shape[1:])),
+        "weight": cfg.hidden_size * cfg.key_width, "state_layers": 6,
+    }
+    return {"decode": decode, "prefill": prefill}, sizes
+
+
+def test_a_periodic_plans_decode_holds_nothing_of_the_gathered_spans_size(periodic_serving_programs):
+    """The plan is scanned by its period, so its attention layer takes the
+    kernel: ONE Mosaic call in the program (the scan's body), the pools left
+    where they lie — nothing the size of the span the gather path would build
+    (48 slots x 1536 positions x 3840 lanes, 566 MB a pool and layer) is
+    copied, gathered, sliced or re-laid, the program's temporaries are under a
+    sixteenth of that, and no weight is copied or transposed (the gate's head
+    split folded into its product re-laid 44 MB a linear layer a step until
+    ``models/olmo_hybrid.py::_gdn_in`` held it behind a barrier)."""
+    from accelerate_tpu.telemetry.profiler import instructions_of_size
+
+    programs, sizes = periodic_serving_programs
+    decode = programs["decode"]
+    text = decode.as_text()
+    assert pallas_calls(decode) == 1 and "paged_attention" in text
+    moved = instructions_of_size(
+        text, ("copy", "gather", "slice", "dynamic-slice", "transpose", "concatenate", "reshape"), sizes["span"] // 2)
+    assert moved == []
+    assert decode.memory_analysis().temp_size_in_bytes < sizes["span"] * 2 // 16
+    assert instructions_of_size(text, ("copy", "transpose"), sizes["weight"]) == []
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_a_periodic_plans_programs_update_both_caches_in_place(periodic_serving_programs, program):
+    """Both pools ride the scan's carry and come back aliased; no ``copy`` of a
+    layer's state or of a layer's KV pool is left, and outside the fusions that
+    read a layer's rows and write them back in place nothing holds a layer's
+    state (decode: one fused pass reads it for both sums over k rows, one
+    writes it)."""
+    from accelerate_tpu.telemetry.profiler import instructions_of_size
+
+    programs, sizes = periodic_serving_programs
+    text = programs[program].as_text()
+    assert instructions_of_size(text, ("copy",), min(sizes["state"], sizes["kv"])) == []
+    pool = (sizes["state_layers"], 48, 30, 48, 384)
+    top = "\n".join(
+        block for block in re.split(r"\n(?=\S)", text) if "fused_computation" not in block.split("\n", 1)[0]
+    )
+    held = instructions_of_size(
+        top, ("slice", "dynamic-slice", "copy", "fusion", "dynamic-update-slice", "transpose"), sizes["state"])
+    assert all(dims in (pool, (12292 // 2, 16, 3840)) for _, _, dims in held), held
+    header = text.split("\n", 1)[0]
+    assert len(re.findall(r"(?:may|must)-alias", header)) == 4, header[:600]
+
+
+def test_the_state_pool_lies_without_padding(periodic_serving_programs):
+    """``(96, 192)`` float32 a head would lie on 256 lanes, a third more memory
+    and bytes a step; packed two k rows a row of lanes, ``(48, 384)``, the pool
+    as the compiler lays it out is within 2% of slots x layers x 30 x 96 x 192 x
+    4 bytes."""
+    programs, sizes = periodic_serving_programs
+    header = programs["decode"].as_text().split("\n", 1)[0]
+    takes = header[header.index("entry_computation_layout"):].split("->")[0]
+    (dims, tile), = re.findall(r"f32\[(6,48,[\d,]+)\]\{[\d,]+:T\(([\d,]+)\)", takes)
+    dims, tile = [int(d) for d in dims.split(",")], [int(t) for t in tile.split(",")]
+    for axis, t in zip((-2, -1), tile):
+        dims[axis] = -(-dims[axis] // t) * t
+    laid_out = 4 * int(np.prod(dims))
+    assert abs(laid_out / (4 * sizes["state_layers"] * sizes["state"]) - 1) < 0.02

@@ -48,7 +48,7 @@ def main() -> int:
 
     kinds = cfg.kinds
     n_attention = sum(k == "attention" for k in kinds)
-    n_mamba = sum(k == "mamba2" for k in kinds)
+    n_mamba = cfg.pattern.count("M")
     pools = jax.eval_shape(lambda: make_pools(
         n_attention, service["num_blocks"], cfg.n_kv_head, block, cfg.head_dim, bf16
     ))
