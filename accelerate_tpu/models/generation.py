@@ -73,8 +73,14 @@ class DecoderFamily:
       is what the next token's convolution reads (the last true rows).  The
       state's shape is the family's own: the service sizes its pool from what
       this returns
-    - ``recurrent_step(l, x, state, tail, cfg) -> (x, state, tail)`` — one
-      token for every slot, ``x: (slots, 1, c)``; slots never mix
+    - ``recurrent_step(l, x, pools, i, live, cfg, mesh) -> (x, state pool,
+      tail)`` — one token for every slot, ``x: (slots, 1, c)``; slots never
+      mix.  ``pools`` is the whole carried cache (``{"ssm", "conv"}``), ``i``
+      the layer's rank in it, ``live: (slots, 1)`` bool, ``mesh`` the pools'
+      mesh where it has several devices (else ``None``).  The hook hands back
+      the WHOLE state pool with layer ``i``'s rows updated where they lie (a
+      kernel that takes the pool in place: Mamba-2's over the live slots), and
+      layer ``i``'s new tail, which the engine writes
     - ``recurrent_scopes`` — the two ``jax.named_scope`` names under which the
       engine writes a layer's rows of the state pool, in prefill and in decode:
       the family's own scan and step scopes, so that a trace counts the write

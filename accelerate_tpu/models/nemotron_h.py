@@ -268,8 +268,16 @@ def mamba_prefill(l, x, true_len, cfg):
         return _gate_norm_out(y, z, l, x[0], cfg)[None], state, tail
 
 
-def mamba_step(l, x, state, tail, cfg):
-    """One token for every slot: ``x: (slots, 1, c)``."""
+def mamba_step(l, x, pools, i, live, cfg, mesh=None):
+    """One token for every slot: ``x: (slots, 1, c)``; ``pools`` the state
+    pools (``{"ssm", "conv"}``), ``i`` this layer's rank in them, ``live:
+    (slots, 1)``.  The recurrence runs over the live slots alone, in place in
+    the whole state pool, which comes back beside the new tail
+    (``native/kernels/ssm_step.py``; imported here, at trace time).  A dead
+    slot's state is left as it is and its ``y`` is zeros."""
+    from ..native.kernels import ssm_step as kernel
+
+    tail = pools["conv"][i]
     with jax.named_scope("atpu_serve_ssm_in"):
         u = rmsnorm(x[:, 0], l["norm"], cfg.norm_eps)
         z, xbc, dt_raw = _split_in_proj(jnp.dot(u, l["in_w"], preferred_element_type=jnp.float32), cfg)
@@ -278,7 +286,7 @@ def mamba_step(l, x, state, tail, cfg):
         xs, b, c = _split_xbc(jax.nn.silu(conv), cfg)
     with jax.named_scope("atpu_serve_ssm_step"):
         dt, a = _dt_and_a(dt_raw, l)
-        y, state = ssm.ssm_step(state, xs, dt, a, b, c, l["d"])
+        y, state = kernel.ssm_step_live(pools["ssm"], i, live[:, 0], xs, dt, a, b, c, l["d"], mesh=mesh)
     with jax.named_scope("atpu_serve_ssm_out"):
         return _gate_norm_out(y, z, l, x[:, 0], cfg)[:, None], state, tail
 

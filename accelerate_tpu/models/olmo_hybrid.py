@@ -284,8 +284,13 @@ def gdn_prefill(l, x, true_len, cfg):
     return _after_mixer(l, x[0], mixed, cfg)[None], state, tail
 
 
-def gdn_step(l, x, state, tail, cfg):
-    """One token for every slot: ``x: (slots, 1, c)``."""
+def gdn_step(l, x, pools, i, live, cfg, mesh=None):
+    """One token for every slot, live or not: ``x: (slots, 1, c)``; layer
+    ``i``'s rows of the state pools ``pools`` (``{"ssm", "conv"}``) read, and
+    the state pool handed back with them updated, beside the new tail.  The
+    plain ``jnp`` step (``ops/delta_rule.py``) over every slot: ``live`` and
+    ``mesh`` are not needed."""
+    state, tail = pools["ssm"][i], pools["conv"][i]
     with jax.named_scope("atpu_serve_gdn_in"):
         qkv, z, a, b = _gdn_in(l, x[:, 0])
     with jax.named_scope("atpu_serve_gdn_conv"):
@@ -296,7 +301,9 @@ def gdn_step(l, x, state, tail, cfg):
         o, state = delta_rule.delta_rule_step(state, q, k, v, g, beta)
     with jax.named_scope("atpu_serve_gdn_out"):
         mixed = _gate_norm_out(o, z, l, x, cfg)
-    return _after_mixer(l, x[:, 0], mixed, cfg)[:, None], state, tail
+    x = _after_mixer(l, x[:, 0], mixed, cfg)[:, None]
+    with jax.named_scope("atpu_serve_gdn_step"):
+        return x, pools["ssm"].at[i].set(state), tail
 
 
 def _embed(g, ids, positions, cfg):

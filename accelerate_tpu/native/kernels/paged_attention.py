@@ -50,46 +50,14 @@ Off the TPU the same kernel runs through the Pallas interpreter
 from __future__ import annotations
 
 import functools
-import sys
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
-_GPU_INTERPRETER = "jax._src.pallas.mosaic_gpu.interpret.interpret_pallas_call"
+from .pallas_import import _GPU_INTERPRETER, import_pallas  # noqa: F401 (the name the tests ask for)
 
-
-def _import_pallas():
-    """``jax.experimental.pallas`` and its ``tpu`` half, for a serving process
-    that imports them here first (this module is imported when the first
-    decode program is traced: set-up time, at every start).
-
-    ``jax._src.pallas.pallas_call`` imports jax's Mosaic-GPU interpreter
-    whatever the backend — 0.7 of the import's 1.0-1.1 s, the LLVM and NVVM
-    dialects behind it — inside a ``try … except ImportError`` of its own,
-    because some builds lack it.  On a TPU nothing can ask for a GPU kernel to
-    be interpreted, so there that one import is made to fail and jax takes its
-    own fallback (``sys.modules[name] = None`` is Python's way to say "not
-    here").  Only where Pallas has not been imported yet (a process that
-    trained first keeps what it has), and the name is free again afterwards.
-    PERF.md, PR 33: ``setup_s`` by phase."""
-    block = (
-        jax.default_backend() == "tpu"
-        and "jax._src.pallas.pallas_call" not in sys.modules
-        and _GPU_INTERPRETER not in sys.modules
-    )
-    if block:
-        sys.modules[_GPU_INTERPRETER] = None
-    try:
-        from jax.experimental import pallas as pl
-        from jax.experimental.pallas import tpu as pltpu
-    finally:
-        if block:
-            del sys.modules[_GPU_INTERPRETER]
-    return pl, pltpu
-
-
-pl, pltpu = _import_pallas()
+pl, pltpu = import_pallas()
 
 from ...models.generation import _NEG_INF  # noqa: E402
 from ...ops import flash_attention  # noqa: E402

@@ -106,7 +106,10 @@ def ssm_step(state, x, dt, a, b, c, d):
     """One token for every slot.  ``state: (S, H, P, N)`` float32,
     ``x: (S, H, P)``, ``dt: (S, H)`` float32, ``b, c: (S, G, N)``.  Returns
     ``(y (S, H, P) float32, new state)``.  Slots never mix: every term is
-    per slot."""
+    per slot.  The plain statement of the step: the decode program runs it
+    over the live slots alone, in place in the state pool
+    (``native/kernels/ssm_step.py``), and the tests hold that kernel to
+    this."""
     f32 = jnp.float32
     h, g = x.shape[1], b.shape[1]
     x, dt = x.astype(f32), dt.astype(f32)

@@ -85,11 +85,12 @@ it was: gather the slot's whole table row, re-lay it as ``(Hkv, S, d)``,
 ``cached_attention`` over all of it.  Few of Nemotron-H's layers attend, over
 few kv heads (3 of 26, 2 heads: 0.94 of a 20.7 ms step), and unrolled they
 would pay one kernel lowering each and the Pallas import at every start; so the
-kernel module is imported in the scan's branch at trace time, a process that
-serves an unrolled plan never imports ``jax.experimental.pallas``, and its
-programs lower to the text they lowered to before the kernel came (PERF.md, PR
-33).  A period's attention layers sit in a scan's body: one lowering a program,
-and a family with 30 kv heads of 128 could not be served by the gather at all
+kernel module is imported in the scan's branch at trace time, and an unrolled
+plan's attention layers lower to the text they lowered to before the kernel
+came (PERF.md §6).  (A recurrent layer's decode step is the family's own:
+Mamba-2's takes a kernel over the live slots, one lowering for all its layers
+— ``recurrent`` in ``_decode_body``.)  A period's attention layers sit in a
+scan's body: one lowering a program, and a family with 30 kv heads of 128 could not be served by the gather at all
 (4.5 GB gathered and re-laid a step: PERF.md, PR 36).  The
 writers pad a token's row to the page's lanes only where the page has pad
 lanes (``_pad_lanes``), so a family whose ``n_kv·d`` is a multiple of 128
@@ -380,8 +381,7 @@ def _decode_body(
             # a scanned plan: each slot's live pages, read where they lie,
             # under a running softmax — one Mosaic kernel a layer, no gathered
             # span (docs/serving.md §decode attention).  Imported here, at
-            # trace time: a process that serves an unrolled plan never pays
-            # for jax.experimental.pallas
+            # trace time: an unrolled plan never lowers it
             from ..native.kernels.paged_attention import paged_attention
 
             with jax.named_scope("atpu_serve_attend"):
@@ -441,12 +441,15 @@ def _decode_body(
             return x, kp, vp
 
         def recurrent(l, x, state, i):
-            # every slot's state, read and written at this layer's rows of the
-            # carried pool.  A dead slot computes on what it holds: states
+            # the family's step takes the whole carried state pool and the
+            # layer's rank, and hands back the pool with this layer's rows
+            # updated where they lie (a kernel over the live slots, or an
+            # update of every slot's rows); the engine writes the tail.  A
+            # slot the step computes on though dead does no harm: states
             # never mix across slots, and its next prefill writes it whole
-            x, new, tail = family.recurrent_step(l, x, state["ssm"][i], state["conv"][i], cfg)
+            x, ssm_pool, tail = family.recurrent_step(l, x, state, i, live, cfg, mesh)
             with jax.named_scope(family.recurrent_scopes[1]):
-                return x, {"ssm": state["ssm"].at[i].set(new), "conv": state["conv"].at[i].set(tail)}
+                return x, {"ssm": ssm_pool, "conv": state["conv"].at[i].set(tail)}
 
         x, kp, vp, state, load = _run_plan(
             kinds, plain_layers, x, kp, vp, state, attention, recurrent,

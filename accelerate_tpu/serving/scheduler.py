@@ -235,6 +235,7 @@ class DecodeService:
         blocks_per_slot = self.capacity // cfg.block_size
         num_blocks = cfg.num_blocks or (cfg.max_slots * blocks_per_slot + 1)
         n_state_layers = kinds.count(RECURRENT) if kinds is not None else 0
+        self._state_layers = n_state_layers
         self.pool = BlockPool(
             num_blocks, cfg.block_size, cfg.max_slots, blocks_per_slot,
             has_state=n_state_layers > 0,
@@ -472,6 +473,11 @@ class DecodeService:
             # no device read
             "kv_pages_walked": 0,
             "kv_pages_tabled": 0,
+            # a mixed plan: (recurrent layer, slot) states the decode step
+            # takes, the decoding slots' alone a layer and micro-step — what
+            # a live-slot step walks (Mamba-2's kernel) of the max_slots a
+            # layer a step over every slot reads (docs/telemetry.md §serving)
+            "state_slots_walked": 0,
             # fault-tolerance accounting (docs/serving.md §fault
             # tolerance): shed completions, recovered (re-prefilled)
             # admissions, retry attempts, exhaustion requeues, pool
@@ -1255,6 +1261,10 @@ class DecodeService:
                 self.stats["kv_pages_walked"] += walked
                 self.stats["kv_pages_tabled"] += tabled
                 about.update(kv_pages_walked=walked, kv_pages_tabled=tabled)
+                if self._state_layers:
+                    states = n * len(active) * self._state_layers
+                    self.stats["state_slots_walked"] += states
+                    about.update(state_slots_walked=states)
                 with flightrec.span("atpu/serve/decode_sync"):
                     block_host = np.asarray(tok_block).reshape(
                         self.config.max_slots, n

@@ -23,6 +23,7 @@ import pytest
 
 from accelerate_tpu import DecodeService, ServingConfig
 from accelerate_tpu.models import nemotron_h
+from accelerate_tpu.native.kernels import ssm_step as ssm_kernel
 from accelerate_tpu.nn import moe
 from accelerate_tpu.ops import ssm
 from benchmark import cells
@@ -313,6 +314,54 @@ def test_a_nan_in_a_dead_slot_reaches_no_live_one_and_admission_resets_it(params
     svc.pool.check_no_leaks()
 
 
+# -- (e') the decode step's kernel: the live slots alone, in place ----------------
+@pytest.mark.parametrize("live", [
+    (0, 0, 0, 0, 0, 0), (1, 1, 1, 1, 1, 1), (0, 0, 1, 0, 0, 0), (1, 0, 1, 1, 0, 1), (0, 0, 0, 0, 0, 1),
+], ids=["none-live", "all-live", "one-live", "non-contiguous", "last-slot-only"])
+def test_the_step_kernel_is_the_plain_step_on_live_slots_and_leaves_the_rest(live):
+    """``native/kernels/ssm_step.py`` (the interpreter here) against
+    ``ops/ssm.py::ssm_step`` on layer 1 of a three-layer pool: the live slots'
+    ``y`` and new state agree to float32 rounding (1e-5, as the scan's test
+    holds: one sum over ``N`` in another order); the dead slots' rows and the other layers' rows are the
+    pool's own, bit for bit, though the dead rows hold NaN; a dead slot's ``y``
+    is zeros."""
+    n_layers, slots, h, p, n, g = 3, 6, 8, 8, 16, 2
+    ks = jax.random.split(jax.random.PRNGKey(sum(live) + 7), 7)
+    live = np.asarray(live, bool)
+    pool = jax.random.normal(ks[0], (n_layers, slots, h, p, n))
+    pool = pool.at[1].set(jnp.where(live[:, None, None, None], pool[1], jnp.nan))
+    x = jax.random.normal(ks[1], (slots, h, p))
+    dt = jax.nn.softplus(jax.random.normal(ks[2], (slots, h)) - 1.0)
+    a = -jnp.exp(jax.random.uniform(ks[3], (h,), minval=0.0, maxval=2.5))
+    b, c = jax.random.normal(ks[4], (slots, g, n)), jax.random.normal(ks[5], (slots, g, n))
+    d = jax.random.normal(ks[6], (h,))
+    want_y, want_state = (np.asarray(t) for t in ssm.ssm_step(pool[1], x, dt, a, b, c, d))
+    y, new = (np.asarray(t) for t in ssm_kernel.ssm_step_live(pool, 1, jnp.asarray(live), x, dt, a, b, c, d))
+    before = np.asarray(pool)
+    np.testing.assert_allclose(y[live], want_y[live], rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(new[1][live], want_state[live], rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(y[~live], 0.0)
+    assert np.isfinite(y).all()
+    assert new[1][~live].tobytes() == before[1][~live].tobytes()
+    assert new[[0, 2]].tobytes() == before[[0, 2]].tobytes()
+
+
+def test_state_slots_walked_counts_the_decoding_slots_a_mamba_layer(params, prompts):
+    """``stats["state_slots_walked"]``: a request of ``m`` tokens decodes ``m -
+    1`` of them (the prefill samples the first), each in every Mamba layer (3
+    of ``MEM*EM``) — host arithmetic, and the ring's step spans carry it."""
+    from accelerate_tpu.telemetry import flightrec
+
+    tapped = Tapped(params)
+    tapped.run(prompts[:4], [m for _, m in REQUESTS[:4]])
+    service = tapped.service
+    want = CFG["hybrid_override_pattern"].count("M") * sum(m - 1 for _, m in REQUESTS[:4])
+    assert service.stats["state_slots_walked"] == want
+    steps = [e for e in flightrec.recorder().snapshot() if e["kind"] == "atpu/serve/step"]
+    mine = steps[-service.stats["steps"]:]
+    assert sum(e.get("state_slots_walked", 0) for e in mine) == want
+
+
 # -- (f) a lower precision than stated fails (a) -----------------------------------
 def _bf16(x):
     return x.astype(jnp.bfloat16).astype(x.dtype)
@@ -330,8 +379,11 @@ def test_a_lower_precision_than_stated_fails_the_comparison(params, prompts, mon
     budgets = [m for _, m in REQUESTS]
     assert worst_gap(params, prompts, Tapped(params).run(prompts, budgets)) < LOGIT_TOL
     if what == "state":
-        step, chunked = ssm.ssm_step, ssm.ssd_chunked
-        monkeypatch.setattr(ssm, "ssm_step", lambda s, *a: (lambda y, s2: (y, _bf16(s2)))(*step(_bf16(s), *a)))
+        # what the programs run: the decode step's kernel over the whole pool
+        # (rounding a row twice is rounding it once), the prefill's scan
+        step, chunked = ssm_kernel.ssm_step_live, ssm.ssd_chunked
+        monkeypatch.setattr(ssm_kernel, "ssm_step_live",
+                            lambda pool, *a, **kw: (lambda y, p: (y, _bf16(p)))(*step(_bf16(pool), *a, **kw)))
         monkeypatch.setattr(ssm, "ssd_chunked", lambda *a: (lambda y, s: (y, _bf16(s)))(*chunked(*a)))
     else:
         route = moe.route_sigmoid_topk

@@ -473,6 +473,10 @@ def test_the_benchmarks_runner_serves_the_family_and_its_readers_find_their_scop
     assert set(NEW_METRICS) <= set(cell.per_layer) and "decode_ssm_ms" not in cell.per_layer
     monkeypatch.setattr(harness, "memory_peak_bytes", lambda: 0)
     monkeypatch.setattr(harness, "memory_in_use_bytes", lambda: 0)
+    # the program registry as the benchmark's own process starts with it: what
+    # earlier tests of this process registered (an unrolled plan's decode among
+    # them) is not this cell's
+    monkeypatch.setattr(profiler, "_programs", {})
     out = cell.runner.run(cell, 2**31 + 77, 0.6, False, time.perf_counter(),
                           {"platform": "cpu", "kind": "cpu", "count": 1})
     correct, compared = harness.decide(out["numbers"], cell.limits)

@@ -543,19 +543,19 @@ def hybrid_serving_programs(one_chip):
         return sds(shape, jnp.int32, one_chip)
 
     statics = dict(family=NEMOTRON_H_DECODER, cfg=cfg, qbits=0, temperature=0.0)
-    decode = engine._decode_jit.lower(
+    lowered = engine._decode_jit.lower(
         *pools, weights("globals"), layers, ints(slots, bps), ints(slots), ints(slots),
         sds((slots, 2), jnp.uint32, one_chip), state, **statics,
-    ).compile()
+    )
     prefill = engine._prefill_jit.lower(
         *pools, weights("globals"), layers, ints(1, 512), ints(bps), ints(),
         sds((2,), jnp.uint32, one_chip), ints(), state, **statics,
     ).compile()
     sizes = {
         "kv": int(np.prod(pools[0].shape[1:])), "state": int(np.prod(state["ssm"].shape[1:])),
-        "experts": int(np.prod(layers[0][1]["up_w"].shape)),
+        "experts": int(np.prod(layers[0][1]["up_w"].shape)), "mamba": cfg.pattern.count("M"),
     }
-    return {"decode": decode, "prefill": prefill}, sizes
+    return {"decode": lowered.compile(), "prefill": prefill, "decode_lowered": lowered.as_text()}, sizes
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -563,11 +563,13 @@ def test_hybrid_programs_move_no_cache_or_expert_sized_buffer(hybrid_serving_pro
     """Both caches are donated, carried whole and touched at their own rows, and
     every layer's weights are arrays of their own: no ``copy`` of a layer's KV
     pool, of a layer's state pool or of an expert stack is left, no instruction
-    of the ENTRY computation holds a layer's state (the reads and the update
-    are fused, in place), and the four cache buffers come back aliased.  (The
-    expert stacks as one ``(L, 32, 2688, 1856)`` parameter were sliced out, 640
-    MB a layer a step: PERF.md, PR 31.)  The experts' products are the
-    compiler's own: no custom kernel in either program."""
+    of the ENTRY computation holds a layer's state (the prefill's write is
+    fused, in place; the decode's update is the state kernel's, on the pool),
+    and the four cache buffers come back aliased.  (The expert stacks as one
+    ``(L, 32, 2688, 1856)`` parameter were sliced out, 640 MB a layer a step:
+    PERF.md §6.)  The experts' products are the compiler's own: the only
+    custom kernel is the decode's one-token recurrence, one call a Mamba
+    layer, and the prefill has none."""
     from accelerate_tpu.telemetry.profiler import instructions_of_size
 
     programs, sizes = hybrid_serving_programs
@@ -579,7 +581,67 @@ def test_hybrid_programs_move_no_cache_or_expert_sized_buffer(hybrid_serving_pro
     assert all(dims == (2, 64, 64, 64, 128) for _, _, dims in held), held  # the pool itself, updated in place
     header = text.split("\n", 1)[0]
     assert len(re.findall(r"(?:may|must)-alias", header)) == 4, header[:600]
-    assert 'custom_call_target="tpu_custom_call"' not in text
+    assert pallas_calls(programs[program]) == (sizes["mamba"] if program == "decode" else 0)
+    assert "paged_attention" not in text  # an unrolled plan's attention keeps the gather
+
+
+def test_a_mixed_plans_decode_lowers_the_state_kernel_once_and_updates_the_pool_in_place(
+        hybrid_serving_programs):
+    """Nemotron-H's one-token recurrence is ONE lowered callee
+    (``native/kernels/ssm_step.py``, called through one ``jax.jit`` whose
+    arguments have the same shapes at every layer) that each Mamba layer
+    calls: jax lowers it once a program, so the cell's 12 Mamba layers pay for
+    one Mosaic lowering.  After inlining, each layer's ``ssm_step`` call takes
+    the whole state pool as its operand and hands it back aliased, and nothing
+    else in the program — no ``copy``, ``slice``, ``dynamic-slice``,
+    ``dynamic-update-slice``, ``transpose`` or fusion result — holds a layer's
+    state or more (the plain step wrote the pool a layer, in a
+    ``dynamic-update-slice`` under ``atpu_serve_ssm_step``: PERF.md §6)."""
+    from accelerate_tpu.telemetry.profiler import instructions_of_size
+
+    programs, sizes = hybrid_serving_programs
+    lowered = programs["decode_lowered"]
+    assert lowered.count("tpu_custom_call") == 1
+    (callee,) = [re.match(r"\w+ @(\w+)", f).group(1) for f in lowered.split("func.func ")[1:] if "tpu_custom_call" in f]
+    assert callee == "_step"
+    assert len(re.findall(rf"call @{callee}\(", lowered)) == sizes["mamba"]
+    text = programs["decode"].as_text()
+    held = instructions_of_size(
+        text, ("copy", "slice", "dynamic-slice", "dynamic-update-slice", "transpose", "fusion"), sizes["state"]
+    )
+    assert [dims for _, _, dims in held if dims[-3:] == (64, 64, 128)] == []  # (the rest: expert stacks)
+    calls = [line for line in text.split("\n") if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == sizes["mamba"]
+    pool = "f32[%d,64,64,64,128]" % sizes["mamba"]
+    for line in calls:
+        assert "ssm_step" in line and line.split("=", 1)[1].lstrip().startswith(f"({pool}")
+        assert "output_to_operand_aliasing={{0}: (7, {})}" in line
+
+
+@pytest.mark.parametrize("devices", [1, 4], ids=["one-chip", "four-chip-mesh"])
+def test_the_state_kernel_compiles_in_mosaic_for_v5e(topo, devices):
+    """The one-token recurrence at the serve-chat cell's shapes: a pool of 12
+    layers x 64 slots of 64 x 64 x 128 float32 (1.61 GB), one slot's 2 MiB
+    state in and out of VMEM a grid step, within the VMEM a kernel gets by
+    default (the compiler refuses one that is not).  No temporary but the
+    slots' small rows.  On a mesh of four devices the pool lies replicated, as
+    the service commits a sharded model's pools, and the kernel runs per
+    device under ``shard_map`` (bare, the TPU lowering refuses it)."""
+    from accelerate_tpu.native.kernels import ssm_step as kernel
+
+    mesh = Mesh(np.array(topo.devices[:devices]).reshape(devices), ("tp",))
+    whole = NamedSharding(mesh, P())
+    n_layers, slots, h, p, n, g = 12, 64, 64, 64, 128, 8
+    f32 = jnp.float32
+    args = (sds((n_layers, slots, h, p, n), f32, whole), sds((), jnp.int32, whole), sds((slots,), jnp.bool_, whole),
+            sds((slots, h, p), f32, whole), sds((slots, h), f32, whole), sds((h,), f32, whole),
+            sds((slots, g, n), f32, whole), sds((slots, g, n), f32, whole), sds((h,), f32, whole))
+    compiled = jax.jit(
+        lambda pool, i, *rest: kernel.ssm_step_live(pool, i, *rest, mesh=mesh), donate_argnums=0
+    ).lower(*args).compile()
+    assert pallas_calls(compiled) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * slots * h * p * 4
+    assert re.search(r"\{1\}: \(0, \{\}, (may|must)-alias\)", compiled.as_text().split("\n", 1)[0])
 
 
 _SERVE_A_MIXED_PLAN = """
@@ -605,17 +667,21 @@ for n in (5, 19):
     service.submit(np.arange(n, dtype=np.int32) % 96, max_new_tokens=6)
 service.run()
 assert len(service.results) == 2 and engine._decode_jit._cache_size() == 1
-print("pallas" if "jax.experimental.pallas" in sys.modules else "no pallas")
+print("pallas" if "jax.experimental.pallas" in sys.modules else "no pallas",
+      "attention kernel" if "accelerate_tpu.native.kernels.paged_attention" in sys.modules else "no attention kernel")
 """
 
 
 def test_a_process_that_serves_a_mixed_plan_never_imports_pallas():
-    """The decode kernel is the scanned plan's, imported in its branch of
-    ``decode_layer`` at trace time: a fresh interpreter that imports the
-    engine, builds a service over a mixed plan and serves two requests through
-    its prefill and decode programs has not imported ``jax.experimental.pallas``
-    (0.8-1.0 s at every start, and one kernel lowering an unrolled attention
-    layer: what ``setup_s`` refused in PR 32, PERF.md PR 33)."""
+    """Never for its attention: the decode attention kernel is the scanned
+    plan's, imported in its branch of ``decode_layer`` at trace time, and an
+    unrolled plan's attention layers keep the gather (a kernel lowering an
+    unrolled attention layer and the whole import were what ``setup_s``
+    refused once, PERF.md §6).  A fresh interpreter that imports the
+    engine, builds a service over Nemotron-H's unrolled plan and serves two
+    requests through its prefill and decode programs imports Pallas once, at
+    its decode's first trace, for the Mamba layers' one shared lowering of the
+    state kernel, and never the attention kernel's module."""
     import os
     import subprocess
     import sys
@@ -627,7 +693,7 @@ def test_a_process_that_serves_a_mixed_plan_never_imports_pallas():
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))), timeout=300,
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
-    assert proc.stdout.strip().splitlines()[-1] == "no pallas"
+    assert proc.stdout.strip().splitlines()[-1] == "pallas no attention kernel"
 
 
 # ---------------------------------------------------------------------------

@@ -6,8 +6,9 @@ decode program, 26 layers, 64 slots, 3073 blocks of 16.  Nothing is compiled
 and no weight is drawn (shapes only), so it runs on the CPU in seconds.
 
 Run it from the root of two checkouts and compare the lines: a change that
-leaves a mixed plan's programs alone prints the same five hashes, and
-``pallas_imported false`` (PERF.md, PR 33):
+leaves a mixed plan's programs alone prints the same five hashes.  The decode
+program holds one lowered state-space kernel for its Mamba layers
+(``pallas_imported true``; its attention layers keep the gather, PERF.md §6):
 
     JAX_PLATFORMS=cpu python tools/mixed_plan_text.py          # this tree
     (cd <parent checkout> && JAX_PLATFORMS=cpu python <this file>)
