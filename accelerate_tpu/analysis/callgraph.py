@@ -22,6 +22,8 @@ import ast
 import dataclasses
 from typing import Iterator, Optional
 
+from .engine import _SCOPES
+
 # Leaves that are tracing transforms regardless of prefix (project- or
 # jax-specific spellings that never collide with stdlib/user names).
 _WRAPPER_LEAVES = {
@@ -84,23 +86,6 @@ def dotted_name(node: ast.AST) -> Optional[str]:
     return None
 
 
-def iter_own_nodes(fn_node: ast.AST) -> Iterator[ast.AST]:
-    """Walk a function body without descending into nested def/class bodies
-    (those are their own call-graph nodes, reached through edges)."""
-    stack = list(ast.iter_child_nodes(fn_node))
-    while stack:
-        node = stack.pop()
-        yield node
-        if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef, ast.ClassDef)):
-            # still surface the nested def's decorators/defaults — they
-            # evaluate in the enclosing scope
-            stack.extend(node.decorator_list)
-            if not isinstance(node, ast.ClassDef):
-                stack.extend(node.args.defaults + [d for d in node.args.kw_defaults if d])
-            continue
-        stack.extend(ast.iter_child_nodes(node))
-
-
 @dataclasses.dataclass
 class FunctionInfo:
     name: str
@@ -113,7 +98,7 @@ class FunctionInfo:
     barrier: bool = False
 
 
-def factory_returned_classes(tree: ast.AST) -> dict[str, str]:
+def factory_returned_classes(module) -> dict[str, str]:
     """``{factory function name: constructed class name}`` for every
     MODULE-LEVEL function whose returns are ALL ``SomeClass(...)`` calls of
     the SAME constructor — the receiver-type source behind factory-return
@@ -148,7 +133,8 @@ def factory_returned_classes(tree: ast.AST) -> dict[str, str]:
     (no ground class exists)."""
     factories: dict[str, str] = {}
     knocked_out: set[str] = set()
-    for node in getattr(tree, "body", []):
+    index = module.index
+    for node in module.tree.body:
         name = None
         if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef,
                              ast.ClassDef)):
@@ -165,15 +151,9 @@ def factory_returned_classes(tree: ast.AST) -> dict[str, str]:
         qualifies = False
         ctor = None
         if isinstance(node, ast.FunctionDef) and not node.decorator_list:
-            params = {
-                a.arg for a in ast.walk(node.args) if isinstance(a, ast.arg)
-            }
-            returns = [
-                sub for sub in iter_own_nodes(node)
-                if isinstance(sub, ast.Return)
-            ]
+            params = {a.arg for a in index.walk(node.args, ast.arg)}
             ctors: set[str] = set()
-            for ret in returns:
+            for ret in index.own(node, ast.Return):
                 c = None
                 if isinstance(ret.value, ast.Call):
                     fn = ret.value.func
@@ -214,45 +194,68 @@ def factory_returned_classes(tree: ast.AST) -> dict[str, str]:
     return resolved
 
 
-def _is_singleton_init(fn_node: ast.AST) -> bool:
-    for sub in iter_own_nodes(fn_node):
-        if isinstance(sub, ast.Assign):
-            for t in sub.targets:
-                if (
-                    isinstance(t, ast.Attribute)
-                    and t.attr == "__dict__"
-                    and isinstance(t.value, ast.Name)
-                    and t.value.id == "self"
-                ):
-                    return True
-    return False
+def _is_singleton_init(index, fn_node: ast.AST) -> bool:
+    return any(
+        isinstance(t, ast.Attribute)
+        and t.attr == "__dict__"
+        and isinstance(t.value, ast.Name)
+        and t.value.id == "self"
+        for sub in index.own(fn_node, ast.Assign)
+        for t in sub.targets
+    )
 
 
-class _Collector(ast.NodeVisitor):
-    def __init__(self, factories: Optional[dict[str, str]] = None):
-        self.stack: list[str] = []
-        self.functions: list[FunctionInfo] = []
+class CallGraph:
+    def __init__(self, module):
+        self.module = module
+        index = module.index
         # same-module factory functions (factory_returned_classes): a
         # receiver bound from `make_runner()` dispatches as the class every
-        # return of make_runner constructs
-        self.factories: dict[str, str] = factories or {}
+        # return of make_runner constructs.  Exported for the program graph:
+        # other modules importing one of these factories resolve their
+        # receivers through it (v11)
+        self.factories: dict[str, str] = factory_returned_classes(module)
         # qualnames of actual ClassDefs: instance-dispatch edges resolve
         # only through these — a factory FUNCTION with a nested def also
         # owns `outer.inner` qualnames, and treating it as a class would
         # wire phantom method edges into the nested function
         self.classes: set[str] = set()
+        self.functions: dict[str, FunctionInfo] = {}
+        enclosing: list[tuple[int, str]] = []  # (end of scope, name)
+        for node in index.of_type(*_SCOPES):
+            i = index.pos[node]
+            while enclosing and enclosing[-1][0] <= i:
+                enclosing.pop()
+            qual = ".".join([name for _, name in enclosing] + [node.name])
+            if isinstance(node, ast.ClassDef):
+                self.classes.add(qual)
+            else:
+                self.functions[qual] = self._function(node, qual)
+            enclosing.append((index.end[i], node.name))
+        self.by_leaf: dict[str, list[FunctionInfo]] = {}
+        for f in self.functions.values():
+            self.by_leaf.setdefault(f.name, []).append(f)
+        # calls of a tracing transform, in ast.walk order (the first root
+        # reason a function gets is the one it keeps)
+        self.wrapper_calls = index.as_walked(
+            [c for c in index.of_type(ast.Call) if is_trace_wrapper(module.resolve(c.func))]
+        )
+        # reached: qualname -> human-readable reason ("root ..." / "via ...")
+        self.reached: dict[str, str] = {}
+        self._find_roots()
+        self._propagate()
 
-    def _visit_fn(self, node):
-        qual = ".".join(self.stack + [node.name])
-        info = FunctionInfo(node.name, qual, node, barrier=_is_singleton_init(node))
+    def _function(self, node, qual: str) -> FunctionInfo:
+        index = self.module.index
+        info = FunctionInfo(node.name, qual, node, barrier=_is_singleton_init(index, node))
         # names bound as data in this scope (params, assignments, loop vars):
         # a data binding passed as an argument is a value, not a reference to
         # a same-named module function — without this, a parameter named like
         # a method creates phantom edges
-        params = {a.arg for a in ast.walk(node.args) if isinstance(a, ast.arg)}
+        params = {a.arg for a in index.walk(node.args, ast.arg)}
         store_counts: dict[str, int] = {}
-        for sub in iter_own_nodes(node):
-            if isinstance(sub, ast.Name) and isinstance(sub.ctx, (ast.Store, ast.Del)):
+        for sub in index.own(node, ast.Name):
+            if isinstance(sub.ctx, (ast.Store, ast.Del)):
                 store_counts[sub.id] = store_counts.get(sub.id, 0) + 1
         local_data = params | set(store_counts)
         # cheap type inference over locals bound to constructor calls:
@@ -267,10 +270,9 @@ class _Collector(ast.NodeVisitor):
         # rebind) leaves the receiver uninferred: its type is not knowable,
         # and a wrong guess would cross-wire reachability.
         ctor_assigns: dict[str, list[str]] = {}
-        for sub in iter_own_nodes(node):
+        for sub in index.own(node, ast.Assign):
             if (
-                isinstance(sub, ast.Assign)
-                and len(sub.targets) == 1
+                len(sub.targets) == 1
                 and isinstance(sub.targets[0], ast.Name)
                 and isinstance(sub.value, ast.Call)
             ):
@@ -312,70 +314,36 @@ class _Collector(ast.NodeVisitor):
             # the counts disagree), and they must all name the same class
             if store_counts.get(target) == len(ctors) and len(set(ctors)) == 1:
                 ctor_of[target] = ctors[0]
-        for sub in iter_own_nodes(node):
-            if isinstance(sub, ast.Call):
-                # direct calls: f(...), self.f(...) / cls.f(...), and dotted
-                # alias.f(...) — the dotted form is what program.py resolves
-                # across module boundaries (``utils.sync(x)``)
-                fn = sub.func
-                if isinstance(fn, ast.Name):
-                    info.edges.add(fn.id)
-                elif isinstance(fn, ast.Attribute):
-                    d = dotted_name(fn)
-                    if d is None:
-                        pass
-                    elif isinstance(fn.value, ast.Name) and fn.value.id in ("self", "cls"):
-                        info.edges.add(fn.attr)
-                    elif isinstance(fn.value, ast.Name) and fn.value.id in ctor_of:
-                        # inferred instance dispatch: obj = Ctor(); obj.m(x)
-                        info.edges.add(f"{ctor_of[fn.value.id]}.{fn.attr}")
-                    elif d.split(".", 1)[0] in ("self", "cls"):
-                        # deeper chains (self.state.update()): the receiver's
-                        # type is unknown — a bare-leaf edge would collide
-                        # with any same-module function named `update`
-                        pass
-                    elif d.split(".", 1)[0] not in local_data:
-                        info.edges.add(d)
-                # callback pattern: names passed as arguments may be called
-                # by the callee (ring hops, pipeline schedules do this).
-                # Nested defs are not Store bindings, so they stay eligible.
-                for arg in list(sub.args) + [kw.value for kw in sub.keywords]:
-                    if isinstance(arg, ast.Name) and arg.id not in local_data:
-                        info.edges.add(arg.id)
-        self.functions.append(info)
-        self.stack.append(node.name)
-        self.generic_visit(node)
-        self.stack.pop()
-
-    visit_FunctionDef = _visit_fn
-    visit_AsyncFunctionDef = _visit_fn
-
-    def visit_ClassDef(self, node):
-        self.classes.add(".".join(self.stack + [node.name]))
-        self.stack.append(node.name)
-        self.generic_visit(node)
-        self.stack.pop()
-
-
-class CallGraph:
-    def __init__(self, module):
-        self.module = module
-        collector = _Collector(factories=factory_returned_classes(module.tree))
-        collector.visit(module.tree)
-        self.functions: dict[str, FunctionInfo] = {
-            f.qualname: f for f in collector.functions
-        }
-        self.classes: set[str] = set(collector.classes)
-        # exported for the program graph: other modules importing one of
-        # these factories resolve their receivers through it (v11)
-        self.factories: dict[str, str] = dict(collector.factories)
-        self.by_leaf: dict[str, list[FunctionInfo]] = {}
-        for f in collector.functions:
-            self.by_leaf.setdefault(f.name, []).append(f)
-        # reached: qualname -> human-readable reason ("root ..." / "via ...")
-        self.reached: dict[str, str] = {}
-        self._find_roots()
-        self._propagate()
+        for sub in index.own(node, ast.Call):
+            # direct calls: f(...), self.f(...) / cls.f(...), and dotted
+            # alias.f(...) — the dotted form is what program.py resolves
+            # across module boundaries (``utils.sync(x)``)
+            fn = sub.func
+            if isinstance(fn, ast.Name):
+                info.edges.add(fn.id)
+            elif isinstance(fn, ast.Attribute):
+                d = dotted_name(fn)
+                if d is None:
+                    pass
+                elif isinstance(fn.value, ast.Name) and fn.value.id in ("self", "cls"):
+                    info.edges.add(fn.attr)
+                elif isinstance(fn.value, ast.Name) and fn.value.id in ctor_of:
+                    # inferred instance dispatch: obj = Ctor(); obj.m(x)
+                    info.edges.add(f"{ctor_of[fn.value.id]}.{fn.attr}")
+                elif d.split(".", 1)[0] in ("self", "cls"):
+                    # deeper chains (self.state.update()): the receiver's
+                    # type is unknown — a bare-leaf edge would collide
+                    # with any same-module function named `update`
+                    pass
+                elif d.split(".", 1)[0] not in local_data:
+                    info.edges.add(d)
+            # callback pattern: names passed as arguments may be called
+            # by the callee (ring hops, pipeline schedules do this).
+            # Nested defs are not Store bindings, so they stay eligible.
+            for arg in list(sub.args) + [kw.value for kw in sub.keywords]:
+                if isinstance(arg, ast.Name) and arg.id not in local_data:
+                    info.edges.add(arg.id)
+        return info
 
     # -- roots --------------------------------------------------------------
     def _mark(self, info: FunctionInfo, reason: str) -> None:
@@ -399,20 +367,15 @@ class CallGraph:
                         if is_trace_wrapper(wr):
                             self._mark(info, f"decorated with partial({wr}, ...)")
         # call-form: jax.jit(f, ...), shard_map_compat(f, ...), lax.scan(f, ...)
-        for node in ast.walk(mod.tree):
-            if not isinstance(node, ast.Call):
-                continue
+        for node in self.wrapper_calls:
             resolved = mod.resolve(node.func)
-            if not is_trace_wrapper(resolved):
-                continue
             # walk the whole argument expressions, not just bare Names: the
             # `shard_map_compat(partial(local_fn, ...), ...)` idiom buries the
             # traced function one call deep
             for arg in list(node.args) + [kw.value for kw in node.keywords]:
-                for sub in ast.walk(arg):
-                    if isinstance(sub, ast.Name):
-                        for info in self.by_leaf.get(sub.id, []):
-                            self._mark(info, f"passed to {resolved}")
+                for sub in mod.index.walk(arg, ast.Name):
+                    for info in self.by_leaf.get(sub.id, []):
+                        self._mark(info, f"passed to {resolved}")
 
     # -- reachability -------------------------------------------------------
     def _propagate(self) -> None:
@@ -469,30 +432,36 @@ def donated_positions(call: ast.Call) -> Optional[list[int]]:
 
 def donating_callables(module) -> dict[str, list[int]]:
     """name -> donated positions, for `g = jax.jit(f, donate_argnums=...)`
-    assignments and `@partial(jax.jit, donate_argnums=...)` decorated defs."""
-    out: dict[str, list[int]] = {}
-    for node in ast.walk(module.tree):
-        if isinstance(node, ast.Assign) and isinstance(node.value, ast.Call):
+    assignments and `@partial(jax.jit, donate_argnums=...)` decorated defs
+    (computed once per module: the summary and two rules read it)."""
+    if getattr(module, "_donors", None) is not None:
+        return module._donors
+    index = module.index
+    found: dict[ast.AST, tuple[list[str], list[int]]] = {}
+    for node in index.of_type(ast.Assign):
+        if isinstance(node.value, ast.Call):
             resolved = module.resolve(node.value.func) or ""
-            if resolved.rsplit(".", 1)[-1] in _JIT_LEAVES:
-                pos = donated_positions(node.value)
-                if pos:
-                    for t in node.targets:
-                        if isinstance(t, ast.Name):
-                            out[t.id] = pos
-        elif isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
-            for dec in node.decorator_list:
-                if not isinstance(dec, ast.Call):
-                    continue
-                resolved = module.resolve(dec.func) or ""
-                leaf = resolved.rsplit(".", 1)[-1]
-                is_jit_factory = leaf in _JIT_LEAVES
-                is_partial_jit = leaf == "partial" and any(
-                    (module.resolve(a) or "").rsplit(".", 1)[-1] in _JIT_LEAVES
-                    for a in dec.args
-                )
-                if is_jit_factory or is_partial_jit:
-                    pos = donated_positions(dec)
-                    if pos:
-                        out[node.name] = pos
+            pos = donated_positions(node.value)
+            if resolved.rsplit(".", 1)[-1] in _JIT_LEAVES and pos:
+                found[node] = ([t.id for t in node.targets if isinstance(t, ast.Name)], pos)
+    for node in index.of_type(ast.FunctionDef, ast.AsyncFunctionDef):
+        for dec in node.decorator_list:
+            if not isinstance(dec, ast.Call):
+                continue
+            resolved = module.resolve(dec.func) or ""
+            leaf = resolved.rsplit(".", 1)[-1]
+            is_jit_factory = leaf in _JIT_LEAVES
+            is_partial_jit = leaf == "partial" and any(
+                (module.resolve(a) or "").rsplit(".", 1)[-1] in _JIT_LEAVES
+                for a in dec.args
+            )
+            pos = donated_positions(dec)
+            if (is_jit_factory or is_partial_jit) and pos:
+                found[node] = ([node.name], pos)
+    # a later binding of a name wins, in the order ast.walk meets them
+    out: dict[str, list[int]] = {}
+    for node in index.as_walked(list(found)):
+        names, pos = found[node]
+        out.update((name, pos) for name in names)
+    module._donors = out
     return out

@@ -7,6 +7,7 @@ CLI runs it in a few hundred milliseconds so it can sit inside ``make test``).
 from __future__ import annotations
 
 import ast
+import bisect
 import dataclasses
 import hashlib
 import io
@@ -88,7 +89,9 @@ _RULE_TOKEN_RE = re.compile(r"^[A-Za-z][A-Za-z0-9_-]*$")
 # in-program stacked-layer permutation inside a captured pipeline body)
 # fires in consumer modules with a commit-at-prepare fix hint
 # (docs/parallel_plan.md §layout contract).
-ANALYSIS_VERSION = "13"
+# v14: every rule and the summary read one per-module index (ModuleIndex)
+# instead of re-walking the tree; a summary's axes are bare names.
+ANALYSIS_VERSION = "14"
 
 # Names that mark a branch/function as profiling/benchmark plumbing, where a
 # deliberate host sync is legitimate.  Shared by blocking-in-hot-loop and the
@@ -100,17 +103,12 @@ GUARD_NAME_RE = re.compile(
 )
 
 
-def is_guard_expr(test: ast.AST) -> bool:
+def is_guard_expr(index: "ModuleIndex", test: ast.AST) -> bool:
     """True when a test expression mentions a profiling/debug knob."""
-    for node in ast.walk(test):
-        name = None
-        if isinstance(node, ast.Name):
-            name = node.id
-        elif isinstance(node, ast.Attribute):
-            name = node.attr
-        if name and GUARD_NAME_RE.search(name):
-            return True
-    return False
+    return any(
+        GUARD_NAME_RE.search(node.id if isinstance(node, ast.Name) else node.attr)
+        for node in index.walk(test, ast.Name, ast.Attribute)
+    )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,54 +163,146 @@ def _dotted(node: ast.AST) -> Optional[str]:
     return dotted_name(node)
 
 
-def _collect_aliases(tree: ast.AST) -> dict[str, str]:
-    """alias -> canonical dotted prefix, from every import in the file.
+_SCOPES = (ast.FunctionDef, ast.AsyncFunctionDef, ast.ClassDef)
+
+
+class ModuleIndex:
+    """One module's tree, walked once and read by every rule and by the
+    summary.  Nodes are held in preorder with each subtree's end, so a
+    subtree is a slice; each type's positions are sorted, so the nodes of a
+    type under any node are a bisected slice; and a scope's own nodes (its
+    body short of nested def/class bodies, but with their decorators and
+    defaults, which run in the enclosing scope) are a few cached ranges.
+    Expression contexts and operators are shared between nodes by the
+    parser: they are in the walks, but no query starts from one."""
+
+    def __init__(self, tree: ast.AST):
+        order: list[ast.AST] = []
+        end: list[int] = []
+        depth: list[int] = []
+        pos: dict[ast.AST, int] = {}
+        by_type: dict[type, list[int]] = {}
+
+        # iterative, so a deep expression (a long chain of `+`) cannot
+        # exhaust the interpreter's recursion limit; (None, i) closes node i
+        stack: list = [(tree, 0)]
+        while stack:
+            node, d = stack.pop()
+            if node is None:
+                end[d] = len(order)
+                continue
+            i = len(order)
+            order.append(node)
+            end.append(0)
+            depth.append(d)
+            pos[node] = i
+            by_type.setdefault(type(node), []).append(i)
+            stack.append((None, i))
+            children = []
+            for field in node._fields:
+                v = getattr(node, field, None)
+                if isinstance(v, list):
+                    children += [(x, d + 1) for x in v if isinstance(x, ast.AST)]
+                elif isinstance(v, ast.AST):
+                    children.append((v, d + 1))
+            stack += reversed(children)
+        self.order, self.end, self.depth, self.pos = order, end, depth, pos
+        self.by_type = by_type
+        self._own: dict[ast.AST, list[tuple[int, int]]] = {}
+
+    def _select(self, ranges, types) -> list:
+        hits: list[int] = []
+        for t in types:
+            at = self.by_type.get(t)
+            if at:
+                for lo, hi in ranges:
+                    hits += at[bisect.bisect_left(at, lo):bisect.bisect_left(at, hi)]
+        if len(types) > 1:
+            hits.sort()
+        order = self.order
+        return [order[i] for i in hits]
+
+    def walk(self, node: ast.AST, *types: type) -> list:
+        """``node`` and its descendants in preorder (of ``types`` only, when
+        given) — what ``ast.walk`` yields, in source order."""
+        i = self.pos[node]
+        if not types:
+            return self.order[i:self.end[i]]
+        return self._select([(i, self.end[i])], types)
+
+    def of_type(self, *types: type) -> list:
+        """Every node of ``types`` in the module, in preorder."""
+        return self._select([(0, len(self.order))], types)
+
+    def own(self, scope: ast.AST, *types: type) -> list:
+        """``scope``'s own nodes (of ``types`` only, when given), in preorder."""
+        ranges = self._own.get(scope)
+        if ranges is None:
+            ranges = self._own[scope] = self._own_ranges(scope)
+        if not types:
+            return [n for lo, hi in ranges for n in self.order[lo:hi]]
+        return self._select(ranges, types)
+
+    def _own_ranges(self, scope) -> list[tuple[int, int]]:
+        start, stop = self.pos[scope] + 1, self.end[self.pos[scope]]
+        ranges, cur = [], start
+        for node in self._select([(start, stop)], _SCOPES):
+            i = self.pos[node]
+            if i < cur:
+                continue  # inside a nested scope already cut out
+            ranges.append((cur, i + 1))
+            evaluated = list(node.decorator_list)
+            if not isinstance(node, ast.ClassDef):
+                evaluated += node.args.defaults + [d for d in node.args.kw_defaults if d]
+            for sub in evaluated:
+                j = self.pos[sub]
+                ranges.append((j, self.end[j]))
+            cur = self.end[i]
+        ranges.append((cur, stop))
+        return sorted(r for r in ranges if r[0] < r[1])
+
+    def as_walked(self, nodes) -> list:
+        """``nodes`` (given in preorder) in the order ``ast.walk`` would meet
+        them — breadth first — for the maps where the first or last binding
+        of a name wins."""
+        return sorted(nodes, key=lambda n: self.depth[self.pos[n]])
+
+
+def _imports(index: ModuleIndex) -> tuple[dict[str, str], list[dict]]:
+    """(alias -> canonical dotted prefix, raw import records) from every
+    import in the file.
 
     ``import jax.numpy as jnp`` → jnp: jax.numpy; ``from jax import lax`` →
-    lax: jax.lax; relative imports keep their module tail (suffix matching in
-    the rules absorbs the missing package prefix).
+    lax: jax.lax; relative imports keep their module tail in the aliases
+    (suffix matching in the rules absorbs the missing package prefix), and
+    their level in the records, which the program graph resolves against the
+    package layout on disk.
     """
     aliases: dict[str, str] = {}
-    for node in ast.walk(tree):
+    records: list[dict] = []
+    for node in index.as_walked(index.of_type(ast.Import, ast.ImportFrom)):
         if isinstance(node, ast.Import):
             for a in node.names:
                 aliases[a.asname or a.name.split(".")[0]] = (
                     a.name if a.asname else a.name.split(".")[0]
                 )
-        elif isinstance(node, ast.ImportFrom):
-            base = node.module or ""
-            for a in node.names:
-                if a.name == "*":
-                    continue
-                full = f"{base}.{a.name}" if base else a.name
-                aliases[a.asname or a.name] = full
-    return aliases
-
-
-def _collect_import_records(tree: ast.AST) -> list[dict]:
-    """Raw import statements with their relative level preserved — the
-    program graph resolves these against the package layout on disk
-    (``_collect_aliases`` flattens levels away, which is fine for dotted-name
-    canonicalization but loses what ``from ..x import f`` points at)."""
-    records: list[dict] = []
-    for node in ast.walk(tree):
-        if isinstance(node, ast.Import):
             records.append(
-                {
-                    "kind": "import",
-                    "names": [[a.name, a.asname] for a in node.names],
-                }
+                {"kind": "import", "names": [[a.name, a.asname] for a in node.names]}
             )
-        elif isinstance(node, ast.ImportFrom):
-            records.append(
-                {
-                    "kind": "from",
-                    "module": node.module or "",
-                    "level": node.level,
-                    "names": [[a.name, a.asname] for a in node.names if a.name != "*"],
-                }
-            )
-    return records
+            continue
+        base = node.module or ""
+        for a in node.names:
+            if a.name != "*":
+                aliases[a.asname or a.name] = f"{base}.{a.name}" if base else a.name
+        records.append(
+            {
+                "kind": "from",
+                "module": base,
+                "level": node.level,
+                "names": [[a.name, a.asname] for a in node.names if a.name != "*"],
+            }
+        )
+    return aliases, records
 
 
 def _parse_rule_list(raw: Optional[str]) -> set[str]:
@@ -236,6 +326,8 @@ def _collect_suppressions(source: str):
     approach is out; we tokenize."""
     per_line: dict[int, set[str]] = {}
     per_file: set[str] = set()
+    if "graftlint" not in source:
+        return per_line, per_file
     try:
         tokens = list(tokenize.generate_tokens(io.StringIO(source).readline))
     except (tokenize.TokenError, IndentationError, SyntaxError):
@@ -267,8 +359,8 @@ class ModuleInfo:
         self.source = source
         self.lines = source.splitlines()
         self.tree = ast.parse(source, filename=path)
-        self.aliases = _collect_aliases(self.tree)
-        self.import_records = _collect_import_records(self.tree)
+        self.index = ModuleIndex(self.tree)
+        self.aliases, self.import_records = _imports(self.index)
         self.line_suppressions, self.file_suppressions = _collect_suppressions(source)
         # module-level `NAME = "literal"` string constants (axis-name rule
         # resolves bare-Name axis arguments through this)
@@ -283,16 +375,20 @@ class ModuleInfo:
             ):
                 self.str_constants[node.targets[0].id] = node.value.value
         self._callgraph = None
+        self._resolved: dict[ast.AST, Optional[str]] = {}
 
     def resolve(self, node: ast.AST) -> Optional[str]:
         """Canonical dotted name of an expression, with import aliases applied
         to the head segment (``jnp.zeros`` → ``jax.numpy.zeros``)."""
+        if node in self._resolved:
+            return self._resolved[node]
         d = _dotted(node)
-        if d is None:
-            return None
-        head, _, rest = d.partition(".")
-        base = self.aliases.get(head, head)
-        return f"{base}.{rest}" if rest else base
+        if d is not None:
+            head, _, rest = d.partition(".")
+            base = self.aliases.get(head, head)
+            d = f"{base}.{rest}" if rest else base
+        self._resolved[node] = d
+        return d
 
     @property
     def callgraph(self):
@@ -314,7 +410,6 @@ class AnalysisContext:
     """Cross-file facts collected in a first pass before rules run."""
 
     axis_universe: set[str] = dataclasses.field(default_factory=set)
-    axis_sources: dict[str, str] = dataclasses.field(default_factory=dict)
     # tensor → recorded PartitionSpec (JSON form) from a checkpoint
     # index.json, when the caller passed one (sharding-spec-drift input)
     ckpt_specs: dict[str, list] = dataclasses.field(default_factory=dict)
@@ -416,47 +511,43 @@ def _literal_strs(node: ast.AST) -> list[str]:
     return []
 
 
-def collect_axes(module: ModuleInfo) -> list[tuple[str, str]]:
-    """Harvest ``(axis, why)`` declarations from one module.  Pure so the
-    result can live in the per-module summary cache."""
-    out: list[tuple[str, str]] = []
-
-    def add(name: str, why: str) -> None:
-        out.append((name, why))
-
-    for node in ast.walk(module.tree):
+def collect_axes(module: ModuleInfo) -> list[str]:
+    """The mesh axes one module declares.  Pure so the result can live in
+    the per-module summary cache."""
+    out: set[str] = set()
+    for node in module.index.of_type(ast.Assign):
         # MESH_AXIS_DP = "dp" / ALL_MESH_AXES = (MESH_AXIS_DP, ...)
-        if isinstance(node, ast.Assign) and len(node.targets) == 1:
-            tgt = node.targets[0]
-            if isinstance(tgt, ast.Name):
-                if tgt.id.startswith("MESH_AXIS"):
-                    for s in _literal_strs(node.value):
-                        add(s, tgt.id)
-                elif "AXES" in tgt.id:
-                    for s in _literal_strs(node.value):
-                        add(s, tgt.id)
-                    if isinstance(node.value, (ast.Tuple, ast.List)):
-                        for e in node.value.elts:
-                            if isinstance(e, ast.Name) and e.id in module.str_constants:
-                                add(module.str_constants[e.id], tgt.id)
-        elif isinstance(node, ast.Call):
-            resolved = module.resolve(node.func) or ""
-            leaf = resolved.rsplit(".", 1)[-1]
-            # Mesh(devs, axis_names=(...)) / Mesh(devs, ("dp", ...))
-            if leaf in ("Mesh", "AbstractMesh", "make_mesh"):
-                for kw in node.keywords:
-                    if kw.arg == "axis_names":
-                        for s in _literal_strs(kw.value):
-                            add(s, "axis_names=")
-                if leaf in ("Mesh", "AbstractMesh") and len(node.args) >= 2:
-                    for s in _literal_strs(node.args[1]):
-                        add(s, "Mesh(...)")
-                # make_mesh({"dp": 2, ...})
-                if leaf == "make_mesh" and node.args and isinstance(node.args[0], ast.Dict):
-                    for k in node.args[0].keys:
-                        if isinstance(k, ast.Constant) and isinstance(k.value, str):
-                            add(k.value, "make_mesh({...})")
-    return out
+        tgt = node.targets[0]
+        if len(node.targets) != 1 or not isinstance(tgt, ast.Name):
+            continue
+        if tgt.id.startswith("MESH_AXIS"):
+            out.update(_literal_strs(node.value))
+        elif "AXES" in tgt.id:
+            out.update(_literal_strs(node.value))
+            out.update(
+                module.str_constants[e.id]
+                for e in getattr(node.value, "elts", ())
+                if isinstance(node.value, (ast.Tuple, ast.List))
+                and isinstance(e, ast.Name)
+                and e.id in module.str_constants
+            )
+    for node in module.index.of_type(ast.Call):
+        leaf = (module.resolve(node.func) or "").rsplit(".", 1)[-1]
+        # Mesh(devs, axis_names=(...)) / Mesh(devs, ("dp", ...))
+        if leaf in ("Mesh", "AbstractMesh", "make_mesh"):
+            for kw in node.keywords:
+                if kw.arg == "axis_names":
+                    out.update(_literal_strs(kw.value))
+            if leaf in ("Mesh", "AbstractMesh") and len(node.args) >= 2:
+                out.update(_literal_strs(node.args[1]))
+            # make_mesh({"dp": 2, ...})
+            if leaf == "make_mesh" and node.args and isinstance(node.args[0], ast.Dict):
+                out.update(
+                    k.value
+                    for k in node.args[0].keys
+                    if isinstance(k, ast.Constant) and isinstance(k.value, str)
+                )
+    return sorted(out)
 
 
 # ---------------------------------------------------------------------------
@@ -719,15 +810,9 @@ def run_analysis(
 
     # -- pass 2: cross-file facts (axis universe + whole-program graph) -----
     for r in records:
-        for axis, why in r.summary.axes:
-            ctx.axis_universe.add(axis)
-            ctx.axis_sources.setdefault(axis, f"{r.rel_path}: {why}")
+        ctx.axis_universe.update(r.summary.axes)
     if not ctx.axis_universe:
         ctx.axis_universe = set(FALLBACK_AXIS_UNIVERSE)
-        ctx.axis_sources = {
-            a: "builtin default (no mesh declaration found)"
-            for a in FALLBACK_AXIS_UNIVERSE
-        }
     program = ProgramGraph(records, cross=cross_module)
     ctx.cross_reached = program.cross_reached
     ctx.donor_aliases = program.donor_aliases
@@ -801,7 +886,7 @@ def run_analysis(
                 results.pop(next(iter(results)))
             cache.store(r.rel_path, r.content_hash, r.cache_entry)
 
-    findings.sort(key=lambda f: (f.path, f.line, f.col, f.rule))
+    findings.sort(key=lambda f: (f.path, f.line, f.col, f.rule, f.message))
     stale: list[str] = []
     if baseline:
         prints = {f.fingerprint() for f in findings}

@@ -36,13 +36,9 @@ import dataclasses
 import os
 from typing import Optional
 
-from .callgraph import (
-    donating_callables,
-    dotted_name,
-    is_trace_wrapper,
-    iter_own_nodes,
-)
-from .engine import GUARD_NAME_RE, is_guard_expr
+from .callgraph import donating_callables, dotted_name
+from .engine import _SCOPES, GUARD_NAME_RE, is_guard_expr
+from .taint import _call_leaf
 
 # methods whose argument escapes into the receiver (stored beyond the call)
 _STORE_METHODS = {
@@ -102,7 +98,7 @@ class ModuleSummary:
     reached: dict = dataclasses.field(default_factory=dict)  # local roots + local closure
     wrapper_passed: list = dataclasses.field(default_factory=list)  # [wrapper, name]
     donors: dict = dataclasses.field(default_factory=dict)  # name -> positions
-    axes: list = dataclasses.field(default_factory=list)  # [axis, why]
+    axes: list = dataclasses.field(default_factory=list)  # declared mesh axes
     imports: list = dataclasses.field(default_factory=list)  # raw import records
     classes: list = dataclasses.field(default_factory=list)  # ClassDef qualnames
     # {factory fn name: constructed class name} (callgraph.py v10 map) — the
@@ -117,7 +113,7 @@ class ModuleSummary:
             "reached": self.reached,
             "wrapper_passed": self.wrapper_passed,
             "donors": self.donors,
-            "axes": [list(a) for a in self.axes],
+            "axes": list(self.axes),
             "imports": self.imports,
             "classes": list(self.classes),
             "factories": dict(self.factories),
@@ -132,7 +128,7 @@ class ModuleSummary:
             reached=dict(d.get("reached", {})),
             wrapper_passed=[list(w) for w in d.get("wrapper_passed", [])],
             donors={k: list(v) for k, v in d.get("donors", {}).items()},
-            axes=[tuple(a) for a in d.get("axes", [])],
+            axes=list(d.get("axes", [])),
             imports=d.get("imports", []),
             classes=list(d.get("classes", [])),
             factories=dict(d.get("factories", {})),
@@ -141,7 +137,7 @@ class ModuleSummary:
         )
 
 
-def escaping_params(fn_node: ast.AST) -> list[int]:
+def escaping_params(index, fn_node: ast.AST) -> list[int]:
     """Positional-parameter indices of ``fn_node`` that are *stored* beyond
     the call: appended/added to a container, assigned to an attribute or
     subscript, or bound to a ``global`` name.  A caller that passes a buffer
@@ -159,10 +155,9 @@ def escaping_params(fn_node: ast.AST) -> list[int]:
         return []
     global_names: set[str] = set()
     escaped: set[str] = set()
-    for node in iter_own_nodes(fn_node):
-        if isinstance(node, (ast.Global, ast.Nonlocal)):
-            global_names.update(node.names)
-    for node in iter_own_nodes(fn_node):
+    for node in index.own(fn_node, ast.Global, ast.Nonlocal):
+        global_names.update(node.names)
+    for node in index.own(fn_node, ast.Call, ast.Assign, ast.AnnAssign, ast.AugAssign):
         if isinstance(node, ast.Call):
             fn = node.func
             if isinstance(fn, ast.Attribute) and fn.attr in _STORE_METHODS:
@@ -219,51 +214,29 @@ def escaping_params(fn_node: ast.AST) -> list[int]:
     return sorted(params.index(p) for p in escaped if p in params)
 
 
-class _BlockScan(ast.NodeVisitor):
-    """Structural scan for an unguarded blocking call: guard-``if`` bodies
-    are exempt at any nesting depth (inside loops, try, with, ...), and
-    nested defs are their own functions, not this one's behavior."""
-
-    def __init__(self):
-        self.guard_depth = 0
-        self.found = False
-
-    def visit_If(self, node):
-        self.visit(node.test)
-        guarded = is_guard_expr(node.test)
-        self.guard_depth += guarded
-        for stmt in node.body:
-            self.visit(stmt)
-        self.guard_depth -= guarded
-        for stmt in node.orelse:
-            self.visit(stmt)
-
-    def visit_Call(self, node):
-        if self.guard_depth == 0 and not self.found:
-            fn = node.func
-            if isinstance(fn, ast.Attribute) and fn.attr in _BLOCKING_LEAVES:
-                self.found = True
-            else:
-                d = dotted_name(fn)
-                if d and d.rsplit(".", 1)[-1] in _BLOCKING_LEAVES:
-                    self.found = True
-        self.generic_visit(node)
-
-    def visit_FunctionDef(self, node):
-        pass  # nested defs are separate call-graph nodes
-
-    visit_AsyncFunctionDef = visit_FunctionDef
-    visit_ClassDef = visit_FunctionDef
-
-
-def _has_unguarded_block(fn_node: ast.AST) -> bool:
+def _has_unguarded_block(index, fn_node: ast.AST) -> bool:
     """True when the function body reaches block_until_ready/effects_barrier
-    outside any profiling-guard ``if`` — i.e. calling this function blocks
-    unconditionally."""
-    scanner = _BlockScan()
-    for stmt in getattr(fn_node, "body", []):
-        scanner.visit(stmt)
-    return scanner.found
+    outside any profiling-guard ``if`` body (at any nesting depth) — i.e.
+    calling this function blocks unconditionally.  Nested defs are their own
+    functions, not this one's behavior."""
+    body = fn_node.body
+    lo, hi = index.pos[body[0]], index.end[index.pos[body[-1]]]
+    nested = [(index.pos[s], index.end[index.pos[s]]) for s in index.own(fn_node, *_SCOPES)]
+    blocking = [
+        i
+        for i in map(index.pos.__getitem__, index.own(fn_node, ast.Call))
+        if lo <= i < hi
+        and not any(a <= i < b for a, b in nested)
+        and _call_leaf(index.order[i].func) in _BLOCKING_LEAVES
+    ]
+    if not blocking:
+        return False
+    guarded = [
+        (index.pos[s.body[0]], index.end[index.pos[s.body[-1]]])
+        for s in index.own(fn_node, ast.If)
+        if is_guard_expr(index, s.test)
+    ]
+    return any(not any(a <= i < b for a, b in guarded) for i in blocking)
 
 
 def extract_summary(module) -> ModuleSummary:
@@ -273,6 +246,7 @@ def extract_summary(module) -> ModuleSummary:
     from .taint import collective_leaves, return_flow
 
     cg = module.callgraph
+    index = module.index
     functions = []
     for info in cg.functions.values():
         self_prefix = (
@@ -284,8 +258,8 @@ def extract_summary(module) -> ModuleSummary:
                 name=info.name,
                 qualname=info.qualname,
                 edges=sorted(info.edges),
-                escapes=escaping_params(info.node),
-                blocks=_has_unguarded_block(info.node),
+                escapes=escaping_params(index, info.node),
+                blocks=_has_unguarded_block(index, info.node),
                 guard=bool(GUARD_NAME_RE.search(info.name)),
                 barrier=info.barrier,
                 div_direct=div_direct,
@@ -298,14 +272,10 @@ def extract_summary(module) -> ModuleSummary:
     # graph resolves the rest through imports (`jax.jit(ops.step)`,
     # `shard_map_compat(partial(do_step, cfg), ...)` with do_step imported)
     wrapper_passed: list[list] = []
-    for node in ast.walk(module.tree):
-        if not isinstance(node, ast.Call):
-            continue
+    for node in cg.wrapper_calls:
         resolved = module.resolve(node.func)
-        if not is_trace_wrapper(resolved):
-            continue
         for arg in list(node.args) + [kw.value for kw in node.keywords]:
-            for sub in ast.walk(arg):
+            for sub in index.walk(arg, ast.Name, ast.Attribute):
                 if isinstance(sub, ast.Name):
                     wrapper_passed.append([resolved, sub.id])
                 elif isinstance(sub, ast.Attribute):

@@ -77,7 +77,7 @@ class AxisNameMismatch(Rule):
                     )
                 )
 
-        for node in ast.walk(module.tree):
+        for node in module.index.of_type(ast.Call, ast.FunctionDef, ast.AsyncFunctionDef):
             if isinstance(node, ast.Call):
                 resolved = module.resolve(node.func) or ""
                 leaf = resolved.rsplit(".", 1)[-1]

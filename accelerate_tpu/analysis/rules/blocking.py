@@ -44,18 +44,14 @@ _CADENCE_NAME_RE = re.compile(
 )
 
 
-def is_cadence_expr(test: ast.AST) -> bool:
+def is_cadence_expr(index, test: ast.AST) -> bool:
     """True when a guard condition shows sampled-cadence evidence: a
     modulus test (``i % n == 0``) or a cadence-named knob/predicate."""
-    for node in ast.walk(test):
-        if isinstance(node, ast.BinOp) and isinstance(node.op, ast.Mod):
-            return True
-        name = None
-        if isinstance(node, ast.Name):
-            name = node.id
-        elif isinstance(node, ast.Attribute):
-            name = node.attr
-        if name and _CADENCE_NAME_RE.search(name):
+    for node in index.walk(test, ast.BinOp, ast.Name, ast.Attribute):
+        if isinstance(node, ast.BinOp):
+            if isinstance(node.op, ast.Mod):
+                return True
+        elif _CADENCE_NAME_RE.search(node.id if isinstance(node, ast.Name) else node.attr):
             return True
     return False
 
@@ -94,8 +90,8 @@ class _LoopVisitor(ast.NodeVisitor):
 
     def visit_If(self, node):
         self.visit(node.test)
-        guarded = is_guard_expr(node.test)
-        cadenced = is_cadence_expr(node.test)
+        guarded = is_guard_expr(self.module.index, node.test)
+        cadenced = is_cadence_expr(self.module.index, node.test)
         self.guard_depth += guarded
         self.cadence_depth += cadenced
         for stmt in node.body:
@@ -198,6 +194,8 @@ class BlockingInHotLoop(Rule):
         for info in module.callgraph.functions.values():
             if GUARD_NAME_RE.search(info.name):
                 continue  # bench/profiling helpers sync on purpose
+            if not module.index.own(info.node, ast.For, ast.While):
+                continue  # a finding needs a loop of this function's own
             v = _LoopVisitor(self, module, info.qualname, blocking_callables)
             for stmt in info.node.body:
                 v.visit(stmt)

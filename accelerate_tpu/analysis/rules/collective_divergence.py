@@ -33,14 +33,71 @@ from __future__ import annotations
 import ast
 
 from ..engine import Finding, Rule
+from ..engine import _SCOPES as _NESTED_DEFS
 from ..taint import (
     FunctionTaint,
+    callee_names,
     collective_sink,
     rank_local_by_design,
     single_process_conjunct,
 )
 
-_NESTED_DEFS = (ast.FunctionDef, ast.AsyncFunctionDef, ast.ClassDef)
+
+class _Tokens:
+    """The collective tokens of one function's statements — a direct sink,
+    or a call into a function the program graph proved collective-bearing —
+    each statement's computed once, from its calls in the module index."""
+
+    def __init__(self, module, coll_map, self_prefix):
+        self.module, self.coll_map, self.self_prefix = module, coll_map, self_prefix
+        self.memo: dict[ast.stmt, list] = {}
+
+    def call(self, call):
+        tok = collective_sink(call, self.module)
+        if tok is not None:
+            return tok
+        for cand in callee_names(call.func, self.self_prefix):
+            if cand in self.coll_map:
+                return cand
+        return None
+
+    def expr(self, node):
+        index = self.module.index
+        hits = [(c, t) for c in index.walk(node, ast.Call) if (t := self.call(c))]
+        hits.sort(key=lambda h: index.depth[index.pos[h[0]]])  # ast.walk's order
+        return [t for _, t in hits]
+
+    def stmts(self, stmts):
+        """Tokens issued by a statement list, skipping nested defs (their
+        own call-graph nodes) and single-process-guarded branches
+        (unreachable on a multi-process run)."""
+        return [t for stmt in stmts for t in self.stmt(stmt)]
+
+    def stmt(self, stmt):
+        out = self.memo.get(stmt)
+        if out is not None:
+            return out
+        out = []
+        if isinstance(stmt, _NESTED_DEFS):
+            pass
+        elif isinstance(stmt, ast.If) and single_process_conjunct(stmt.test):
+            out = self.expr(stmt.test) + self.stmts(stmt.orelse)
+        else:
+            for child in ast.iter_child_nodes(stmt):
+                if isinstance(child, ast.stmt):
+                    out += self.stmt(child)
+                elif isinstance(child, ast.ExceptHandler):
+                    if child.type is not None:
+                        out += self.expr(child.type)
+                    out += self.stmts(child.body)
+                elif isinstance(child, ast.withitem):
+                    out += self.expr(child.context_expr)
+                elif isinstance(child, ast.match_case):
+                    out += self.stmts(child.body)
+                elif isinstance(child, ast.expr):
+                    out += self.expr(child)
+        self.memo[stmt] = out
+        return out
 
 
 class CollectiveDivergence(Rule):
@@ -74,6 +131,9 @@ class CollectiveDivergence(Rule):
                 if "." in info.qualname
                 else None
             )
+            tokens = _Tokens(module, coll_map, self_prefix)
+            if not any(map(tokens.call, module.index.own(info.node, ast.Call))):
+                continue  # no collective to branch, loop or exit around
             taint = FunctionTaint(
                 module, info.node, known=div_map, self_prefix=self_prefix
             )
@@ -95,9 +155,7 @@ class CollectiveDivergence(Rule):
                     )
                 )
 
-            self._scan(
-                info.node.body, [], module, taint, coll_map, fire
-            )
+            self._scan(info.node.body, [], tokens, taint, fire)
         return findings
 
     def _check_rank_local_contract(self, module) -> list[Finding]:
@@ -106,9 +164,7 @@ class CollectiveDivergence(Rule):
         level) is a finding, unconditionally — divergence analysis does not
         apply because the module must not collectivize at all."""
         findings: list[Finding] = []
-        for node in ast.walk(module.tree):
-            if not isinstance(node, ast.Call):
-                continue
+        for node in module.index.of_type(ast.Call):
             tok = collective_sink(node, module)
             if tok is None:
                 continue
@@ -127,60 +183,6 @@ class CollectiveDivergence(Rule):
                 )
             )
         return findings
-
-    # -- token collection ----------------------------------------------------
-    def _call_token(self, call, module, taint, coll_map):
-        """Collective token for one Call: a direct sink, or a call into a
-        function the program graph proved collective-bearing."""
-        tok = collective_sink(call, module)
-        if tok is not None:
-            return tok
-        for cand in taint.callee_names(call.func):
-            if cand in coll_map:
-                return cand
-        return None
-
-    def _expr_tokens(self, node, module, taint, coll_map):
-        out = []
-        for sub in ast.walk(node):
-            if isinstance(sub, ast.Call):
-                tok = self._call_token(sub, module, taint, coll_map)
-                if tok is not None:
-                    out.append(tok)
-        return out
-
-    def _tokens(self, stmts, module, taint, coll_map):
-        """Collective tokens issued by a statement list, skipping nested
-        defs (their own call-graph nodes) and single-process-guarded
-        branches (unreachable on a multi-process run)."""
-        out = []
-        for stmt in stmts:
-            if isinstance(stmt, _NESTED_DEFS):
-                continue
-            if isinstance(stmt, ast.If) and single_process_conjunct(stmt.test):
-                out += self._expr_tokens(stmt.test, module, taint, coll_map)
-                out += self._tokens(stmt.orelse, module, taint, coll_map)
-                continue
-            for child in ast.iter_child_nodes(stmt):
-                if isinstance(child, ast.stmt):
-                    out += self._tokens([child], module, taint, coll_map)
-                elif isinstance(child, ast.ExceptHandler):
-                    if child.type is not None:
-                        out += self._expr_tokens(
-                            child.type, module, taint, coll_map
-                        )
-                    out += self._tokens(child.body, module, taint, coll_map)
-                elif isinstance(child, ast.withitem):
-                    out += self._expr_tokens(
-                        child.context_expr, module, taint, coll_map
-                    )
-                elif hasattr(ast, "match_case") and isinstance(
-                    child, ast.match_case
-                ):
-                    out += self._tokens(child.body, module, taint, coll_map)
-                elif isinstance(child, ast.expr):
-                    out += self._expr_tokens(child, module, taint, coll_map)
-        return out
 
     def _exits(self, stmts):
         """Top-to-bottom ``return``/``raise`` statements inside a branch (any
@@ -201,38 +203,31 @@ class CollectiveDivergence(Rule):
                     out += self._exits([child])
                 elif isinstance(child, ast.ExceptHandler):
                     out += self._exits(child.body)
-                elif hasattr(ast, "match_case") and isinstance(
-                    child, ast.match_case
-                ):
+                elif isinstance(child, ast.match_case):
                     out += self._exits(child.body)
         return out
 
     # -- the statement scan ----------------------------------------------------
-    def _scan(self, stmts, tail, module, taint, coll_map, fire):
+    def _scan(self, stmts, tail, tokens, taint, fire):
         """``tail`` carries the collective tokens that follow the current
         block at every enclosing level — what an early exit would skip."""
+        suffix = [tail]  # suffix[k]: the tokens of stmts[-k:], then tail
+        for stmt in reversed(stmts):
+            suffix.append(tokens.stmt(stmt) + suffix[-1])
         for idx, stmt in enumerate(stmts):
             if isinstance(stmt, _NESTED_DEFS):
                 continue
-            after = (
-                self._tokens(stmts[idx + 1:], module, taint, coll_map) + tail
-            )
+            after = suffix[len(stmts) - idx - 1]
             if isinstance(stmt, ast.If):
                 if single_process_conjunct(stmt.test):
                     # the branch never executes multi-process: nothing inside
                     # it can diverge a mesh (the sanctioned PR-13 gate)
-                    self._scan(
-                        stmt.orelse, after, module, taint, coll_map, fire
-                    )
+                    self._scan(stmt.orelse, after, tokens, taint, fire)
                     continue
                 if taint.expr_tainted(stmt.test):
                     desc = taint.describe(stmt.test)
-                    body_toks = self._tokens(
-                        stmt.body, module, taint, coll_map
-                    )
-                    else_toks = self._tokens(
-                        stmt.orelse, module, taint, coll_map
-                    )
+                    body_toks = tokens.stmts(stmt.body)
+                    else_toks = tokens.stmts(stmt.orelse)
                     if sorted(body_toks) != sorted(else_toks):
                         fire(
                             stmt,
@@ -261,13 +256,13 @@ class CollectiveDivergence(Rule):
                                     "ranks never reach it, the rest block "
                                     "in it forever",
                                 )
-                self._scan(stmt.body, after, module, taint, coll_map, fire)
-                self._scan(stmt.orelse, after, module, taint, coll_map, fire)
+                self._scan(stmt.body, after, tokens, taint, fire)
+                self._scan(stmt.orelse, after, tokens, taint, fire)
             elif isinstance(stmt, ast.While):
                 if not single_process_conjunct(stmt.test) and taint.expr_tainted(
                     stmt.test
                 ):
-                    toks = self._tokens(stmt.body, module, taint, coll_map)
+                    toks = tokens.stmts(stmt.body)
                     if toks:
                         fire(
                             stmt,
@@ -278,11 +273,11 @@ class CollectiveDivergence(Rule):
                             "differ per rank, so the collective sequence "
                             "does too",
                         )
-                self._scan(stmt.body, after, module, taint, coll_map, fire)
-                self._scan(stmt.orelse, after, module, taint, coll_map, fire)
+                self._scan(stmt.body, after, tokens, taint, fire)
+                self._scan(stmt.orelse, after, tokens, taint, fire)
             elif isinstance(stmt, (ast.For, ast.AsyncFor)):
                 if taint.expr_tainted(stmt.iter):
-                    toks = self._tokens(stmt.body, module, taint, coll_map)
+                    toks = tokens.stmts(stmt.body)
                     if toks:
                         fire(
                             stmt,
@@ -293,20 +288,18 @@ class CollectiveDivergence(Rule):
                             "differ per rank, so the collective sequence "
                             "does too",
                         )
-                self._scan(stmt.body, after, module, taint, coll_map, fire)
-                self._scan(stmt.orelse, after, module, taint, coll_map, fire)
+                self._scan(stmt.body, after, tokens, taint, fire)
+                self._scan(stmt.orelse, after, tokens, taint, fire)
             elif isinstance(stmt, (ast.With, ast.AsyncWith)):
-                self._scan(stmt.body, after, module, taint, coll_map, fire)
+                self._scan(stmt.body, after, tokens, taint, fire)
             elif isinstance(stmt, ast.Try) or (
                 hasattr(ast, "TryStar") and isinstance(stmt, ast.TryStar)
             ):
-                self._scan(stmt.body, after, module, taint, coll_map, fire)
+                self._scan(stmt.body, after, tokens, taint, fire)
                 for h in stmt.handlers:
-                    self._scan(h.body, after, module, taint, coll_map, fire)
-                self._scan(stmt.orelse, after, module, taint, coll_map, fire)
-                self._scan(
-                    stmt.finalbody, after, module, taint, coll_map, fire
-                )
+                    self._scan(h.body, after, tokens, taint, fire)
+                self._scan(stmt.orelse, after, tokens, taint, fire)
+                self._scan(stmt.finalbody, after, tokens, taint, fire)
             elif isinstance(stmt, ast.Match):
                 for case in stmt.cases:
-                    self._scan(case.body, after, module, taint, coll_map, fire)
+                    self._scan(case.body, after, tokens, taint, fire)

@@ -32,14 +32,18 @@ def visible_donors(module, ctx) -> dict[str, list[int]]:
     graph resolved through imports — `from .opt import apply_grads` and
     `opt.apply_grads` both land here when `apply_grads` donates."""
     donors = dict(ctx.donor_aliases.get(module.rel_path, {}))
-    # memoized: two rules call this per module, and the engine-driven path
-    # already seeded ctx.donor_aliases from the same walk at summary time
-    local = getattr(module, "_donor_cache", None)
-    if local is None:
-        local = module._donor_cache = donating_callables(module)
-    for name, pos in local.items():
+    for name, pos in donating_callables(module).items():
         donors.setdefault(name, pos)
     return donors
+
+
+def calls_a_donor(module, fn_node, donors) -> bool:
+    """Whether a scope's own calls name a donating callable: without one
+    nothing in it can be donated."""
+    return any(
+        (fn.id if isinstance(fn, ast.Name) else dotted_name(fn)) in donors
+        for fn in (c.func for c in module.index.own(fn_node, ast.Call))
+    )
 
 
 class _LinearScanner(ast.NodeVisitor):
@@ -166,6 +170,8 @@ class DonationReuse(Rule):
             return []
         findings = []
         for info in module.callgraph.functions.values():
+            if not calls_a_donor(module, info.node, donors):
+                continue
             scanner = _LinearScanner(self, module, info.qualname, donors)
             for stmt in info.node.body:
                 scanner.visit(stmt)

@@ -59,47 +59,36 @@ class DtypeWiden(Rule):
         rel = module.rel_path.replace("\\", "/")
         return any(rel.endswith(p) for p in _POLICY_MODULES)
 
-    @staticmethod
-    def _scope_walk(root: ast.AST, skip_functions: bool):
-        """Descendants of ``root``; with ``skip_functions`` the bodies of
-        nested function defs are excluded (module scope must not see
-        function locals — a same-named local elsewhere is NOT the payload)."""
-        stack = list(ast.iter_child_nodes(root))
-        while stack:
-            node = stack.pop()
-            if skip_functions and isinstance(
-                node, (ast.FunctionDef, ast.AsyncFunctionDef)
-            ):
-                continue
-            yield node
-            stack.extend(ast.iter_child_nodes(node))
-
     def _check_payloads(self, module) -> list[Finding]:
         """Flag ``compress.quantize`` payload locals widened with a bare
         ``.astype`` — per SCOPE, so an unrelated same-named local in another
         function never fires.  A function scope includes its closures (an
-        outer payload cast inside a nested def is still the payload); the
+        outer payload cast inside a nested def is still the payload); module
+        scope skips function bodies (it must not see function locals).  The
         resulting double visit of nested nodes is de-duplicated."""
-        if self._is_policy_module(module):
+        index = module.index
+        quantized = [
+            node
+            for node in index.of_type(ast.Assign)
+            if isinstance(node.value, ast.Call)
+            and (module.resolve(node.value.func) or "").endswith("compress.quantize")
+        ]
+        if self._is_policy_module(module) or not quantized:
             return []
+        defs = index.of_type(ast.FunctionDef, ast.AsyncFunctionDef)
+        module_hidden = [(index.pos[d], index.end[index.pos[d]]) for d in defs]
         findings: list[Finding] = []
         seen: set[int] = set()
-        scopes: list[tuple[ast.AST, bool]] = [(module.tree, True)] + [
-            (node, False)
-            for node in ast.walk(module.tree)
-            if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef))
-        ]
-        for scope, skip_functions in scopes:
-            nodes = list(self._scope_walk(scope, skip_functions))
+        for scope in [module.tree] + defs:
+            lo, hi = index.pos[scope], index.end[index.pos[scope]]
+            hidden = module_hidden if scope is module.tree else []
+
+            def in_scope(node):
+                i = index.pos[node]
+                return lo < i < hi and not any(a <= i < b for a, b in hidden)
+
             payloads: set[str] = set()
-            for node in nodes:
-                if not (
-                    isinstance(node, ast.Assign) and isinstance(node.value, ast.Call)
-                ):
-                    continue
-                resolved = module.resolve(node.value.func) or ""
-                if not resolved.endswith("compress.quantize"):
-                    continue
+            for node in filter(in_scope, quantized):
                 for target in node.targets:
                     if isinstance(target, ast.Name):
                         payloads.add(target.id)
@@ -111,10 +100,9 @@ class DtypeWiden(Rule):
                         payloads.add(target.elts[0].id)
             if not payloads:
                 continue
-            for node in nodes:
+            for node in filter(in_scope, index.walk(scope, ast.Call)):
                 if (
-                    isinstance(node, ast.Call)
-                    and isinstance(node.func, ast.Attribute)
+                    isinstance(node.func, ast.Attribute)
                     and node.func.attr == "astype"
                     and node.args
                     and isinstance(node.func.value, ast.Name)
@@ -144,9 +132,7 @@ class DtypeWiden(Rule):
             )
 
         findings.extend(self._check_payloads(module))
-        for node in ast.walk(module.tree):
-            if not isinstance(node, ast.Call):
-                continue
+        for node in module.index.of_type(ast.Call):
             fn = node.func
             resolved = module.resolve(fn) or ""
             leaf = resolved.rsplit(".", 1)[-1]

@@ -12,7 +12,6 @@ from __future__ import annotations
 
 import ast
 
-from ..callgraph import iter_own_nodes
 from ..engine import Finding, Rule
 
 # methods that force a host transfer wherever they appear
@@ -21,19 +20,22 @@ _SINK_METHODS = {"item", "tolist"}
 _NUMPY_SINKS = {"asarray", "array", "ascontiguousarray", "copy"}
 _JAX_SINKS = {"jax.device_get"}
 _BUILTIN_CASTS = {"float", "int", "bool", "complex"}
+# calls whose result is host metadata, never a tracer
+_HOST_METADATA = {
+    "devices", "local_devices", "device_count", "local_device_count", "process_index",
+}
 
 
-def _is_static_expr(node: ast.AST) -> bool:
+def _is_static_expr(index, node: ast.AST) -> bool:
     """Expressions whose value is known at trace time (no host sync): python
     literals, ``len()``, and shape/ndim/size attribute reads."""
     if isinstance(node, ast.Constant):
         return True
     if isinstance(node, ast.Call) and isinstance(node.func, ast.Name):
         return node.func.id == "len"
-    for sub in ast.walk(node):
-        if isinstance(sub, ast.Attribute) and sub.attr in ("shape", "ndim", "size"):
-            return True
-    return False
+    return any(
+        sub.attr in ("shape", "ndim", "size") for sub in index.walk(node, ast.Attribute)
+    )
 
 
 class HostSyncInTrace(Rule):
@@ -51,9 +53,7 @@ class HostSyncInTrace(Rule):
     def check(self, module, ctx):
         findings = []
         for info, reason in module.callgraph.traced_functions():
-            for node in iter_own_nodes(info.node):
-                if not isinstance(node, ast.Call):
-                    continue
+            for node in module.index.own(info.node, ast.Call):
                 msg = self._sink_message(module, node)
                 if msg:
                     findings.append(
@@ -87,7 +87,7 @@ class HostSyncInTrace(Rule):
             and not self._host_metadata_arg(module, node)
             and len(node.args) == 1
             and not node.keywords
-            and not _is_static_expr(node.args[0])
+            and not _is_static_expr(module.index, node.args[0])
         ):
             return f"{fn.id}() concretizes a traced value to a python scalar"
         if isinstance(fn, ast.Attribute):
@@ -103,16 +103,8 @@ class HostSyncInTrace(Rule):
     def _host_metadata_arg(module, node: ast.Call) -> bool:
         """True when the argument is host metadata, never a tracer: device
         handles (``jax.devices()``), mesh/sharding topology queries."""
-        for arg in node.args:
-            for sub in ast.walk(arg):
-                if isinstance(sub, ast.Call):
-                    resolved = module.resolve(sub.func) or ""
-                    if resolved.rsplit(".", 1)[-1] in (
-                        "devices",
-                        "local_devices",
-                        "device_count",
-                        "local_device_count",
-                        "process_index",
-                    ):
-                        return True
-        return False
+        return any(
+            (module.resolve(sub.func) or "").rsplit(".", 1)[-1] in _HOST_METADATA
+            for arg in node.args
+            for sub in module.index.walk(arg, ast.Call)
+        )

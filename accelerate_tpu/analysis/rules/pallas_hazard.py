@@ -78,11 +78,8 @@ def _positional_params(fn_node) -> set[str]:
     return {a.arg for a in list(args.posonlyargs) + list(args.args)}
 
 
-def _mentions_any(test: ast.AST, names: set[str]) -> bool:
-    for node in ast.walk(test):
-        if isinstance(node, ast.Name) and node.id in names:
-            return True
-    return False
+def _mentions_any(index, test: ast.AST, names: set[str]) -> bool:
+    return any(node.id in names for node in index.walk(test, ast.Name))
 
 
 class _KernelBodyVisitor(ast.NodeVisitor):
@@ -125,7 +122,7 @@ class _KernelBodyVisitor(ast.NodeVisitor):
         self.generic_visit(node)
 
     def _check_branch(self, node, kind: str) -> None:
-        if _mentions_any(node.test, self.ref_params):
+        if _mentions_any(self.module.index, node.test, self.ref_params):
             self._flag(
                 node,
                 f"python-side {kind} on a kernel ref parameter bakes one "
@@ -192,6 +189,9 @@ class PallasHazard(Rule):
 
     def check(self, module, ctx):
         findings: list[Finding] = []
+        index = module.index
+        if not any(_call_leaf(c, module) == "pallas_call" for c in index.of_type(ast.Call)):
+            return findings
         # kernel functions by bare name, for call-site -> body resolution
         by_name = {}
         for info in module.callgraph.functions.values():
@@ -238,8 +238,8 @@ class PallasHazard(Rule):
             body_visitor.visit(stmt)
         findings = list(body_visitor.findings)
         outer_refs = body_visitor.ref_params
-        for node in ast.walk(target.node):
-            if isinstance(node, ast.FunctionDef) and node is not target.node:
+        for node in module.index.walk(target.node, ast.FunctionDef):
+            if node is not target.node:
                 nested = _KernelBodyVisitor(self, module, target)
                 nested.ref_params = outer_refs | _positional_params(node)
                 for stmt in node.body:

@@ -29,7 +29,6 @@ from __future__ import annotations
 import ast
 import re
 
-from ..callgraph import iter_own_nodes
 from ..engine import Finding, Rule
 
 # module-level constructors: leaf -> positional index of the shape argument
@@ -89,73 +88,52 @@ def _jit_sites(module):
                     for a in dec.args
                 ):
                     sites[info.qualname] = _jit_statics(dec, module)
-    for node in ast.walk(module.tree):
-        if not isinstance(node, ast.Call):
-            continue
-        resolved = module.resolve(node.func) or ""
-        if resolved.rsplit(".", 1)[-1] not in _JIT_LEAVES:
-            continue
-        if node.args and isinstance(node.args[0], ast.Name):
-            for info in cg.by_leaf.get(node.args[0].id, []):
-                sites.setdefault(info.qualname, _jit_statics(node, module))
+    calls = [
+        node
+        for node in module.index.of_type(ast.Call)
+        if (module.resolve(node.func) or "").rsplit(".", 1)[-1] in _JIT_LEAVES
+        and node.args
+        and isinstance(node.args[0], ast.Name)
+    ]
+    for node in module.index.as_walked(calls):
+        for info in cg.by_leaf.get(node.args[0].id, []):
+            sites.setdefault(info.qualname, _jit_statics(node, module))
     return sites
 
 
-def _dynamic_shape_names(expr: ast.AST) -> set[str]:
-    """Names a shape expression *dynamically* depends on.  ``x.shape[0]`` /
-    ``x.ndim`` / ``len(x)`` are static at trace time, so names that only
-    appear under those forms don't make the shape dynamic."""
-    static_subtrees: set[int] = set()
-    for node in ast.walk(expr):
-        is_static = (
-            isinstance(node, ast.Attribute) and node.attr in ("shape", "ndim", "size")
-        ) or (
-            isinstance(node, ast.Call)
-            and isinstance(node.func, ast.Name)
-            and node.func.id == "len"
-        )
-        if is_static:
-            for sub in ast.walk(node):
-                static_subtrees.add(id(sub))
+def _free_names(index, expr: ast.AST, hides) -> set[str]:
+    """Names in ``expr`` outside every subtree whose root ``hides``."""
+    hidden = [(index.pos[n], index.end[index.pos[n]]) for n in index.walk(expr) if hides(n)]
     return {
         n.id
-        for n in ast.walk(expr)
-        if isinstance(n, ast.Name) and id(n) not in static_subtrees
+        for n in index.walk(expr, ast.Name)
+        if not any(a <= index.pos[n] < b for a, b in hidden)
     }
 
 
-def _names_in_concretizing_positions(test: ast.AST):
-    """Names whose truthiness/ordering the test depends on — excluding
-    trace-safe forms (`x is None`, isinstance/hasattr/callable, len(), and
-    `.shape`/`.ndim`/`.size` reads, which are static at trace time)."""
-    out: set[str] = set()
-    skip: set[int] = set()
-    for node in ast.walk(test):
-        if id(node) in skip:
-            continue
-        if isinstance(node, ast.Compare) and all(
-            isinstance(op, (ast.Is, ast.IsNot)) for op in node.ops
-        ):
-            for sub in ast.walk(node):
-                skip.add(id(sub))
-        elif isinstance(node, ast.Attribute) and node.attr in ("shape", "ndim", "size"):
-            for sub in ast.walk(node):
-                skip.add(id(sub))
-        elif isinstance(node, ast.Call):
-            fn = node.func
-            if isinstance(fn, ast.Name) and fn.id in (
-                "isinstance",
-                "hasattr",
-                "callable",
-                "getattr",
-                "len",
-            ):
-                for sub in ast.walk(node):
-                    skip.add(id(sub))
-    for node in ast.walk(test):
-        if id(node) not in skip and isinstance(node, ast.Name):
-            out.add(node.id)
-    return out
+def _static_read(node: ast.AST) -> bool:
+    """``x.shape`` / ``x.ndim`` / ``x.size`` and ``len(x)``: static at trace time."""
+    return (isinstance(node, ast.Attribute) and node.attr in ("shape", "ndim", "size")) or (
+        isinstance(node, ast.Call)
+        and isinstance(node.func, ast.Name)
+        and node.func.id == "len"
+    )
+
+
+def _dynamic_shape_names(index, expr: ast.AST) -> set[str]:
+    """Names a shape expression *dynamically* depends on: names that only
+    appear under a static read don't make the shape dynamic."""
+    return _free_names(index, expr, _static_read)
+
+
+def _trace_safe_test(node: ast.AST) -> bool:
+    """`x is None`, isinstance/hasattr/callable/getattr, len(), and
+    `.shape`/`.ndim`/`.size` reads: static at trace time."""
+    if isinstance(node, ast.Compare):
+        return all(isinstance(op, (ast.Is, ast.IsNot)) for op in node.ops)
+    if isinstance(node, ast.Call) and isinstance(node.func, ast.Name):
+        return node.func.id in ("isinstance", "hasattr", "callable", "getattr", "len")
+    return _static_read(node)
 
 
 # names whose assignment marks a captured-step callable
@@ -191,8 +169,8 @@ _ITER_WRAPPERS = {"enumerate", "zip", "tqdm", "islice", "cycle", "reversed"}
 
 def _captured_names(module) -> set[str]:
     out = set()
-    for node in ast.walk(module.tree):
-        if isinstance(node, ast.Assign) and isinstance(node.value, ast.Call):
+    for node in module.index.of_type(ast.Assign):
+        if isinstance(node.value, ast.Call):
             resolved = module.resolve(node.value.func) or ""
             if resolved.rsplit(".", 1)[-1] in _CAPTURE_LEAVES:
                 for t in node.targets:
@@ -201,11 +179,11 @@ def _captured_names(module) -> set[str]:
     return out
 
 
-def _has_raw_length_source(expr: ast.AST) -> bool:
+def _has_raw_length_source(index, expr: ast.AST) -> bool:
     """Does the expression derive from a per-request length — ``len(...)``
     or a ``.shape``/``.size`` read?  Those are exactly the values that must
     go through the bucketing helper before becoming a serving-program shape."""
-    for sub in ast.walk(expr):
+    for sub in index.walk(expr, ast.Call, ast.Attribute):
         if (
             isinstance(sub, ast.Call)
             and isinstance(sub.func, ast.Name)
@@ -217,8 +195,8 @@ def _has_raw_length_source(expr: ast.AST) -> bool:
     return False
 
 
-def _subtree_has_pad_evidence(node: ast.AST) -> bool:
-    for sub in ast.walk(node):
+def _subtree_has_pad_evidence(index, node: ast.AST) -> bool:
+    for sub in index.walk(node, ast.Name, ast.Attribute, ast.keyword):
         if isinstance(sub, ast.Name) and _PAD_EVIDENCE_RE.search(sub.id):
             return True
         if isinstance(sub, ast.Attribute) and _PAD_EVIDENCE_RE.search(sub.attr):
@@ -237,17 +215,14 @@ def _scope_params(scope) -> set[str]:
     return {p.arg for p in a.posonlyargs + a.args + a.kwonlyargs}
 
 
-def _assignment_in(scope, name: str):
-    """Last assignment to ``name`` among the scope's own statements —
-    ``iter_own_nodes`` stops at nested def/class bodies at any depth, so a
-    function under a module-level ``if`` is never scanned as module code."""
-    assigned = None
-    for node in iter_own_nodes(scope):
-        if isinstance(node, ast.Assign):
-            for t in node.targets:
-                if isinstance(t, ast.Name) and t.id == name:
-                    assigned = node.value
-    return assigned
+def _assignment_in(index, scope, name: str):
+    """First assignment to ``name`` in source order among the scope's own
+    statements — own nodes stop at nested def/class bodies at any depth, so
+    a function under a module-level ``if`` is never scanned as module code."""
+    for node in index.own(scope, ast.Assign):
+        if any(isinstance(t, ast.Name) and t.id == name for t in node.targets):
+            return node.value
+    return None
 
 
 def _loader_expr(module, expr: ast.AST, scope, _depth: int = 0):
@@ -262,13 +237,13 @@ def _loader_expr(module, expr: ast.AST, scope, _depth: int = 0):
     if isinstance(expr, ast.Name):
         if _PAD_EVIDENCE_RE.search(expr.id):
             return None  # `padded_loader` names its own mitigation
-        assigned = _assignment_in(scope, expr.id)
+        assigned = _assignment_in(module.index, scope, expr.id)
         if (
             assigned is None
             and scope is not module.tree
             and expr.id not in _scope_params(scope)
         ):
-            assigned = _assignment_in(module.tree, expr.id)
+            assigned = _assignment_in(module.index, module.tree, expr.id)
         if assigned is not None and not (
             isinstance(assigned, ast.Name) and assigned.id == expr.id
         ):
@@ -350,27 +325,28 @@ class RecompileHazard(Rule):
         (different device count, jax version, compression policy) would
         dispatch a wrong program instead of recompiling."""
         findings = []
-        cg = module.callgraph
-        scopes = [module.tree] + [info.node for info in cg.functions.values()]
+        index = module.index
+
+        def deserializes(node):
+            return (module.resolve(node.func) or "").rsplit(".", 1)[-1] in _DESERIALIZE_LEAVES
+
+        if not any(map(deserializes, index.of_type(ast.Call))):
+            return findings
+        scopes = [module.tree] + [info.node for info in module.callgraph.functions.values()]
         for scope in scopes:
-            calls = []
-            evidence = False
             # own statements only: a nested function's deserialize call (and
             # its fingerprint guard) is judged in the nested scope's own row
-            for node in iter_own_nodes(scope):
-                if isinstance(node, ast.Call):
-                    resolved = module.resolve(node.func) or ""
-                    if resolved.rsplit(".", 1)[-1] in _DESERIALIZE_LEAVES:
-                        calls.append(node)
-                name = None
-                if isinstance(node, ast.Name):
-                    name = node.id
-                elif isinstance(node, ast.Attribute):
-                    name = node.attr
-                elif isinstance(node, ast.Constant) and isinstance(node.value, str):
-                    name = node.value  # meta["fingerprint"]-style dict keys
-                if name and _FINGERPRINT_EVIDENCE_RE.search(name):
-                    evidence = True
+            calls = [c for c in index.own(scope, ast.Call) if deserializes(c)]
+            evidence = any(
+                # meta["fingerprint"]-style dict keys count as Constants
+                _FINGERPRINT_EVIDENCE_RE.search(
+                    node.id if isinstance(node, ast.Name)
+                    else node.attr if isinstance(node, ast.Attribute)
+                    else node.value if isinstance(node.value, str)
+                    else ""
+                )
+                for node in index.own(scope, ast.Name, ast.Attribute, ast.Constant)
+            )
             if not calls or evidence:
                 continue
             qual = getattr(scope, "name", "")
@@ -402,16 +378,15 @@ class RecompileHazard(Rule):
         without bucket/pad evidence compiles a fresh program per distinct
         request length — exactly the explosion the service exists to avoid."""
         findings = []
-        for node in ast.walk(module.tree):
-            if not isinstance(node, ast.Call):
-                continue
+        index = module.index
+        for node in index.of_type(ast.Call):
             resolved = module.resolve(node.func) or ""
             if resolved.rsplit(".", 1)[-1] not in _SERVING_ENTRY_LEAVES:
                 continue
             args = list(node.args) + [kw.value for kw in node.keywords]
-            if any(_subtree_has_pad_evidence(a) for a in args):
+            if any(_subtree_has_pad_evidence(index, a) for a in args):
                 continue
-            if any(_has_raw_length_source(a) for a in args):
+            if any(_has_raw_length_source(index, a) for a in args):
                 findings.append(
                     Finding(
                         self.id,
@@ -446,26 +421,19 @@ class RecompileHazard(Rule):
 
     def _scan_scope_loops(self, module, scope, captured):
         findings = []
-        for loop in iter_own_nodes(scope):
-            if not isinstance(loop, (ast.For, ast.AsyncFor)):
-                continue
+        index = module.index
+        for loop in index.own(scope, ast.For, ast.AsyncFor):
             loader = _loader_expr(module, loop.iter, scope)
-            if loader is None or _subtree_has_pad_evidence(loader):
+            if loader is None or _subtree_has_pad_evidence(index, loader):
                 continue
-            targets = {
-                n.id for n in ast.walk(loop.target) if isinstance(n, ast.Name)
-            }
-            for node in ast.walk(loop):
-                if not (
-                    isinstance(node, ast.Call)
-                    and isinstance(node.func, ast.Name)
-                    and node.func.id in captured
-                ):
+            targets = {n.id for n in index.walk(loop.target, ast.Name)}
+            for node in index.walk(loop, ast.Call):
+                if not (isinstance(node.func, ast.Name) and node.func.id in captured):
                     continue
                 feeds_batch = any(
-                    isinstance(n, ast.Name) and n.id in targets
+                    n.id in targets
                     for a in list(node.args) + [kw.value for kw in node.keywords]
-                    for n in ast.walk(a)
+                    for n in index.walk(a, ast.Name)
                 )
                 if feeds_batch:
                     findings.append(
@@ -493,9 +461,10 @@ class RecompileHazard(Rule):
                 Finding(self.id, module.rel_path, node.lineno, node.col_offset, msg, symbol=qual)
             )
 
-        for node in ast.walk(info.node):
+        index = module.index
+        for node in index.walk(info.node, ast.If, ast.While, ast.Call):
             if isinstance(node, (ast.If, ast.While)):
-                used = _names_in_concretizing_positions(node.test) & dynamic
+                used = _free_names(index, node.test, _trace_safe_test) & dynamic
                 for p in sorted(used):
                     hit(
                         node,
@@ -509,10 +478,7 @@ class RecompileHazard(Rule):
                 leaf = resolved.rsplit(".", 1)[-1]
                 if isinstance(fn, ast.Name) and fn.id == "range":
                     used = {
-                        n.id
-                        for a_ in node.args
-                        for n in ast.walk(a_)
-                        if isinstance(n, ast.Name)
+                        n.id for a_ in node.args for n in index.walk(a_, ast.Name)
                     } & dynamic
                     for p in sorted(used):
                         hit(
@@ -527,7 +493,7 @@ class RecompileHazard(Rule):
                         if kw.arg == "shape":
                             shape_arg = kw.value
                     if shape_arg is not None:
-                        used = _dynamic_shape_names(shape_arg) & dynamic
+                        used = _dynamic_shape_names(index, shape_arg) & dynamic
                         for p in sorted(used):
                             hit(
                                 node,
@@ -541,7 +507,7 @@ class RecompileHazard(Rule):
                     and not resolved.startswith(("jax.", "numpy"))
                 ):
                     used = set().union(
-                        set(), *(_dynamic_shape_names(a_) for a_ in node.args)
+                        set(), *(_dynamic_shape_names(index, a_) for a_ in node.args)
                     ) & dynamic
                     for p in sorted(used):
                         hit(

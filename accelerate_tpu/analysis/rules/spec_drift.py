@@ -76,7 +76,7 @@ def _normalize_spec(spec: list) -> list:
 
 def _plan_dicts(module):
     """Yield (plan_name, ast.Dict) for every literal sharding-plan binding."""
-    for node in ast.walk(module.tree):
+    for node in module.index.of_type(ast.Assign, ast.AnnAssign):
         targets = []
         if isinstance(node, ast.Assign):
             targets, value = node.targets, node.value
@@ -103,7 +103,7 @@ def _strategy_literals(module):
     """Yield (value, node) for every literal ``sharding_strategy`` binding:
     a keyword argument (``FullyShardedDataParallelPlugin(sharding_strategy=
     "NO_SHARD")``) or an assignment whose target name says so."""
-    for node in ast.walk(module.tree):
+    for node in module.index.of_type(ast.Call, ast.Assign, ast.AnnAssign):
         if isinstance(node, ast.Call):
             for kw in node.keywords:
                 if (

@@ -67,14 +67,11 @@ def _is_shape_attr(node: ast.AST) -> bool:
     return isinstance(node, ast.Attribute) and node.attr == "shape"
 
 
-def _names_in(node: ast.AST) -> list[str]:
-    out = []
-    for sub in ast.walk(node):
-        if isinstance(sub, ast.Name):
-            out.append(sub.id)
-        elif isinstance(sub, ast.Attribute):
-            out.append(sub.attr)
-    return out
+def _names_in(index, node: ast.AST) -> list[str]:
+    return [
+        sub.id if isinstance(sub, ast.Name) else sub.attr
+        for sub in index.walk(node, ast.Name, ast.Attribute)
+    ]
 
 
 class StageBoundaryVsPlan(Rule):
@@ -110,7 +107,10 @@ class StageBoundaryVsPlan(Rule):
                 )
             )
 
-        for node in ast.walk(module.tree):
+        index = module.index
+        for node in index.of_type(
+            ast.FunctionDef, ast.AsyncFunctionDef, ast.Call, ast.Subscript, ast.BinOp
+        ):
             if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 # def f(..., axis_name="pp"): every call site that omits the
                 # keyword rediscovers the axis through the default
@@ -138,7 +138,7 @@ class StageBoundaryVsPlan(Rule):
                         n
                         for a in list(node.args)
                         + [kw.value for kw in node.keywords]
-                        for n in _names_in(a)
+                        for n in _names_in(index, a)
                     ]
                     if any(_layer_orderish(n) for n in involved):
                         fire(
@@ -165,12 +165,9 @@ class StageBoundaryVsPlan(Rule):
                 resolved = module.resolve(fn) or ""
                 if resolved.rsplit(".", 1)[-1] in _SPEC_LEAVES:
                     for arg in list(node.args) + [kw.value for kw in node.keywords]:
-                        hits = [
-                            sub
-                            for sub in ast.walk(arg)
-                            if isinstance(sub, ast.Constant) and sub.value == _PP
-                        ]
-                        for sub in hits:
+                        for sub in index.walk(arg, ast.Constant):
+                            if sub.value != _PP:
+                                continue
                             fire(sub, "literal 'pp' axis in a PartitionSpec")
                     continue
                 # axis_name="pp" handed to some consumer-side collective
@@ -196,8 +193,8 @@ class StageBoundaryVsPlan(Rule):
                 # layers // pp_size-shaped span arithmetic: one side names
                 # layers, the other names a pp size — the hand-sliced span
                 # the plan's StagePlan.layer_spans replaces
-                left = [n.lower() for n in _names_in(node.left)]
-                right = [n.lower() for n in _names_in(node.right)]
+                left = [n.lower() for n in _names_in(index, node.left)]
+                right = [n.lower() for n in _names_in(index, node.right)]
 
                 def layerish(names):
                     return any("layer" in n for n in names)

@@ -35,7 +35,7 @@ import ast
 
 from ..callgraph import dotted_name
 from ..engine import Finding, Rule
-from .donation import visible_donors
+from .donation import calls_a_donor, visible_donors
 
 
 class _EscapeScanner(ast.NodeVisitor):
@@ -141,6 +141,8 @@ class TransitiveDonation(Rule):
             return []
         findings: list[Finding] = []
         for info in module.callgraph.functions.values():
+            if not calls_a_donor(module, info.node, donors):
+                continue
             scanner = _EscapeScanner(self, module, info.qualname, donors, escapers)
             for stmt in info.node.body:
                 scanner.visit(stmt)

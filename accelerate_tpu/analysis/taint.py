@@ -45,9 +45,9 @@ from __future__ import annotations
 
 import ast
 import re
-from typing import Dict, Iterable, List, Optional, Set, Tuple
+from typing import Dict, List, Optional, Set, Tuple
 
-from .callgraph import dotted_name, iter_own_nodes
+from .callgraph import dotted_name
 
 # ---------------------------------------------------------------------------
 # source tables
@@ -400,13 +400,8 @@ def collective_sink(node: ast.Call, module) -> Optional[str]:
 def collective_leaves(module, fn_node: ast.AST) -> List[str]:
     """Sorted collective-sink tokens issued directly in ``fn_node``'s own
     body (nested defs excluded — they are their own call-graph nodes)."""
-    out: Set[str] = set()
-    for sub in iter_own_nodes(fn_node):
-        if isinstance(sub, ast.Call):
-            tok = collective_sink(sub, module)
-            if tok:
-                out.add(tok)
-    return sorted(out)
+    calls = module.index.own(fn_node, ast.Call)
+    return sorted({tok for tok in (collective_sink(c, module) for c in calls) if tok})
 
 
 # ---------------------------------------------------------------------------
@@ -452,7 +447,8 @@ class FunctionTaint:
     def describe(self, node: ast.AST) -> str:
         """Best-effort token naming WHY an expression is divergent, for
         finding messages."""
-        for sub in ast.walk(node):
+        index = self.module.index
+        for sub in index.as_walked(index.walk(node, ast.Call, ast.Attribute, ast.Subscript)):
             if isinstance(sub, ast.Call):
                 src = divergence_source_call(sub, self.module)
                 if src:
@@ -463,9 +459,9 @@ class FunctionTaint:
                 src = divergence_source_subscript(sub, self.module)
                 if src:
                     return src
-        for sub in ast.walk(node):
+        for sub in index.as_walked(index.walk(node, ast.Call, ast.Name)):
             if isinstance(sub, ast.Call):
-                for cand in self.callee_names(sub.func):
+                for cand in callee_names(sub.func, self.self_prefix):
                     if cand in self.known:
                         return f"{cand}() [{self.known[cand]}]"
             elif isinstance(sub, ast.Name) and sub.id in self.tainted:
@@ -669,38 +665,36 @@ class FunctionTaint:
         for kw in node.keywords:
             at, ap = self.eval(kw.value)
             t, p = t or at, p | ap
-        for cand in self.callee_names(fn):
+        for cand in callee_names(fn, self.self_prefix):
             if cand in self.known:
                 t = True
             else:
                 p.add(cand)
         return t, p
 
-    def callee_names(self, fn: ast.AST) -> List[str]:
-        """Candidate callable names a Call's func may resolve to, in the
-        edge conventions ``program._resolve_edge`` / the alias maps use:
-        bare names for Name calls and ``self.x()`` (plus the enclosing
-        ``Cls.x`` qualname when known), full dotted names otherwise."""
-        if isinstance(fn, ast.Name):
-            return [] if fn.id in _BUILTIN_NOISE else [fn.id]
-        if isinstance(fn, ast.Attribute):
-            dotted = dotted_name(fn)
-            if dotted is None:
+
+def callee_names(fn: ast.AST, self_prefix: Optional[str]) -> List[str]:
+    """Candidate callable names a Call's func may resolve to, in the edge
+    conventions ``program._resolve_edge`` / the alias maps use: bare names
+    for Name calls and ``self.x()`` (plus the enclosing ``Cls.x`` qualname,
+    ``self_prefix``, when known), full dotted names otherwise."""
+    if isinstance(fn, ast.Name):
+        return [] if fn.id in _BUILTIN_NOISE else [fn.id]
+    if isinstance(fn, ast.Attribute):
+        dotted = dotted_name(fn)
+        if dotted is None:
+            return []
+        parts = dotted.split(".")
+        if parts[0] in ("self", "cls"):
+            if len(parts) != 2:
+                # self.logger.log(): the receiver is an attribute object
+                # of unknown type, not the enclosing class — resolving
+                # the leaf against our own methods would be a lie
                 return []
-            parts = dotted.split(".")
-            if parts[0] in ("self", "cls"):
-                if len(parts) != 2:
-                    # self.logger.log(): the receiver is an attribute object
-                    # of unknown type, not the enclosing class — resolving
-                    # the leaf against our own methods would be a lie
-                    return []
-                leaf = parts[1]
-                out = [leaf]
-                if self.self_prefix:
-                    out.append(f"{self.self_prefix}.{leaf}")
-                return out
-            return [dotted]
-        return []
+            leaf = parts[1]
+            return [leaf, f"{self_prefix}.{leaf}"] if self_prefix else [leaf]
+        return [dotted]
+    return []
 
 
 def return_flow(module, fn_node, self_prefix=None) -> Tuple[bool, List[str]]:

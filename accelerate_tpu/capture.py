@@ -309,7 +309,9 @@ class CapturedStep:
     def __call__(self, *args):
         # host phases (docs/telemetry.md §spans and scopes): assemble →
         # atpu/dispatch → writeback, three spans on the flight recorder's
-        # ring, from whose stamps StepRecord's phases are computed too
+        # ring, from whose stamps StepRecord's phases are computed too.  Each
+        # starts at the stamp that ended the one before, so together they
+        # cover the call
         spans = self._spans
         assemble = spans.span("atpu/step/assemble").__enter__()
         tel = self._telemetry
@@ -433,7 +435,7 @@ class CapturedStep:
             # the executable call alone (a drift rebuild or a resilience
             # retry, where one happens, is inside it and is split out of
             # StepRecord.dispatch_ms below)
-            with spans.span("atpu/dispatch") as launch:
+            with assemble.then("atpu/dispatch") as launch:
                 if tel is not None or self._aot_cache is not None:
                     # AOT-compiled entries (telemetry's split builds AND cache-
                     # armed builds) reject drifted input layouts instead of
@@ -490,7 +492,9 @@ class CapturedStep:
                 # it would silently trace every step until the next sample
                 prof.abort()
             raise
-        with spans.span("atpu/step/writeback") as writeback:
+        # from the dispatch's end stamp: on a sampled step the profiler
+        # window's close above falls in this span
+        with launch.then("atpu/step/writeback") as writeback:
             self._writeback(new_state)
             if self._uses_accumulate is None:
                 # first ever call: the trace just revealed whether the body

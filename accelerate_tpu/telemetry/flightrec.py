@@ -138,12 +138,20 @@ class Span:
 
     __slots__ = ("name", "fields", "start_ns", "end_ns", "_rec", "_ann")
 
-    def __init__(self, rec: "FlightRecorder", name: str, fields: dict):
+    def __init__(self, rec: "FlightRecorder", name: str, fields: dict, start_ns: int = 0):
         self._rec = rec
         self.name = name
         self.fields = fields
-        self.start_ns = self.end_ns = 0
+        # a nonzero start is a stamp already taken (:meth:`then`)
+        self.start_ns = start_ns
+        self.end_ns = 0
         self._ann = None
+
+    def then(self, name: str, /, **fields) -> "Span":
+        """The span that follows this closed one: it starts at this span's
+        end stamp, so adjacent spans share their boundary and together cover
+        their interval exactly."""
+        return Span(self._rec, name, fields, self.end_ns)
 
     @property
     def ms(self) -> float:
@@ -155,7 +163,8 @@ class Span:
             self._ann = _annotation(self.name)
             if self._ann is not None:
                 self._ann.__enter__()
-        self.start_ns = time.monotonic_ns() + rec._wall_offset_ns
+        if not self.start_ns:
+            self.start_ns = time.monotonic_ns() + rec._wall_offset_ns
         return self
 
     def __exit__(self, *exc) -> bool:

@@ -6,6 +6,7 @@ exposes N host devices via --xla_force_host_platform_device_count, so every
 sharding/collective path runs exactly the SPMD code it would on a pod.
 """
 
+import contextlib
 import os
 import sys
 
@@ -58,21 +59,43 @@ def _reset_singletons():
     PartialState._reset_state()
 
 
-@pytest.fixture
-def only_the_aot_store_skips_a_compile():
-    """For a test that stores an executable in the AOT store and loads it
-    again in the same process: the suite's persistent XLA cache (above) is
-    off while it runs.  An XLA:CPU executable that came out of that cache
-    serializes into an entry that loads and then dies at its first dispatch
-    ("Function iota_compare_fusion not found"), past verify-on-store; and
-    which programs are in it depends on whose compile once took over the 0.5 s
-    threshold (the tiny serving programs do, on a machine six workers load).
-    On a TPU the two layers compose; here a compile is a compile."""
+@contextlib.contextmanager
+def fresh_executables():
+    """Within it, every executable is compiled by this process: the suite's
+    persistent XLA cache (above) is off, and JAX's in-memory caches are
+    cleared on entry, since an earlier test in the same worker may have
+    loaded the same programs from a warm cache and ``lower().compile()``
+    would hand that executable back.  They are cleared again on exit, so no
+    later test is handed what was compiled here with the cache off.
+
+    An XLA:CPU executable that came out of the persistent cache is not the
+    one the compiler produced, in two ways the tests meet:
+
+    * it serializes into an AOT-store entry that loads and then dies at its
+      first dispatch ("Function iota_compare_fusion not found"), past
+      verify-on-store;
+    * it does not keep the order of two collectives that have no data
+      dependence (gpipe's token-count all-reduce over dp beside the ring's
+      collective-permute): the devices enter them in different orders and
+      the run deadlocks, where the freshly compiled program never does.
+
+    On a TPU the layers compose; here a compile is a compile."""
     import jax
     from jax.experimental.compilation_cache import compilation_cache
 
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", True)
-    compilation_cache.reset_cache()
+    jax.clear_caches()
+    try:
+        yield
+    finally:
+        jax.clear_caches()
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def compiled_in_this_process():
+    """The test runs inside :func:`fresh_executables`."""
+    with fresh_executables():
+        yield

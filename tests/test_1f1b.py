@@ -207,7 +207,7 @@ def test_1f1b_loss_decreases():
     assert losses[-1] < losses[0], losses
 
 
-def test_interleaved_grad_parity_with_gpipe_at_v2():
+def test_interleaved_grad_parity_with_gpipe_at_v2(compiled_in_this_process):
     """ISSUE 15 acceptance: the interleaved schedule (V=2, each device
     hosting two non-contiguous layer spans) trains identically to GPipe —
     loss trajectory AND updated parameters agree on a 4-layer trunk."""

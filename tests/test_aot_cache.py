@@ -31,7 +31,7 @@ from accelerate_tpu.nn.tape import Tensor
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "tools"))
 
-pytestmark = pytest.mark.usefixtures("only_the_aot_store_skips_a_compile")
+pytestmark = pytest.mark.usefixtures("compiled_in_this_process")
 
 
 @pytest.fixture(autouse=True)
@@ -570,6 +570,67 @@ def test_scope_map_persists_across_processes(tmp_path):
         any(p.startswith("atpu") for p in phases)
         for phases in warm["phases_per_sample"]
     ), f"warm samples lost the per-phase split: {warm['phases_per_sample']}"
+
+
+_WARM_CACHE_CHILD = r'''
+import sys
+
+sys.path.insert(0, "@REPO@/tests")
+sys.path.insert(0, "@REPO@")
+from conftest import fresh_executables  # the suite's setup: cache at $JAX_COMPILATION_CACHE_DIR
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from accelerate_tpu import CompilationCacheKwargs
+from accelerate_tpu.native.aot_cache import AOTCompilationCache, _deserialize
+
+
+def greedy_pick(lengths, logits):
+    """A serving program in small: each row's greedy token among its live
+    positions."""
+    live = jnp.arange(logits.shape[1])[None, :] < lengths[:, None]
+    return jnp.argmax(jnp.where(live, logits, -jnp.inf), axis=-1)
+
+
+args = (jnp.array([3, 5], jnp.int32), jnp.arange(32.0).reshape(2, 16))
+pick = jax.jit(greedy_pick)
+want = np.asarray(pick(*args))  # compiled, or loaded from the warm cache
+if sys.argv[1] == "store":
+    with fresh_executables():
+        cache = AOTCompilationCache(CompilationCacheKwargs(cache_dir=sys.argv[2]))
+        fp = cache.fingerprint()
+        assert cache.store("pick", fp, pick.lower(*args).compile(), None, "serve", "pick")
+        entry = cache.lookup("pick", fp, "serve", "pick")
+        got = np.asarray(_deserialize(entry, jax.devices()[:1])(*args))
+    np.testing.assert_array_equal(got, want)
+'''
+
+
+def test_a_warm_persistent_cache_never_reaches_the_store(tmp_path):
+    """What the AOT store holds was compiled by the process that stored it.
+    A first process fills a private persistent XLA cache with a tiny
+    serving program; a second loads that program from the cache and
+    dispatches it, then stores and loads it under ``fresh_executables`` and
+    dispatches the entry.  An executable that came out of the persistent
+    cache would load from the store and die at its first dispatch
+    ("Function iota_reduce_fusion not found"); the in-memory caches the
+    first dispatch filled are what would hand it back."""
+    import subprocess
+
+    child = tmp_path / "child.py"
+    child.write_text(_WARM_CACHE_CHILD.replace("@REPO@", REPO))
+    env = dict(os.environ)
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jax_cache")
+    env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+    for phase in ("warm", "store"):
+        proc = subprocess.run(
+            [sys.executable, str(child), phase, str(tmp_path / "aot")],
+            env=env, capture_output=True, text=True, timeout=300, cwd=REPO,
+        )
+        assert proc.returncode == 0, f"{phase} child failed\n{proc.stderr[-4000:]}"
+    assert os.listdir(tmp_path / "jax_cache"), "the first child cached nothing"
 
 
 def test_jax_cache_layer_disarmed_for_scope_dependent_runs(tmp_path, monkeypatch):

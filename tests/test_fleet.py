@@ -403,7 +403,7 @@ def test_resize_reshards_bitwise_and_resumes(tmp_path):
     assert events.count("resize") == 1
 
 
-def test_resize_prewarm_zero_recompiles(tmp_path):
+def test_resize_prewarm_zero_recompiles(tmp_path, compiled_in_this_process):
     """Acceptance: zero recompiles for programs served by the AOT-cache
     prewarm — a run whose resized topology was already compiled (a prior
     fleet at that dp, same store) resumes with the post-resize first step
@@ -795,7 +795,7 @@ def test_evaluate_window_serving_signals():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("aot_store", [False, True], ids=["compiled", "aot_store"])
-def test_autopilot_closed_loop_no_caller_polling(tmp_path, aot_store, only_the_aot_store_skips_a_compile):
+def test_autopilot_closed_loop_no_caller_polling(tmp_path, aot_store, compiled_in_this_process):
     """ISSUE acceptance: under an injected host_lost then host_gained plan
     the autopilot ALONE drives dp down and back up — the loop below never
     reads should_resize or calls resize — with final losses within 1e-3 of

@@ -896,6 +896,86 @@ def test_package_is_clean_and_fast():
     assert data["duration_s"] < 15.0, f"analysis took {data['duration_s']}s"
 
 
+_INDEX_SNIPPET = """
+import functools
+
+@functools.partial(jit, static_argnums=(0,))
+def outer(n, x=make(), *, y=other()):
+    z = [v * 2 for v in x if v]
+
+    @decorate(n)
+    def inner(a, b=default_of_inner()):
+        return helper(a) + lambda_user(lambda q: q + b)
+
+    class Local(Base):
+        attr = value()
+
+        def method(self):
+            return self.attr
+
+    if n > 1:
+        return inner(z) + Local().method()
+    return {k: v for k, v in zip(x, z)}[0]
+"""
+
+
+def _own_by_stack(fn_node):
+    """A scope's own nodes by the plain stack walk: its body short of
+    nested def/class bodies, with their decorators and defaults."""
+    import ast
+
+    stack, out = list(ast.iter_child_nodes(fn_node)), []
+    while stack:
+        node = stack.pop()
+        out.append(node)
+        if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef, ast.ClassDef)):
+            stack.extend(node.decorator_list)
+            if not isinstance(node, ast.ClassDef):
+                stack.extend(node.args.defaults + [d for d in node.args.kw_defaults if d])
+            continue
+        stack.extend(ast.iter_child_nodes(node))
+    return out
+
+
+def test_module_index_walks_what_ast_walk_does(tmp_path):
+    """The one walk of a module that every rule and the summary read:
+    ``walk`` yields what ``ast.walk`` does (in source order; ``as_walked``
+    gives back ast.walk's order), ``of_type`` and ``walk(node, *types)``
+    select by type, and ``own`` is a scope's body short of nested def/class
+    bodies, with their decorators and defaults."""
+    import ast
+
+    from accelerate_tpu.analysis.engine import ModuleInfo
+
+    f = tmp_path / "snippet.py"
+    f.write_text(_INDEX_SNIPPET)
+    mod = ModuleInfo(str(f), "snippet.py", _INDEX_SNIPPET)
+    index = mod.index
+
+    def own_nodes(nodes):  # the parser shares expression contexts and operators
+        return [n for n in nodes if isinstance(n, (ast.stmt, ast.expr, ast.arg, ast.keyword))]
+
+    for node in own_nodes(ast.walk(mod.tree)):
+        walked = own_nodes(ast.walk(node))
+        assert index.as_walked(own_nodes(index.walk(node))) == walked
+        assert index.walk(node, ast.Call, ast.Name) == [
+            n for n in index.walk(node) if isinstance(n, (ast.Call, ast.Name))
+        ]
+    assert index.of_type(ast.Call) == [n for n in index.walk(mod.tree) if isinstance(n, ast.Call)]
+    scopes = [mod.tree] + index.of_type(ast.FunctionDef, ast.ClassDef)
+    assert [s.name for s in scopes[1:]] == ["outer", "inner", "Local", "method"]
+    for scope in scopes:
+        want = own_nodes(_own_by_stack(scope))
+        got = own_nodes(index.own(scope))
+        assert sorted(map(id, got)) == sorted(map(id, want)), getattr(scope, "name", "<module>")
+        assert index.own(scope, ast.Call) == [n for n in got if isinstance(n, ast.Call)]
+    outer = scopes[1]
+    calls = {n.func.id for n in index.own(outer, ast.Call) if isinstance(n.func, ast.Name)}
+    # the nested def's decorator and default run in outer's scope; its body does not
+    assert {"decorate", "default_of_inner", "make", "other", "zip"} <= calls
+    assert not {"helper", "lambda_user", "value"} & calls
+
+
 # ---------------------------------------------------------------------------
 # donation-reuse: loop second pass (use-after-donate across iterations)
 # ---------------------------------------------------------------------------

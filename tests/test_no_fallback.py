@@ -251,7 +251,7 @@ def test_importing_the_test_harness_opens_no_backend():
     assert "INITIALIZED False" in proc.stdout
 
 
-def test_aot_store_loads_onto_the_meshs_devices_not_the_backends():
+def test_aot_store_loads_onto_the_meshs_devices_not_the_backends(compiled_in_this_process):
     """The jax 0.9 failure behind resize-prewarm: ``deserialize_and_load``
     defaults to every device of the backend, so a program compiled for a
     4-device sub-mesh came back expecting 8 shards."""

@@ -303,7 +303,7 @@ def test_non_transient_fault_raises(tiny_model, monkeypatch):
 
 @pytest.mark.parametrize("aot_store", [False, True], ids=["compiled", "aot_store"])
 def test_sigterm_drains_and_fresh_replica_resumes(tiny_model, tmp_path, monkeypatch, aot_store,
-                                                  only_the_aot_store_skips_a_compile):
+                                                  compiled_in_this_process):
     """Injected SIGTERM mid-decode: the guard's sticky flag drains the
     service (journal finalized, open rids reported); a fresh replica on the
     same journal completes every request, bitwise equal, zero lost.  Against
