@@ -86,7 +86,7 @@ test_core:
 	  tests/test_fp16_capture.py tests/test_autocast.py \
 	  tests/test_comm_hook.py tests/test_powersgd.py \
 	  tests/test_config_knobs.py \
-	  tests/test_tracking.py tests/test_telemetry.py tests/test_device_time.py tests/test_spans_scopes.py \
+	  tests/test_tracking.py tests/test_telemetry.py tests/test_device_time.py tests/test_spans_scopes.py tests/test_compile_spans.py \
 	  tests/test_utils_misc.py tests/test_compile_cache_placement.py \
 	  tests/test_no_fallback.py \
 	  tests/test_deepspeed_compat.py tests/test_param_offload.py -q

@@ -37,6 +37,7 @@ from .optim import LRScheduler, Optimizer
 from .optimizer import AcceleratedOptimizer, DynamicLossScaler
 from .scheduler import AcceleratedScheduler
 from .state import AcceleratorState, GradientState, PartialState
+from .telemetry import flightrec as _flightrec
 from .utils import operations as ops
 from .utils.dataclasses import (
     DataLoaderConfiguration,
@@ -561,6 +562,7 @@ class Accelerator:
         return PartialState().split_between_processes(inputs, apply_padding)
 
     # --------------------------------------------------------------- prepare
+    @_flightrec.spanned("atpu/setup/prepare")
     def prepare(self, *args, device_placement=None):
         """Re-bind user objects onto the mesh (reference accelerator.py:1283).
 

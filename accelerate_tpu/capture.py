@@ -850,11 +850,13 @@ class CapturedStep:
                 flat_args, _ = jax.tree_util.tree_flatten(args_template)
 
                 # the scopes are read back from this executable's text: its
-                # cache key has to see them (profiler.scopes_in_cache_key)
+                # cache key has to see them (profiler.scopes_in_cache_key).
+                # The build's spans are the recorder's listener's (JAX's own
+                # trace, lowering and compile events); its times are theirs
                 with _profiler.scopes_in_cache_key():
-                    with self._spans.span("atpu/trace") as tracing:
+                    with _flightrec.CompilePhases() as tracing:
                         lowered = jitted.lower(dev_leaves, host_leaves, *flat_args)
-                    with self._spans.span("atpu/compile") as compiling:
+                    with _flightrec.CompilePhases() as compiling:
                         compiled = lowered.compile()
                 self._last_build_ms = (tracing.ms, compiling.ms)
                 label = f"capture:{self._builds_total}"

@@ -191,6 +191,7 @@ class DecodeService:
     keep their GSPMD layouts — pools and activations inherit them.
     """
 
+    @flightrec.spanned("atpu/serve/init")
     def __init__(self, model, config: Optional[ServingConfig] = None, telemetry=None,
                  aot_cache=None, preemption_guard=None):
         from ..models.generation import ATTENTION, RECURRENT, layer_plan, stacked_params_for_mode

@@ -5,10 +5,12 @@ Four pillars, all default-OFF and zero-overhead when off:
 1. **Step-phase timing** (`timeline.py`) — every ``CapturedStep.__call__``
    records dataloader-wait / assembly / trace / compile / dispatch ms into a
    ring-buffered :class:`~.timeline.StepTimeline`.  The phases' spans
-   (``atpu/step/assemble``, ``atpu/dispatch``, ``atpu/step/writeback``, and
-   ``atpu/trace`` / ``atpu/compile`` on a build) are the flight recorder's
-   (pillar 8): always on, and visible in an xprof trace collected through
-   ``accelerator.profile()``.
+   (``atpu/step/assemble``, ``atpu/dispatch``, ``atpu/step/writeback``) are
+   the flight recorder's (pillar 8): always on, and visible in an xprof trace
+   collected through ``accelerator.profile()``.  A build's trace and compile
+   times are the recorder's own compile-phase spans (``atpu/trace``,
+   ``atpu/lower``, ``atpu/compile``), which its listener writes for every
+   program JAX builds in the process.
 2. **Recompile forensics** (`recompile.py`) — every new compiled variant is
    diffed against the previous cache key and emits a
    :class:`~.recompile.RecompileEvent` naming exactly what moved (arg

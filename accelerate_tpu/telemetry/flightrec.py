@@ -17,7 +17,8 @@ doing when it stopped*:
 * stagewise 1F1B tick dispatch (``parallel/stagewise.py``);
 * fleet vote / rendezvous / resize phases (``fleet/``);
 * serving admissions and decode windows (``serving/``);
-* checkpoint and AOT-store I/O (``checkpointing.py``, ``native/aot_cache.py``).
+* checkpoint and AOT-store I/O (``checkpointing.py``, ``native/aot_cache.py``);
+* set-up: ``Accelerator.prepare`` and ``DecodeService.__init__`` as spans.
 
 Each event is stamped with ``time.monotonic_ns()`` and a per-process
 sequence number; the rank is resolved lazily at dump time (recording must
@@ -37,6 +38,20 @@ the instants that mark work *about to start* (``step_begin``,
 ``decode_window``) stay instants: in a hang they are the proof of where the
 process stopped.
 
+**Compile phases and host pauses** are the recorder's own producers,
+registered once when the process recorder is made, and only when it is on:
+a ``jax.monitoring`` listener turns every jaxpr trace, MLIR lowering and
+backend compile JAX reports into an ``atpu/trace`` / ``atpu/lower`` /
+``atpu/compile`` span (``fun``; a compile also ``cache`` = ``hit`` where the
+persistent cache served it, ``miss`` where it was consulted and compiled,
+``off`` where it was not consulted), stamped when JAX reports it; a
+``gc.callbacks`` hook writes one ``atpu/gc`` span per generation-2
+collection.  They are the one producer of those names; an in-memory jit
+cache hit reports nothing and so writes nothing.  Written once the phase has
+ended, they carry no profiler annotation: they are on the ring alone.
+:class:`CompilePhases` hands a caller (``CapturedStep``'s build) the
+compile-phase spans written on its thread inside its block.
+
 **The ring clock** is the clock the profiler stamps its host events with:
 Unix-epoch nanoseconds (``CLOCK_REALTIME``; a profiler session then rebases
 every plane to its own start, a constant per session).  Stamps are taken
@@ -53,13 +68,17 @@ module is declared rank-local-by-design to the graftlint taint pass
 collective sink.
 
 Kill switch: ``ACCELERATE_FLIGHTREC=0`` turns recording into a no-op (the
-bench A/B's "off" arm); ``ACCELERATE_FLIGHTREC_CAPACITY`` resizes the ring
-(default 65,536 events: an engine step writes about seven, so a minute of
-serving at 15 ms a step still fits).
+bench A/B's "off" arm) and registers no listener;
+``ACCELERATE_FLIGHTREC_CAPACITY`` resizes the ring (default 65,536 events:
+an engine step writes about seven, so a minute of serving at 15 ms a step
+still fits).
 """
 
 from __future__ import annotations
 
+import collections
+import functools
+import gc
 import json
 import os
 import socket
@@ -192,6 +211,10 @@ class FlightRecorder:
         # monotonic ns up to which overwritten events reached (spans())
         self._overwritten_until_ns: Optional[int] = None
         self._lock = threading.Lock()
+        # ``(name, start_ns, end_ns, fields)`` of spans closed where the lock
+        # may be held by the very thread closing them (a collection that
+        # started inside ``_append``): appended at the next append or read
+        self._aside: collections.deque = collections.deque()
         # monotonic↔wall anchor: collective seqs align ranks *ordinally*;
         # the wall anchor lets tools place per-rank monotonic stamps on one
         # absolute timeline, and is what
@@ -224,6 +247,16 @@ class FlightRecorder:
 
     def _append(self, t_ns: int, kind: str, fields: dict, dur_ns: Optional[int]) -> None:
         # caller holds the lock
+        self._take_aside()
+        self._put(t_ns, kind, fields, dur_ns)
+
+    def _take_aside(self) -> None:
+        # caller holds the lock; a collection inside _put may set more aside
+        while self._aside:
+            name, start_ns, end_ns, fields = self._aside.popleft()
+            self._put(start_ns - self._wall_offset_ns, name, fields, end_ns - start_ns)
+
+    def _put(self, t_ns: int, kind: str, fields: dict, dur_ns: Optional[int]) -> None:
         i = self._n % self.capacity
         old = self._slots[i]
         if old is not None:
@@ -257,6 +290,13 @@ class FlightRecorder:
             self._append(
                 start_ns - self._wall_offset_ns, name, fields, max(0, end_ns - start_ns)
             )
+
+    def set_aside(self, name: str, start_ns: int, end_ns: int, /, **fields) -> None:
+        """:meth:`record_span` for a caller that may run while its own thread
+        holds the ring's lock (a ``gc.callbacks`` hook): takes no lock; the
+        span enters the ring at the next append or read."""
+        if self.enabled:
+            self._aside.append((name, start_ns, end_ns, fields))
 
     def note_collective(self, op: str, /, **fields) -> int:
         """Tick the collective-sequence counter and record the event.
@@ -315,6 +355,7 @@ class FlightRecorder:
         """Retained slots, oldest first — safe to call from the watchdog
         thread while producers keep appending."""
         with self._lock:
+            self._take_aside()
             n, cap = self._n, self.capacity
             slots = list(self._slots)
         return [s for s in (slots[i % cap] for i in range(max(0, n - cap), n)) if s is not None]
@@ -421,3 +462,140 @@ def note_collective(op: str, /, **fields) -> int:
 def span(name: str, /, **fields) -> Span:
     """Module-level shortcut: ``with flightrec.span("atpu/serve/step"):``."""
     return _RECORDER.span(name, **fields)
+
+
+def spanned(name: str):
+    """Decorator: every call of the function runs inside ``span(name)``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with _RECORDER.span(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
+
+
+# -- compile phases and host pauses (the recorder's own producers) -------------
+
+_JAX_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "atpu/trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "atpu/lower",
+    "/jax/core/compile/backend_compile_duration": "atpu/compile",
+}
+# fired inside a backend compile whenever the persistent cache is consulted,
+# and when it answered (``cache_misses`` fires only where an entry is written,
+# so it misses compiles under the cache's size or time thresholds)
+_CACHE_CONSULTED = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_compiling = threading.local()  # cache flags, open CompilePhases: per thread
+_listening = False
+_gc_start_ns = 0
+
+
+def _on_jax_event(event: str, **_) -> None:
+    if event == _CACHE_CONSULTED:
+        _compiling.consulted = True
+    elif event == _CACHE_HIT:
+        _compiling.hit = True
+
+
+def _on_jax_duration(event: str, duration_secs: float, **kwargs) -> None:
+    """One span per compile phase, on the ring's own clock: it ends now, as
+    JAX reports it, and started its duration before."""
+    name = _JAX_PHASES.get(event)
+    if name is None:
+        return
+    rec = _RECORDER
+    end_ns = rec.now_ns()
+    start_ns = end_ns - int(duration_secs * 1e9)
+    fields = {"fun": str(kwargs.get("fun_name", ""))}
+    if name == "atpu/compile":
+        here = _compiling
+        consulted, hit = getattr(here, "consulted", False), getattr(here, "hit", False)
+        here.consulted = here.hit = False
+        fields["cache"] = ("hit" if hit else "miss") if consulted else "off"
+    rec.record_span(name, start_ns, end_ns, **fields)
+    for phases in getattr(_compiling, "open", ()):
+        phases.spans.append((start_ns, end_ns))
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """One ``atpu/gc`` span per generation-2 collection.  A collection may
+    start inside any allocation, ``_append``'s under the lock among them, so
+    the span is set aside, never appended here."""
+    global _gc_start_ns
+    if info.get("generation") != 2:
+        return
+    try:
+        rec = _RECORDER
+        if phase == "start":
+            _gc_start_ns = rec.now_ns()
+        elif _gc_start_ns:
+            rec.set_aside("atpu/gc", _gc_start_ns, rec.now_ns(), gen=2,
+                          collected=info.get("collected", 0))
+            _gc_start_ns = 0
+    except Exception:  # never raise into a collection
+        pass
+
+
+def _listen() -> None:
+    """Register the listeners above, once per process, with the recorder on."""
+    global _listening
+    if _listening or not _RECORDER.enabled:
+        return
+    try:
+        from jax import monitoring
+    except ImportError:
+        return
+    monitoring.register_event_listener(_on_jax_event)
+    monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    gc.callbacks.append(_on_gc)
+    _listening = True
+
+
+def _union_ns(intervals) -> int:
+    """Length of the union of ``(start_ns, end_ns)`` intervals: nested spans
+    (an inner jit traced inside an outer one) count once, as the outermost."""
+    total, reach = 0, None
+    for s, e in sorted(intervals):
+        if reach is None or s > reach:
+            total += e - s
+            reach = e
+        elif e > reach:
+            total += e - reach
+            reach = e
+    return total
+
+
+class CompilePhases:
+    """``with CompilePhases() as p:`` — collects the compile-phase spans the
+    listener writes on this thread while the block runs; ``p.ms`` is their
+    outermost time.  Without the listener (recorder off) it is the block's
+    own time, so a caller's build times keep their meaning."""
+
+    __slots__ = ("spans", "start_ns", "end_ns")
+
+    def __init__(self):
+        self.spans: list = []
+        self.start_ns = self.end_ns = 0
+
+    def __enter__(self) -> "CompilePhases":
+        if not hasattr(_compiling, "open"):
+            _compiling.open = []
+        _compiling.open.append(self)
+        self.start_ns = time.monotonic_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.end_ns = time.monotonic_ns()
+        _compiling.open.remove(self)
+        return False
+
+    @property
+    def ms(self) -> float:
+        if not _listening:
+            return (self.end_ns - self.start_ns) / 1e6
+        return _union_ns(self.spans) / 1e6
+
+
+_listen()

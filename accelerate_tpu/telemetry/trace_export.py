@@ -9,8 +9,9 @@ https://ui.perfetto.dev, "Trace Event Format" JSON):
   ``step_begin``/``step_end`` pair per captured call is also the *anchor*
   that places the device stream on the absolute axis;
 * **host phases** — the ring's spans (``atpu/step/assemble``,
-  ``atpu/dispatch``, ``atpu/step/writeback``, ``atpu/trace``,
-  ``atpu/compile``, and the engine step's ``atpu/serve/*``) as complete
+  ``atpu/dispatch``, ``atpu/step/writeback``, the compile phases
+  ``atpu/trace`` / ``atpu/lower`` / ``atpu/compile``, ``atpu/gc``, and the
+  engine step's ``atpu/serve/*``) as complete
   ("X") events on the ``host phases`` track, at the stamps they were taken
   at.  A step whose spans the ring no longer holds (it wrapped, or the
   recorder is off) falls back to its ``StepRecord``'s durations laid out in

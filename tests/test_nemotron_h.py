@@ -26,6 +26,7 @@ from accelerate_tpu.models import nemotron_h
 from accelerate_tpu.native.kernels import ssm_step as ssm_kernel
 from accelerate_tpu.nn import moe
 from accelerate_tpu.ops import ssm
+from accelerate_tpu.telemetry import flightrec
 from benchmark import cells
 
 ref = cells.load_module("reference", "nemotron_h")
@@ -447,12 +448,21 @@ def test_the_benchmarks_runner_serves_the_family_and_its_readers_find_the_load(t
     cell, harness = tiny_cell
     monkeypatch.setattr(harness, "memory_peak_bytes", lambda: 0)
     monkeypatch.setattr(harness, "memory_in_use_bytes", lambda: 0)
+    # the ring as the benchmark's own process starts with it (this process's
+    # compiles would fill the shared one)
+    rec = flightrec.FlightRecorder()
+    monkeypatch.setattr(flightrec, "_RECORDER", rec)
     out = cell.runner.run(cell, 2**31 + 77, 0.6, False, time.perf_counter(),
                           {"platform": "cpu", "kind": "cpu", "count": 1})
     correct, compared = harness.decide(out["numbers"], cell.limits)
     assert correct, compared
     assert out["failed"] == 0 and out["counters"]["recompile_events"] == 0
     assert out["notes"]["tokens_compared"] > 15
+    # off the chip the readers' part ends at the ring's last program span: read
+    # the ring as it stood at the run's last engine step, since the reference's
+    # compiles and the collection that frees the program come after it
+    last_step = max(e["end_ns"] for e in rec.spans(0, rec.now_ns())[0] if e["name"] == "atpu/serve/step")
+    monkeypatch.setattr(rec, "now_ns", lambda: last_step)
     ctx = {"cell": cell, "counters": out["counters"], "planes": None, "summary": None,
            "peaks": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}}
     read = {name: cell.layer_metric(name).read(ctx) for name in cell.per_layer}

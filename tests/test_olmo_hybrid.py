@@ -25,6 +25,7 @@ import pytest
 from accelerate_tpu import DecodeService, ServingConfig
 from accelerate_tpu.models import olmo_hybrid
 from accelerate_tpu.ops import delta_rule, ssm
+from accelerate_tpu.telemetry import flightrec
 from benchmark import cells
 from benchmark import flops_olmo_hybrid as costs
 
@@ -477,12 +478,21 @@ def test_the_benchmarks_runner_serves_the_family_and_its_readers_find_their_scop
     # earlier tests of this process registered (an unrolled plan's decode among
     # them) is not this cell's
     monkeypatch.setattr(profiler, "_programs", {})
+    # the ring as the benchmark's own process starts with it (this process's
+    # compiles would fill the shared one)
+    rec = flightrec.FlightRecorder()
+    monkeypatch.setattr(flightrec, "_RECORDER", rec)
     out = cell.runner.run(cell, 2**31 + 77, 0.6, False, time.perf_counter(),
                           {"platform": "cpu", "kind": "cpu", "count": 1})
     correct, compared = harness.decide(out["numbers"], cell.limits)
     assert correct, compared
     assert out["failed"] == 0 and out["counters"]["recompile_events"] == 0
     assert out["notes"]["tokens_compared"] > 15
+    # off the chip the readers' part ends at the ring's last program span: read
+    # the ring as it stood at the run's last engine step, since the reference's
+    # compiles and the collection that frees the program come after it
+    last_step = max(e["end_ns"] for e in rec.spans(0, rec.now_ns())[0] if e["name"] == "atpu/serve/step")
+    monkeypatch.setattr(rec, "now_ns", lambda: last_step)
     ctx = {"cell": cell, "counters": out["counters"], "planes": None, "summary": None,
            "peaks": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}}
     read = {name: cell.layer_metric(name).read(ctx) for name in cell.per_layer}

@@ -265,9 +265,14 @@ def _call_spans(events):
         if e["name"] == "step_begin":
             calls.append({})
         elif calls and e["name"] in ("atpu/step/assemble", "atpu/dispatch", "atpu/step/writeback",
-                                     "atpu/trace", "atpu/compile", "step_end"):
+                                     "atpu/trace", "atpu/lower", "atpu/compile", "step_end"):
+            # the last of a name: an inner jit's trace closes inside the step's
             calls[-1][e["name"].rsplit("/", 1)[-1]] = e
     return calls
+
+
+def _ms(span):
+    return (span["end_ns"] - span["start_ns"]) / 1e6
 
 
 def test_captured_call_spans_cover_the_call_and_equal_the_step_record():
@@ -278,7 +283,7 @@ def test_captured_call_spans_cover_the_call_and_equal_the_step_record():
     calls = _call_spans(rec.spans(0, rec.now_ns())[0])
     records = acc.telemetry.timeline.records()
     assert len(calls) == len(records) == 4
-    assert {"trace", "compile"} <= set(calls[0]) and "trace" not in calls[1]
+    assert {"trace", "lower", "compile"} <= set(calls[0]) and "trace" not in calls[1]
     for k, (spans, record) in enumerate(zip(calls, records)):
         a, d, w, end = spans["assemble"], spans["dispatch"], spans["writeback"], spans["step_end"]
         assert a["step"] == record.step == k and a["built"] == record.built == (k == 0)
@@ -294,7 +299,13 @@ def test_captured_call_spans_cover_the_call_and_equal_the_step_record():
         assert record.assembly_ms + built_ms == pytest.approx((d["start_ns"] - a["start_ns"]) / 1e6, abs=1e-6)
         assert record.dispatch_ms == pytest.approx((w["end_ns"] - d["start_ns"]) / 1e6, abs=1e-6)
         if k == 0:
-            assert record.trace_ms == pytest.approx((spans["trace"]["end_ns"] - spans["trace"]["start_ns"]) / 1e6)
+            # the build's times are the recorder's listener's spans of the
+            # step's program: trace + lower, then compile
+            trace, lower, compiling = spans["trace"], spans["lower"], spans["compile"]
+            assert (trace["fun"], lower["fun"], compiling["fun"]) == ("traced", "jit(traced)", "jit(traced)")
+            assert compiling["cache"] in ("hit", "miss")
+            assert record.trace_ms == pytest.approx(_ms(trace) + _ms(lower))
+            assert record.compile_ms == pytest.approx(_ms(compiling))
 
 
 def test_captured_call_spans_exist_with_telemetry_off():
@@ -303,7 +314,14 @@ def test_captured_call_spans_exist_with_telemetry_off():
     for _ in range(2):
         step(batch)
     calls = _call_spans(flightrec.recorder().spans(0, flightrec.recorder().now_ns())[0])
-    assert [sorted(c) for c in calls] == [["assemble", "dispatch", "step_end", "writeback"]] * 2
+    # the plain-jit build inside the first dispatch: its compile phases are
+    # the recorder's listener's, telemetry on or off
+    assert [sorted(c) for c in calls] == [
+        ["assemble", "compile", "dispatch", "lower", "step_end", "trace", "writeback"],
+        ["assemble", "dispatch", "step_end", "writeback"],
+    ]
+    assert calls[0]["dispatch"]["start_ns"] <= calls[0]["trace"]["start_ns"]
+    assert calls[0]["compile"]["end_ns"] <= calls[0]["dispatch"]["end_ns"]
     assert [c["assemble"]["step"] for c in calls] == [0, 1]
 
 

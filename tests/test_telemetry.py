@@ -601,7 +601,9 @@ def test_captured_step_records_flight_events_without_telemetry(monkeypatch):
     """The recorder is the default-off convention's one exception: with
     telemetry fully off, captured-step begin/end still lands in the ring
     (with a locally-maintained step index)."""
-    fresh = FlightRecorder(capacity=64)
+    # room for the build's compile spans (the recorder's listener) beside
+    # the step events
+    fresh = FlightRecorder(capacity=4096)
     monkeypatch.setattr(flightrec, "_RECORDER", fresh)
     nn.manual_seed(0)
     acc = Accelerator()  # telemetry off
@@ -792,8 +794,9 @@ def test_watchdog_start_displaces_prior_instance(tmp_path):
 def test_trace_export_writes_joinable_tracks(tmp_path, monkeypatch):
     from accelerate_tpu.telemetry.trace_export import validate_trace
 
-    # fresh ring: the process-global recorder carries earlier tests' steps
-    monkeypatch.setattr(flightrec, "_RECORDER", FlightRecorder(capacity=256))
+    # fresh ring: the process-global recorder carries earlier tests' steps;
+    # room for the build's compile spans beside them
+    monkeypatch.setattr(flightrec, "_RECORDER", FlightRecorder(capacity=4096))
     trace_path = str(tmp_path / "trace.json")
     acc, _, step = _make_step(profile_every_n=1, trace_export_path=trace_path)
     for _ in range(2):
