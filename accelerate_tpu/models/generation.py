@@ -79,7 +79,8 @@ class DecoderFamily:
       the layer's rank in it, ``live: (slots, 1)`` bool, ``mesh`` the pools'
       mesh where it has several devices (else ``None``).  The hook hands back
       the WHOLE state pool with layer ``i``'s rows updated where they lie (a
-      kernel that takes the pool in place: Mamba-2's over the live slots), and
+      kernel that takes the pool in place: Mamba-2's and the delta rule's, over
+      the live slots), and
       layer ``i``'s new tail, which the engine writes
     - ``recurrent_scopes`` — the two ``jax.named_scope`` names under which the
       engine writes a layer's rows of the state pool, in prefill and in decode:

@@ -285,12 +285,15 @@ def gdn_prefill(l, x, true_len, cfg):
 
 
 def gdn_step(l, x, pools, i, live, cfg, mesh=None):
-    """One token for every slot, live or not: ``x: (slots, 1, c)``; layer
-    ``i``'s rows of the state pools ``pools`` (``{"ssm", "conv"}``) read, and
-    the state pool handed back with them updated, beside the new tail.  The
-    plain ``jnp`` step (``ops/delta_rule.py``) over every slot: ``live`` and
-    ``mesh`` are not needed."""
-    state, tail = pools["ssm"][i], pools["conv"][i]
+    """One token for every slot: ``x: (slots, 1, c)``; ``pools`` the state
+    pools (``{"ssm", "conv"}``), ``i`` this layer's rank in them, ``live:
+    (slots, 1)``.  The recurrence runs over the live slots alone, in place in
+    the whole state pool, which comes back beside the new tail
+    (``native/kernels/gdn_step.py``; imported here, at trace time).  A dead
+    slot's state is left as it is and its ``o`` is zeros."""
+    from ..native.kernels import gdn_step as kernel
+
+    tail = pools["conv"][i]
     with jax.named_scope("atpu_serve_gdn_in"):
         qkv, z, a, b = _gdn_in(l, x[:, 0])
     with jax.named_scope("atpu_serve_gdn_conv"):
@@ -298,12 +301,10 @@ def gdn_step(l, x, pools, i, live, cfg, mesh=None):
         q, k, v = _split_qkv(jax.nn.silu(conv), cfg)
     with jax.named_scope("atpu_serve_gdn_step"):
         g, beta = _decay_and_beta(a, b, l, cfg)
-        o, state = delta_rule.delta_rule_step(state, q, k, v, g, beta)
+        o, state = kernel.gdn_step_live(pools["ssm"], i, live[:, 0], q, k, v, g, beta, mesh=mesh)
     with jax.named_scope("atpu_serve_gdn_out"):
         mixed = _gate_norm_out(o, z, l, x, cfg)
-    x = _after_mixer(l, x[:, 0], mixed, cfg)[:, None]
-    with jax.named_scope("atpu_serve_gdn_step"):
-        return x, pools["ssm"].at[i].set(state), tail
+    return _after_mixer(l, x[:, 0], mixed, cfg)[:, None], state, tail
 
 
 def _embed(g, ids, positions, cfg):

@@ -7,16 +7,12 @@ decode program runs it.  For head ``h`` of group ``g = h // (H // G)``::
     S'[h] = exp(dt[h] A[h]) S[h] + (dt[h] x[h]) (outer) B[g]        # (P, N)
     y[h]  = S'[h] @ C[g] + D[h] x[h]
 
-One grid step a slot, over the LIVE slots only: the program compacts the live
-mask into a list of slot ids (ascending, then the last live id repeated) and a
-count, both scalar-prefetched.  A step past the count maps every block to the
-last live step's, so the pipeline starts no copy for it and ``pl.when`` skips
-its compute: a dead slot's state is neither read nor written.  A live slot's
-``(H, P, N)`` float32 state (2 MiB at Nemotron-H's 64 × 64 × 128) is read
-into VMEM once, updated, summed against ``C`` from the same tile and written
-back once — the whole pool is the kernel's operand and its output, aliased,
-and the layer's rank picks the rows: a slice of it would be materialised as
-the custom call's operand.
+One grid step a LIVE slot, in place in the whole pool: the live list, the
+scalar prefetch, the aliasing and the ``shard_map`` on a mesh are
+``live_slots.py``'s, shared with the delta rule's step (``gdn_step.py``).  A
+live slot's ``(H, P, N)`` float32 state (2 MiB at Nemotron-H's 64 × 64 × 128)
+is read into VMEM once, updated, summed against ``C`` from the same tile and
+written back once; a dead slot's is neither read nor written.
 
 **Heads down the lanes.**  Inside the tile the state's ``P`` runs down the
 sublanes and ``N`` across the lanes, so a head's ``x·dt`` is wanted as a column
@@ -52,29 +48,16 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec
 
 from .pallas_import import import_pallas
 
 pl, pltpu = import_pallas()
 
-from ...ops import flash_attention  # noqa: E402
-from ...parallel.mesh import shard_map_compat  # noqa: E402
+from ...ops import flash_attention  # noqa: E402  (imports Pallas: after import_pallas)
+from . import live_slots  # noqa: E402
+from .live_slots import per_slot  # noqa: E402
 
 __all__ = ["ssm_step_live"]
-
-
-def _live_list(live):
-    """``(ids, count)`` of a ``(slots,)`` live mask: the live slots' ids in
-    ascending order, then the last of them repeated to ``slots`` entries (the
-    last slot's where none is live), and how many are live."""
-    slots = live.shape[0]
-    seen = jnp.cumsum(live.astype(jnp.int32))  # live slots up to and including each
-    count = seen[-1]
-    step = jnp.minimum(jnp.arange(slots, dtype=jnp.int32), jnp.maximum(count - 1, 0))
-    # the (k+1)-th live slot is the number of slots with k or fewer live up to them
-    ids = jnp.sum(seen[None, :] <= step[:, None], axis=1, dtype=jnp.int32)
-    return jnp.minimum(ids, slots - 1), count
 
 
 def _kernel(ids, count, layer, keep_ref, xdt_ref, b_ref, c_ref, s_ref, o_ref, y_ref, *, rep: int):
@@ -107,33 +90,12 @@ def _kernel(ids, count, layer, keep_ref, xdt_ref, b_ref, c_ref, s_ref, o_ref, y_
 def _call(pool, layer, ids, count, keep, xdt, b, c, *, interpret: bool, mesh):
     _, slots, heads, p, n = pool.shape
     groups = b.shape[1]
-
-    def per_slot(*block):
-        return pl.BlockSpec(block, lambda k, ids, count, layer: (ids[k],) + (0,) * (len(block) - 1))
-
-    state = pl.BlockSpec((1, 1, heads, p, n), lambda k, ids, count, layer: (layer[0], ids[k], 0, 0, 0))
-    call = pl.pallas_call(
-        functools.partial(_kernel, rep=heads // groups),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,  # ids, count, layer
-            grid=(slots,),
-            in_specs=[per_slot(1, 1, heads), per_slot(1, p, heads), per_slot(1, groups, n),
-                      per_slot(1, groups, n), state],
-            out_specs=[state, per_slot(1, p, heads)],
-        ),
-        out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-                   jax.ShapeDtypeStruct((slots, p, heads), jnp.float32)],
-        input_output_aliases={7: 0},  # the pool (after ids, count, layer, keep, xdt, b, c)
-        interpret=interpret,
-        name="ssm_step",
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)
-        ),
+    return live_slots.over_live_slots(
+        functools.partial(_kernel, rep=heads // groups), pool, layer, ids, count, (keep, xdt, b, c),
+        [per_slot(1, 1, heads), per_slot(1, p, heads), per_slot(1, groups, n), per_slot(1, groups, n)],
+        [per_slot(1, p, heads)], [jax.ShapeDtypeStruct((slots, p, heads), jnp.float32)],
+        name="ssm_step", interpret=interpret, mesh=mesh,
     )
-    if mesh is not None and mesh.size > 1:
-        whole = PartitionSpec()
-        call = shard_map_compat(call, mesh, (whole,) * 8, (whole, whole))
-    return call(ids, count[None], layer[None], keep, xdt, b, c, pool)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "mesh"))
@@ -141,7 +103,7 @@ def _step(pool, layer, live, x, dt, a, b, c, d, *, interpret: bool, mesh):
     f32 = jnp.float32
     x, dt = x.astype(f32), dt.astype(f32)
     keep = jnp.exp(dt * a.astype(f32))  # (S, H)
-    ids, count = _live_list(live)
+    ids, count = live_slots.live_list(live)
     pool, y = _call(
         pool, layer, ids, count, keep[:, None, :], jnp.swapaxes(x * dt[..., None], 1, 2),
         b.astype(f32), c.astype(f32), interpret=interpret, mesh=mesh,

@@ -1,6 +1,10 @@
 """The gated delta rule (Gated DeltaNet; Yang, Kautz, Hatamizadeh 2024) in plain
-``jax.numpy``: the chunked scan a prefill runs and the one-token recurrence a
-decode step runs.
+``jax.numpy``: the chunked scan a prefill runs, and the one-token recurrence
+as the plain statement of what a decode step runs.  The decode program runs
+that step as a Pallas kernel over the live slots, in place in the state pool
+(``native/kernels/gdn_step.py``: the same float32 math, the sums over ``k``
+rows in another order); ``delta_rule_step`` is the kernel's reference in the
+tests.
 
 Per head, with a state ``S`` of ``(d_k, d_v)``, a log-decay ``g_t <= 0`` and a
 write strength ``beta_t`` (up to 2 where negative eigenvalues are allowed)::

@@ -88,9 +88,9 @@ would pay one kernel lowering each and the Pallas import at every start; so the
 kernel module is imported in the scan's branch at trace time, and an unrolled
 plan's attention layers lower to the text they lowered to before the kernel
 came (PERF.md §6).  (A recurrent layer's decode step is the family's own:
-Mamba-2's takes a kernel over the live slots, one lowering for all its layers
-— ``recurrent`` in ``_decode_body``.)  A period's attention layers sit in a
-scan's body: one lowering a program, and a family with 30 kv heads of 128 could not be served by the gather at all
+Mamba-2's and the delta rule's each take a kernel over the live slots, one
+lowering for all layers of the kind — ``recurrent`` in ``_decode_body``.)  A
+period's attention layers sit in a scan's body: one lowering a program, and a family with 30 kv heads of 128 could not be served by the gather at all
 (4.5 GB gathered and re-laid a step: PERF.md, PR 36).  The
 writers pad a token's row to the page's lanes only where the page has pad
 lanes (``_pad_lanes``), so a family whose ``n_kv·d`` is a multiple of 128

@@ -24,6 +24,7 @@ import pytest
 
 from accelerate_tpu import DecodeService, ServingConfig
 from accelerate_tpu.models import olmo_hybrid
+from accelerate_tpu.native.kernels import gdn_step as gdn_kernel
 from accelerate_tpu.ops import delta_rule, ssm
 from accelerate_tpu.telemetry import flightrec
 from benchmark import cells
@@ -360,6 +361,54 @@ def test_a_nan_in_a_dead_slot_reaches_no_live_one_and_admission_resets_it(params
     svc.pool.check_no_leaks()
 
 
+# -- (d') the decode step's kernel: the live slots alone, in place ----------------
+@pytest.mark.parametrize("live", [
+    (0, 0, 0, 0, 0, 0), (1, 1, 1, 1, 1, 1), (0, 0, 1, 0, 0, 0), (1, 0, 1, 1, 0, 1), (0, 0, 0, 0, 0, 1),
+], ids=["none-live", "all-live", "one-live", "non-contiguous", "last-slot-only"])
+def test_the_step_kernel_is_the_plain_step_on_live_slots_and_leaves_the_rest(live):
+    """``native/kernels/gdn_step.py`` (the interpreter here) against
+    ``ops/delta_rule.py::delta_rule_step`` on layer 1 of a three-layer pool,
+    packed two k rows a row of lanes as the cell's state is (``d_k`` 16,
+    ``d_v`` 64: ``(8, 128)``): the live slots' ``o`` and new state agree to
+    float32 rounding (1e-5: the sums over k rows in another order); the dead
+    slots' rows and the other layers' rows are the pool's own, bit for bit,
+    though the dead rows hold NaN; a dead slot's ``o`` is zeros."""
+    n_layers, slots, h, d_k, d_v = 3, 6, 3, 16, 64
+    ks = jax.random.split(jax.random.PRNGKey(sum(live) + 11), 6)
+    live = np.asarray(live, bool)
+    pool = delta_rule.pack_state(jax.random.normal(ks[0], (n_layers, slots, h, d_k, d_v)))
+    assert pool.shape[-2:] == (8, 128)
+    pool = pool.at[1].set(jnp.where(live[:, None, None, None], pool[1], jnp.nan))
+    q, k = jax.random.normal(ks[1], (slots, h, d_k)), jax.random.normal(ks[2], (slots, h, d_k))
+    v = jax.random.normal(ks[3], (slots, h, d_v))
+    g = -jax.nn.softplus(jax.random.normal(ks[4], (slots, h)))
+    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(ks[5], (slots, h)))  # past 1: a negative eigenvalue
+    want_o, want_state = (np.asarray(t) for t in delta_rule.delta_rule_step(pool[1], q, k, v, g, beta))
+    o, new = (np.asarray(t) for t in gdn_kernel.gdn_step_live(pool, 1, jnp.asarray(live), q, k, v, g, beta))
+    before = np.asarray(pool)
+    np.testing.assert_allclose(o[live], want_o[live], rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(new[1][live], want_state[live], rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(o[~live], 0.0)
+    assert np.isfinite(o).all()
+    assert new[1][~live].tobytes() == before[1][~live].tobytes()
+    assert new[[0, 2]].tobytes() == before[[0, 2]].tobytes()
+
+
+def test_state_slots_walked_counts_the_decoding_slots_a_linear_layer(params, prompts):
+    """``stats["state_slots_walked"]``: a request of ``m`` tokens decodes ``m -
+    1`` of them (the prefill samples the first), each in every linear layer (6
+    of ``[linear x 3, full] x 2``; the cell's 16 layers hold 12) — what the
+    kernel walks, host arithmetic, and the ring's step spans carry it."""
+    tapped = Tapped(params)
+    tapped.run(prompts[:4], [m for _, m in REQUESTS[:4]])
+    service = tapped.service
+    want = PERIOD.count("linear_attention") * 2 * sum(m - 1 for _, m in REQUESTS[:4])
+    assert service.stats["state_slots_walked"] == want
+    steps = [e for e in flightrec.recorder().snapshot() if e["kind"] == "atpu/serve/step"]
+    mine = steps[-service.stats["steps"]:]
+    assert sum(e.get("state_slots_walked", 0) for e in mine) == want
+
+
 # -- (e) a lower precision than stated fails (a) -----------------------------------
 def _bf16(x):
     return x.astype(jnp.bfloat16).astype(x.dtype)
@@ -370,9 +419,11 @@ def test_a_lower_precision_than_stated_fails_the_comparison(params, prompts, mon
     """The state pool, the decay or the l2 norms in bfloat16: the logits leave
     the reference by more than five times ``LOGIT_TOL`` (13, 20 and 160 times)."""
     if what == "state":
-        step, chunked = delta_rule.delta_rule_step, delta_rule.delta_rule_chunked
-        monkeypatch.setattr(delta_rule, "delta_rule_step",
-                            lambda s, *a: (lambda o, s2: (o, _bf16(s2)))(*step(_bf16(s), *a)))
+        # what the programs run: the decode step's kernel over the whole pool
+        # (rounding a row twice is rounding it once), the prefill's scan
+        step, chunked = gdn_kernel.gdn_step_live, delta_rule.delta_rule_chunked
+        monkeypatch.setattr(gdn_kernel, "gdn_step_live",
+                            lambda pool, *a, **kw: (lambda o, p: (o, _bf16(p)))(*step(_bf16(pool), *a, **kw)))
         monkeypatch.setattr(delta_rule, "delta_rule_chunked", lambda *a: (lambda o, s: (o, _bf16(s)))(*chunked(*a)))
     elif what == "decay":
         exact = olmo_hybrid._decay_and_beta

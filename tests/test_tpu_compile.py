@@ -704,8 +704,8 @@ def test_a_process_that_serves_a_mixed_plan_never_imports_pallas():
 def periodic_serving_programs(one_chip):
     """``_decode_jit`` and the 256-token ``_prefill_jit`` of Olmo-Hybrid compiled
     for the described v5e at the published widths and the serve-longout cell's
-    pools (48 slots, 3073 blocks of 16, 96 blocks a slot), two periods deep
-    (``[linear, linear, linear, full] x 2``: the scan's body is the same at 4)
+    pools (48 slots, 3073 blocks of 16, 96 blocks a slot), the cell's four
+    periods deep (``[linear, linear, linear, full] x 4``: 12 linear layers)
     and 2048 rows of vocabulary."""
     from accelerate_tpu.models.olmo_hybrid import (
         _PERIOD,
@@ -716,8 +716,8 @@ def periodic_serving_programs(one_chip):
     from accelerate_tpu.ops import delta_rule
     from accelerate_tpu.serving import engine, make_pools, make_state_pool
 
-    cfg = OlmoHybridConfig(vocab_size=2048, layer_types=_PERIOD * 2)
-    slots, block, bps, num_blocks, repeats = 48, 16, 96, 3073, 2
+    cfg = OlmoHybridConfig(vocab_size=2048, layer_types=_PERIOD * 4)
+    slots, block, bps, num_blocks, repeats = 48, 16, 96, 3073, 4
 
     def shapes(tree):
         return jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype, one_chip), tree)
@@ -725,11 +725,11 @@ def periodic_serving_programs(one_chip):
     def weights(kind, lead=()):
         return {k: sds((*lead, *s), BF16, one_chip) for k, s in layer_shapes(cfg, kind).items()}
 
-    pools = shapes(jax.eval_shape(lambda: make_pools(2, num_blocks, cfg.n_kv_head, block, cfg.head_dim, BF16)))
+    pools = shapes(jax.eval_shape(lambda: make_pools(repeats, num_blocks, cfg.n_kv_head, block, cfg.head_dim, BF16)))
     heads, d_k, d_v = cfg.linear_num_value_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
     packed = jax.eval_shape(delta_rule.pack_state, sds((heads, d_k, d_v), jnp.float32, one_chip)).shape
     state = shapes(jax.eval_shape(lambda: make_state_pool(
-        6, slots, packed, (cfg.linear_conv_kernel_dim - 1, cfg.conv_width), BF16,
+        3 * repeats, slots, packed, (cfg.linear_conv_kernel_dim - 1, cfg.conv_width), BF16,
     )))
     layers = (tuple(weights(kind, (repeats,)) for kind in cfg.kinds[:4]), {}, {})
 
@@ -737,10 +737,10 @@ def periodic_serving_programs(one_chip):
         return sds(shape, jnp.int32, one_chip)
 
     statics = dict(family=OLMO_HYBRID_DECODER, cfg=cfg, qbits=0, temperature=0.0)
-    decode = engine._decode_jit.lower(
+    lowered = engine._decode_jit.lower(
         *pools, weights("globals"), layers, ints(slots, bps), ints(slots), ints(slots),
         sds((slots, 2), jnp.uint32, one_chip), state, **statics,
-    ).compile()
+    )
     prefill = engine._prefill_jit.lower(
         *pools, weights("globals"), layers, ints(1, 256), ints(bps), ints(),
         sds((2,), jnp.uint32, one_chip), ints(), state, **statics,
@@ -748,14 +748,15 @@ def periodic_serving_programs(one_chip):
     sizes = {
         "span": slots * bps * block * cfg.n_kv_head * cfg.head_dim,  # what the gather path would build a pool and layer
         "state": slots * heads * d_k * d_v, "kv": int(np.prod(pools[0].shape[1:])),
-        "weight": cfg.hidden_size * cfg.key_width, "state_layers": 6,
+        "weight": cfg.hidden_size * cfg.key_width, "state_layers": 3 * repeats, "repeats": repeats,
     }
-    return {"decode": decode, "prefill": prefill}, sizes
+    return {"decode": lowered.compile(), "prefill": prefill, "decode_lowered": lowered.as_text()}, sizes
 
 
 def test_a_periodic_plans_decode_holds_nothing_of_the_gathered_spans_size(periodic_serving_programs):
     """The plan is scanned by its period, so its attention layer takes the
-    kernel: ONE Mosaic call in the program (the scan's body), the pools left
+    kernel: ONE paged-attention call in the program (the scan's body, beside
+    its three linear layers' delta-rule step), the pools left
     where they lie — nothing the size of the span the gather path would build
     (48 slots x 1536 positions x 3840 lanes, 566 MB a pool and layer) is
     copied, gathered, sliced or re-laid, the program's temporaries are under a
@@ -767,7 +768,9 @@ def test_a_periodic_plans_decode_holds_nothing_of_the_gathered_spans_size(period
     programs, sizes = periodic_serving_programs
     decode = programs["decode"]
     text = decode.as_text()
-    assert pallas_calls(decode) == 1 and "paged_attention" in text
+    calls = [line for line in text.split("\n") if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted("paged_attention" if "paged_attention" in c else "gdn_step" if "gdn_step" in c else c
+                  for c in calls) == ["gdn_step"] * 3 + ["paged_attention"]
     moved = instructions_of_size(
         text, ("copy", "gather", "slice", "dynamic-slice", "transpose", "concatenate", "reshape"), sizes["span"] // 2)
     assert moved == []
@@ -780,8 +783,8 @@ def test_a_periodic_plans_programs_update_both_caches_in_place(periodic_serving_
     """Both pools ride the scan's carry and come back aliased; no ``copy`` of a
     layer's state or of a layer's KV pool is left, and outside the fusions that
     read a layer's rows and write them back in place nothing holds a layer's
-    state (decode: one fused pass reads it for both sums over k rows, one
-    writes it)."""
+    state (decode: the delta-rule kernel's operand and result are the pool
+    itself; prefill: the write of the admitted slot's rows is fused)."""
     from accelerate_tpu.telemetry.profiler import instructions_of_size
 
     programs, sizes = periodic_serving_programs
@@ -793,7 +796,7 @@ def test_a_periodic_plans_programs_update_both_caches_in_place(periodic_serving_
     )
     held = instructions_of_size(
         top, ("slice", "dynamic-slice", "copy", "fusion", "dynamic-update-slice", "transpose"), sizes["state"])
-    assert all(dims in (pool, (12292 // 2, 16, 3840)) for _, _, dims in held), held
+    assert all(dims in (pool, (3073 * sizes["repeats"], 16, 3840)) for _, _, dims in held), held
     header = text.split("\n", 1)[0]
     assert len(re.findall(r"(?:may|must)-alias", header)) == 4, header[:600]
 
@@ -806,9 +809,77 @@ def test_the_state_pool_lies_without_padding(periodic_serving_programs):
     programs, sizes = periodic_serving_programs
     header = programs["decode"].as_text().split("\n", 1)[0]
     takes = header[header.index("entry_computation_layout"):].split("->")[0]
-    (dims, tile), = re.findall(r"f32\[(6,48,[\d,]+)\]\{[\d,]+:T\(([\d,]+)\)", takes)
+    (dims, tile), = re.findall(r"f32\[(%d,48,[\d,]+)\]\{[\d,]+:T\(([\d,]+)\)" % sizes["state_layers"], takes)
     dims, tile = [int(d) for d in dims.split(",")], [int(t) for t in tile.split(",")]
     for axis, t in zip((-2, -1), tile):
         dims[axis] = -(-dims[axis] // t) * t
     laid_out = 4 * int(np.prod(dims))
     assert abs(laid_out / (4 * sizes["state_layers"] * sizes["state"]) - 1) < 0.02
+
+
+def test_a_periodic_plans_decode_lowers_the_delta_rule_kernel_once_and_updates_the_pool_in_place(
+        periodic_serving_programs):
+    """Olmo-Hybrid's one-token delta rule is ONE lowered callee
+    (``native/kernels/gdn_step.py``, called through one ``jax.jit`` whose
+    arguments have the same shapes at every linear layer) that the scan's body
+    calls once a linear layer: three calls a period, run four times, so the
+    cell's 12 linear layers run 12 ``tpu_custom_call``s a step from one Mosaic
+    lowering.  Each takes the whole state pool as its operand and hands it back
+    aliased, and nothing under ``atpu_serve_gdn_step`` — no
+    ``dynamic-update-slice``, ``slice``, ``copy`` or fusion — holds a layer's
+    state or more (the plain step read the state twice and wrote it back with
+    a pool-sized ``select_dynamic-update-slice_fusion`` a layer: PERF.md §5)."""
+    from accelerate_tpu.telemetry.profiler import instructions_of_size
+
+    programs, sizes = periodic_serving_programs
+    lowered = programs["decode_lowered"]
+    callees = {re.match(r"\w+ @(\w+)", f).group(1): f for f in lowered.split("func.func ")[1:] if "tpu_custom_call" in f}
+    assert lowered.count("stablehlo.custom_call @tpu_custom_call") == 2  # one lowering a kernel: attention's, this
+    pool_type = "tensor<%dx48x30x48x384xf32>" % sizes["state_layers"]
+    (callee,) = [name for name, body in callees.items() if f"-> ({pool_type}" in body]
+    assert callee == "_gdn_step"
+    assert len(re.findall(rf"call @{callee}\(", lowered)) == 3  # the scan's body: one a linear layer of the period
+    text = programs["decode"].as_text()
+    calls = [line for line in text.split("\n") if 'custom_call_target="tpu_custom_call"' in line and "gdn_step" in line]
+    assert len(calls) == 3
+    # the scan over the repeats: its condition compares the counter with a constant
+    loop = re.search(r" while\(.*condition=(%[\w.]+), body=(%[\w.]+)", text)
+    blocks = {b.split(" ", 1)[0]: b for b in re.split(r"\n(?=\S)", text)}
+    (trips,) = re.findall(r"s32\[\]\S* constant\((\d+)\)", blocks[loop.group(1)])
+    body = blocks[loop.group(2)]
+    assert all(line in body for line in calls)
+    assert int(trips) * len(calls) == 3 * sizes["repeats"] == 12
+    pool = "f32[%d,48,30,48,384]" % sizes["state_layers"]
+    for line in calls:
+        assert line.split("=", 1)[1].lstrip().startswith(f"({pool}")
+        assert "output_to_operand_aliasing={{0}: (6, {})}" in line
+    scoped = "\n".join(line for line in text.split("\n") if "atpu_serve_gdn_step" in line)
+    held = instructions_of_size(
+        scoped, ("copy", "slice", "dynamic-slice", "dynamic-update-slice", "transpose", "fusion"), sizes["state"])
+    assert held == []
+
+
+@pytest.mark.parametrize("devices", [1, 4], ids=["one-chip", "four-chip-mesh"])
+def test_the_delta_rule_kernel_compiles_in_mosaic_for_v5e(topo, devices):
+    """The one-token delta rule at the serve-longout cell's shapes: a pool of
+    12 linear layers x 48 slots x 30 heads of 96 x 192 float32, packed (48,
+    384) (1.27 GB), one slot's 2.2 MB state in and out of VMEM a grid step
+    (8.8 MB double-buffered, under the 16 MiB scoped default), ``exp(g)``,
+    ``β`` and ``k·q`` in SMEM; no temporary but the slots' small rows.  On a
+    mesh of four devices the pool lies replicated and the kernel runs per
+    device under ``shard_map`` (bare, the TPU lowering refuses it)."""
+    from accelerate_tpu.native.kernels import gdn_step as kernel
+
+    mesh = Mesh(np.array(topo.devices[:devices]).reshape(devices), ("tp",))
+    whole = NamedSharding(mesh, P())
+    n_layers, slots, h, d_k, d_v = 12, 48, 30, 96, 192
+    f32 = jnp.float32
+    args = (sds((n_layers, slots, h, 48, 384), f32, whole), sds((), jnp.int32, whole),
+            sds((slots,), jnp.bool_, whole), sds((slots, h, d_k), f32, whole), sds((slots, h, d_k), f32, whole),
+            sds((slots, h, d_v), f32, whole), sds((slots, h), f32, whole), sds((slots, h), f32, whole))
+    compiled = jax.jit(
+        lambda pool, i, *rest: kernel.gdn_step_live(pool, i, *rest, mesh=mesh), donate_argnums=0
+    ).lower(*args).compile()
+    assert pallas_calls(compiled) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * slots * h * d_v * 4
+    assert re.search(r"\{1\}: \(0, \{\}, (may|must)-alias\)", compiled.as_text().split("\n", 1)[0])
